@@ -31,7 +31,7 @@ from repro.kernels.addtree.ops import tree_reduce_sum
 from repro.kernels.qmatmul.ops import qmatmul
 from repro.ops import TUNING_CACHE, ExecPolicy
 from repro.ops.autotune import tune_conv2d, tune_fused_conv_block
-from repro.ops.tiling import largest_divisor
+from repro.ops.tiling import legal_qmatmul_tiles
 
 # (B, N, H, W, M, kh, kw, sh, sw) — the paper's two conv layers + a wide one
 CONV_CASES = [
@@ -103,10 +103,11 @@ def _sweep_qmatmul() -> None:
         ws = jnp.full((1, n), 0.02, jnp.float32)
         best, best_us = None, float("inf")
         # label + cache the tiles that actually execute: the wrapper clamps
-        # each requested block to the largest divisor of its dim, so two
+        # each requested block to a legal divisor of its dim, so two
         # requested caps can collapse to the same real tile — dedupe
-        tiles = sorted({(largest_divisor(m, c), largest_divisor(n, c),
-                         largest_divisor(k, c)) for c in QMM_BLOCKS})
+        tiles = sorted({tuple(legal_qmatmul_tiles(
+            m, n, k, {"bm": c, "bn": c, "bk": c}).values())
+            for c in QMM_BLOCKS})
         for bm, bn, bk in tiles:
             pol = ExecPolicy(tiling={"bm": bm, "bn": bn, "bk": bk})
             us = time_fn(functools.partial(qmatmul, policy=pol),

@@ -15,10 +15,12 @@ reuse traced jaxprs and the executable cache):
 * ``artifact_aot`` — full hit: plans AND serialized executables restore;
                      boot is deserialization + first dispatch only.
 
-Each child reports its warmup phase breakdown (repro.artifact.warmup)
-and a digest of its first response's logits — the three modes must be
-bitwise-identical (same weights, same plan, same program), which the
-schema check asserts. The trajectory lands in ``BENCH_boot.json``; the
+The donor replica that saves the stores is a child too: a chip belongs to
+one process, so the parent never touches JAX and each child gets the
+device to itself. Each boot child reports its warmup phase breakdown
+(repro.artifact.warmup) and a digest of its first response's logits —
+the three modes must be bitwise-identical (same weights, same plan, same
+program), which the schema check asserts. The trajectory lands in ``BENCH_boot.json``; the
 acceptance bar is artifact_aot ≥ 2× faster to first response than fresh.
 """
 from __future__ import annotations
@@ -82,6 +84,7 @@ def _child(mode: str, store: str, buckets: str) -> None:
     logits = np.asarray(results[uid]["logits"], np.float32)
     print(json.dumps({
         "mode": mode,
+        "platform": jax.default_backend(),
         "boot_to_first_response_ms": round(elapsed * 1e3, 3),
         "phases_ms": {p: round(boot.phase_s(p) * 1e3, 3) for p in PHASES},
         "calls": {p: boot.phase_calls(p) for p in PHASES},
@@ -92,22 +95,31 @@ def _child(mode: str, store: str, buckets: str) -> None:
     }))
 
 
-def _run_child(mode: str, store: str, buckets: str) -> dict:
+def _run_child(*args: str) -> str:
+    """Run this module in a child process; returns its stdout."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(REPO / "src"), str(REPO)]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.plan_boot", "--child", mode,
-         "--store", store, "--buckets", buckets],
+        [sys.executable, "-m", "benchmarks.plan_boot", *args],
         cwd=REPO, env=env, capture_output=True, text=True, check=True)
-    # report is the last stdout line; anything above is boot chatter
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return proc.stdout
 
 
-# ---------- parent: save stores, measure the three modes ----------
+def _report(stdout: str) -> dict:
+    # the report is the last stdout line; anything above is boot chatter
+    return json.loads(stdout.strip().splitlines()[-1])
 
-def _save_stores(tmp: pathlib.Path, buckets: str) -> dict[str, str]:
+
+def _store_dirs(tmp: pathlib.Path) -> dict[str, str]:
+    return {"artifact": str(tmp / "store_noaot"),
+            "artifact_aot": str(tmp / "store_aot"), "fresh": ""}
+
+
+# ---------- donor child: save the stores ----------
+
+def _save_stores(tmp: pathlib.Path, buckets: str) -> None:
     """One donor replica saves the bucket ladder twice: with AOT
     executables (the full-hit store) and without (isolates how much of
     the win is skipping derivation vs skipping XLA compile)."""
@@ -123,24 +135,24 @@ def _save_stores(tmp: pathlib.Path, buckets: str) -> dict[str, str]:
         VisionEngineConfig(batch=BATCH,
                            buckets="auto" if buckets == "auto" else None))
     from repro.artifact.store import PlanStore
-    stores = {"artifact": str(tmp / "store_noaot"),
-              "artifact_aot": str(tmp / "store_aot")}
-    for mode, root in stores.items():
-        store = PlanStore(root)
-        for bucket, bound in sorted(engine._bounds.items()):
+    for mode in ("artifact", "artifact_aot"):
+        store = PlanStore(_store_dirs(tmp)[mode])
+        for bucket in engine.buckets:
             shape = (bucket, *model.input_shape()[1:])
-            store.save(engine.bucket_name(bucket), bound,
+            store.save(engine.bucket_name(bucket), engine.bound(bucket),
                        input_shapes=[shape], aot=mode == "artifact_aot")
-    stores["fresh"] = ""
-    return stores
 
+
+# ---------- parent (never touches JAX): measure the three modes ----------
 
 def bench_point(*, smoke: bool = False) -> dict:
-    import jax
     buckets = "fixed" if smoke else "auto"
     with tempfile.TemporaryDirectory() as tmp:
-        stores = _save_stores(pathlib.Path(tmp), buckets)
-        reports = {m: _run_child(m, stores[m], buckets) for m in MODES}
+        _run_child("--save-stores", tmp, "--buckets", buckets)
+        stores = _store_dirs(pathlib.Path(tmp))
+        reports = {m: _report(_run_child("--child", m, "--store",
+                                         stores[m], "--buckets", buckets))
+                   for m in MODES}
     fresh_ms = reports["fresh"]["boot_to_first_response_ms"]
     for mode in MODES:
         rec = reports[mode]
@@ -155,7 +167,7 @@ def bench_point(*, smoke: bool = False) -> dict:
         "bench": "plan_boot",
         "schema": 1,
         "smoke": smoke,
-        "platform": jax.default_backend(),
+        "platform": reports["fresh"]["platform"],
         "batch": BATCH,
         "buckets": buckets,
         "modes": reports,
@@ -203,6 +215,8 @@ if __name__ == "__main__":
                     help="internal: run one measured boot and print JSON")
     ap.add_argument("--store", default="",
                     help="internal: artifact store dir for the child")
+    ap.add_argument("--save-stores", default=None, metavar="DIR",
+                    help="internal: donor child saving the stores in DIR")
     ap.add_argument("--buckets", default="auto",
                     choices=("auto", "fixed"))
     ap.add_argument("--smoke", action="store_true",
@@ -215,6 +229,9 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.child:
         _child(args.child, args.store, args.buckets)
+        sys.exit(0)
+    if args.save_stores:
+        _save_stores(pathlib.Path(args.save_stores), args.buckets)
         sys.exit(0)
     print("name,us_per_call,derived")
     point = bench_point(smoke=args.smoke)

@@ -63,11 +63,11 @@ import functools
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quantize import conv_epilogue
 from repro.core.window import maxpool2
-from repro.sharding.compat import shard_map
 
 __all__ = ["ChannelParallelism", "conv2d_channel_parallel",
            "fused_conv_block_channel_parallel", "ring_all_reduce",

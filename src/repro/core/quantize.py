@@ -129,20 +129,14 @@ def requant_epilogue(acc: jax.Array, scale: jax.Array,
     """Dequantize an integer conv accumulator: ``acc·scale [+ b]``.
 
     ``scale``/``b`` must be pre-broadcast to ``acc``'s layout by the
-    caller. The optimization barrier between the multiply and the add
-    pins the arithmetic to mul-round-then-add-round: without it XLA may
-    contract the pair into a single-rounding FMA inside a fused kernel
-    but not in the eager chain, and the fused-vs-unfused bitwise parity
-    the registry guarantees (DESIGN.md §8) would silently hold only
-    per-compilation. One elementwise op on an accumulator tile — the
-    barrier costs nothing measurable.
+    caller. Compilers may contract the multiply-add into one FMA — the
+    installed XLA:CPU does inside a fusion, and a compiled Pallas TPU
+    kernel may — so backends agree on this step to one rounding, not bit
+    for bit; the integer accumulation before it is exact everywhere
+    (DESIGN.md §8 states the parity that holds).
     """
     out = acc * scale
-    if b is None:
-        return out
-    if hasattr(jax.lax, "optimization_barrier"):
-        out = jax.lax.optimization_barrier(out)
-    return out + b
+    return out if b is None else out + b
 
 
 def conv_epilogue(out: jax.Array, scale: jax.Array | None,
@@ -152,8 +146,8 @@ def conv_epilogue(out: jax.Array, scale: jax.Array | None,
     dtype. This is THE post-reduction arithmetic — every consumer
     (``repro.ops`` conv2d / fused xla backend, the fused ref oracle, the
     channel-parallel schedules) must call it rather than re-spelling the
-    broadcasts, or the fused-vs-unfused and sharded-vs-unsharded bitwise
-    parity guarantees silently decay into per-call-site conventions."""
+    broadcasts, or the fused-vs-unfused and sharded-vs-unsharded parity
+    guarantees silently decay into per-call-site conventions."""
     if scale is not None:
         return requant_epilogue(
             out, scale[None, :, None, None],

@@ -258,7 +258,10 @@ def conv2d_im2col(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
     """
     m, n, kh, kw = w.shape
     win = extract_windows(x, (kh, kw), stride)          # (B,Ho,Wo,η)
+    # HIGHEST: fp32 operands contract fp32-accurately on a TPU too (its
+    # default is one bf16 pass), like the Pallas conv kernels
     out = jnp.einsum("bhwe,me->bmhw", win, w.reshape(m, n * kh * kw),
+                     precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32).astype(x.dtype)
     if b is not None:
         out = out + b[None, :, None, None].astype(out.dtype)

@@ -148,25 +148,29 @@ class ExecutionPlan:
             bv = _weight(node, 2, "b")
             spec = node.sharding
             if self.mesh is None or spec is None or spec.mode == "none":
-                # single-device (or pure-data-parallel: XLA propagates the
-                # caller's batch sharding through elementwise stages)
+                # single-device, or pure data parallel over the mesh
                 pol = self._stage_policy(base, tuned.get(node.id))
                 tiling = getattr(node, "tiling", None)
-                if tiling is not None:
-                    # over-budget stage: stream halo-overlapped row bands
-                    # through the same op registry (DESIGN.md §13)
-                    from repro.stream.executor import (
-                        stream_conv2d, stream_fused_conv_block)
+
+                def stage(xl, wl, bl):
+                    if tiling is not None:
+                        # over-budget stage: stream halo-overlapped row
+                        # bands through the same op registry (§13)
+                        from repro.stream.executor import (
+                            stream_conv2d, stream_fused_conv_block)
+                        if fused:
+                            return stream_fused_conv_block(
+                                xl, wl, bl, stride=node.stride,
+                                odd=node.odd, tiling=tiling, policy=pol)
+                        return stream_conv2d(xl, wl, bl, stride=node.stride,
+                                             tiling=tiling, policy=pol)
                     if fused:
-                        return stream_fused_conv_block(
-                            xin, wv, bv, stride=node.stride, odd=node.odd,
-                            tiling=tiling, policy=pol)
-                    return stream_conv2d(xin, wv, bv, stride=node.stride,
-                                         tiling=tiling, policy=pol)
-                if fused:
-                    return fused_conv_block(xin, wv, bv, stride=node.stride,
-                                            odd=node.odd, policy=pol)
-                return conv2d(xin, wv, bv, stride=node.stride, policy=pol)
+                        return fused_conv_block(xl, wl, bl,
+                                                stride=node.stride,
+                                                odd=node.odd, policy=pol)
+                    return conv2d(xl, wl, bl, stride=node.stride, policy=pol)
+
+                return self._batch_parallel(stage, xin, wv, bv)
             from repro.core.parallelism import (
                 ChannelParallelism, conv2d_channel_parallel,
                 fused_conv_block_channel_parallel)
@@ -207,22 +211,22 @@ class ExecutionPlan:
                 env[node.id] = v.reshape(v.shape[0], -1)
             elif isinstance(node, DenseNode):
                 wq = folded.get(node.id)
+                b = _weight(node, 2, "b")
                 if wq is not None:
                     # bind pre-quantized this dense weight: run the real
                     # int8 datapath directly (== ops.dense under int8)
                     from repro.ops import qdense
-                    xv = env[node.inputs[0]]
-                    out = qdense(xv, wq, out_dtype=xv.dtype,
-                                 policy=self._stage_policy(
-                                     base, tuned.get(node.id)))
-                    b = _weight(node, 2, "b")
+                    pol = self._stage_policy(base, tuned.get(node.id))
+                    out = self._batch_parallel(
+                        lambda xl, wl: qdense(xl, wl, out_dtype=xl.dtype,
+                                              policy=pol),
+                        env[node.inputs[0]], wq)
                     env[node.id] = out if b is None else out + b
                 else:
-                    env[node.id] = dense(
-                        env[node.inputs[0]], _weight(node, 1, "w"),
-                        _weight(node, 2, "b"),
-                        policy=self._stage_policy(dense_pol,
-                                                  tuned.get(node.id)))
+                    pol = self._stage_policy(dense_pol, tuned.get(node.id))
+                    env[node.id] = self._batch_parallel(
+                        lambda xl, wl, bl: dense(xl, wl, bl, policy=pol),
+                        env[node.inputs[0]], _weight(node, 1, "w"), b)
             else:
                 raise TypeError(f"no executor for node {node.pretty()}")
         return env[self.graph.output_id]
@@ -243,6 +247,33 @@ class ExecutionPlan:
         if isinstance(x, jax.core.Tracer):
             return jax.lax.with_sharding_constraint(x, sh)
         return jax.device_put(x, sh)
+
+    def _batch_parallel(self, fn, x, *consts):
+        """``fn(x, *consts)`` with the batch split over the mesh's
+        ``data`` axis by a shard_map, ``consts`` (weights, None allowed)
+        replicated. A Pallas kernel is a one-device program that XLA
+        cannot partition, so on a data-parallel mesh the plan hands each
+        device its batch slice itself; without a data axis wider than
+        one, or when the batch does not divide it, this is plain
+        ``fn(x, *consts)``."""
+        data = self.mesh.shape.get("data", 1) if self.mesh is not None \
+            else 1
+        lead = x.codes if isinstance(x, QTensor) else x
+        if data == 1 or lead.shape[0] % data:
+            return fn(x, *consts)
+        from jax import shard_map
+        batch = P("data", *[None] * (lead.ndim - 1))
+        present = [c for c in consts if c is not None]
+
+        def local(xl, *kept):
+            it = iter(kept)
+            return fn(xl, *[None if c is None else next(it) for c in consts])
+
+        return shard_map(
+            local, mesh=self.mesh,
+            in_specs=(QTensor(batch, P()) if isinstance(x, QTensor)
+                      else batch, *[P()] * len(present)),
+            out_specs=P("data"), check_vma=False)(x, *present)
 
     def _gather(self, v):
         """Collect a (possibly channel-sharded) activation at the conv→fc

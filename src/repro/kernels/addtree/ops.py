@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.kernels.addtree.kernel import tree_reduce_sum_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import choose_tree_rows, tile_params
+from repro.ops.tiling import SUBLANE, choose_tree_rows, tile_params
 
 
 @functools.partial(jax.jit, static_argnames=("rb", "interpret"))
@@ -46,5 +46,6 @@ def tree_reduce_sum(x: jax.Array, interpret: bool | None = None, *,
                         choose_tree_rows(r), pol.tile_overrides)
     if rb is not None:
         tiles["rb"] = rb
-    return _tree_reduce_sum_jit(x, rb=max(1, min(tiles["rb"], r)),
-                                interpret=interpret)
+    # a row block short of R sits on sublanes: whole sublane tiles only
+    rb = min(r, -(-max(1, tiles["rb"]) // SUBLANE) * SUBLANE)
+    return _tree_reduce_sum_jit(x, rb=rb, interpret=interpret)

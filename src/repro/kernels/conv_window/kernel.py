@@ -8,31 +8,36 @@ Mapping of the paper's §III.B.2 structure onto the TPU memory hierarchy
   SHIFT_BUFFER (K-1)×(W-K)     the input *slab*: a (rows_in × W) full-width
     holds W-K trailing pixels    stripe of the image, DMA'd HBM->VMEM once
     of the previous K-1 rows     per (row-block, batch) grid step
-  WINDOW_BUFFER K×K regs       the Kh·Kw statically-unrolled strided slices
-    one window per clock         of the slab in VREGs, assembled into an
-                                 im2col tile (RB·Wo, N·Kh·Kw) in VMEM
-  K² DSP multipliers +         one MXU contraction of the im2col tile with
-    odd-even addition tree       the (N·Kh·Kw, MB) weight tile — the systolic
-                                 array performs all multiplies and the full
-                                 reduction tree per output element
+  WINDOW_BUFFER K×K regs       the Kh·Kw taps: per output row, each tap is
+    one window per clock         a lane-shifted (N, Wo) view of one slab
+                                 row; a kernel row's Kw taps stack on
+                                 sublanes into a (Kw·N, Wo) operand
+  K² DSP multipliers +         one (MB, Kw·N)·(Kw·N, Wo) MXU contraction
+    odd-even addition tree       per kernel row, accumulated in fp32 — the
+                                 systolic array does the multiplies and
+                                 the sums over Kw and the channels
   M parallel kernel banks      the Cout grid axis (output-channel parallel)
-  N-channel parallel units     Cin folded into the contraction (all input
-                                 channels reduce inside the MXU)
+  N-channel parallel units     Cin folded into each tap's contraction
+
+Layout (DESIGN.md §8): the slab is ``(B, H, P, N, W/P)`` — rows on a
+leading axis, channels on sublanes, width on lanes, and the columns split
+into P = stride_w interleaved *phases* (column c sits in phase c % P at
+lane c // P). A row is then a dynamic index on an untiled axis and every
+tap, strided or not, is a contiguous lane slice, so the kernel body uses
+no strided or gathered vector access. The output is ``(B, Ho, M, Wo)``:
+its last two block dims are always (MB, Wo), so the row block RB is free
+of the 8×128 tiling rule and only MB must be a multiple of 8 or all of M.
+The wrappers transpose NCHW in and out around the call.
 
 Reuse invariant preserved: each input element crosses HBM->VMEM once per
 row block (halo rows of adjacent blocks excepted: Kh−stride_h rows, the same
 (K−1)/K-style overlap the paper's SHIFT_BUFFER absorbs — here amortized to
-(Kh−s)/(RB·s) per block, i.e. *better* than one line-buffer row because a
-block carries RB rows). Pipelining of DMA against MXU work is done by the
-Pallas TPU pipeline (double-buffered by default) — the "one window per
-clock" II=1 property becomes "one im2col tile per grid step with the next
-slab's DMA in flight".
+(Kh−s)/(RB·s) per block). The halo'd slab is addressed with element
+offsets on every axis (``pl.Element``); the Pallas TPU pipeline
+double-buffers it against the MXU work of the previous step.
 
-Grid: (B, ⌈Ho/RB⌉, ⌈M/MB⌉). Block shapes are chosen by ops.py to fit a VMEM
-budget and keep the contraction dims MXU-aligned where possible (the feature
-dim η = N·Kh·Kw is deliberately NOT padded to a power of two — the odd-even
-tree rule; the MXU only needs multiples of the 8×128 tile, which Mosaic pads
-to internally).
+Grid: (B/BB, Ho/RB, M/MB). Block sizes come from ops.py (VMEM budget,
+tiling rule, measured tuning cache).
 """
 from __future__ import annotations
 
@@ -42,108 +47,129 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# fp32 operands contract at full fp32 precision on the MXU; int8 codes
+# (integer-valued fp32, |code| <= 127) are exact at any precision
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def slab_layout(x: jax.Array, phases: int) -> jax.Array:
+    """(B, N, H, W) -> (B, H, P, N, ⌈W/P⌉): rows leading, column c in
+    phase c % P at lane c // P (zero columns pad W to a multiple of P;
+    no valid output ever reads them)."""
+    bsz, n, h, w = x.shape
+    wp = -(-w // phases)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, wp * phases - w)))
+    return x.reshape(bsz, n, h, wp, phases).transpose(0, 2, 4, 1, 3)
+
+
+def tap_weights(w: jax.Array) -> jax.Array:
+    """(M, N, Kh, Kw) -> (Kh, M, Kw·N): one (M, Kw·N) matrix per kernel
+    row, features ordered (tap, channel) like ``conv_row``'s operand."""
+    m, n, kh, kw = w.shape
+    return w.transpose(2, 0, 3, 1).reshape(kh, m, kw * n)
+
+
+def slab_spec(bb: int, rows_in: int, phases: int, n: int, wp: int,
+              row_step: int) -> pl.BlockSpec:
+    """The halo'd input slab: ``rows_in`` rows starting every
+    ``row_step`` rows, so consecutive row blocks overlap by the halo
+    exactly like adjacent line-buffer windows. Every axis is
+    element-indexed (Pallas TPU does not mix element and blocked axes)."""
+    return pl.BlockSpec(
+        (pl.Element(bb), pl.Element(rows_in), pl.Element(phases),
+         pl.Element(n), pl.Element(wp)),
+        lambda bi, ri, mi: (bi * bb, ri * row_step, 0, 0, 0))
+
+
+def conv_row(x_ref, w_ref, img, row, *, kh: int, kw: int,
+             stride: tuple[int, int], groups: int, group: int,
+             width: int) -> jax.Array:
+    """fp32 (MB, width) accumulator of conv output row ``row`` (local to
+    the slab), restricted to the output columns q·groups + group.
+
+    The slab holds P = groups·stride_w column phases, so output column
+    (q·groups + group) at tap j reads input column P·q + group·sw + j:
+    phase e % P, lane offset e // P with e = group·sw + j — one
+    contiguous lane slice per tap. ``groups=2`` splits even from odd
+    output columns, which is how the fused kernel pools columns without
+    a strided lane access. Each kernel row's Kw taps stack on sublanes
+    into one (Kw·N, width) operand: one MXU contraction per kernel row."""
+    sh, sw = stride
+    phases = groups * sw
+    acc = None
+    for i in range(kh):
+        rows = [x_ref[img, row * sh + i, ph] for ph in range(phases)]
+        taps = []
+        for j in range(kw):
+            e = group * sw + j
+            off = e // phases
+            taps.append(rows[e % phases][:, off:off + width])  # (N, width)
+        part = jax.lax.dot_general(
+            w_ref[i], jnp.concatenate(taps, axis=0),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=PRECISION, preferred_element_type=jnp.float32)
+        acc = part if acc is None else acc + part
+    return acc
+
 
 def _conv_window_kernel(x_ref, w_ref, b_ref, o_ref, *,
                         kh: int, kw: int, stride: tuple[int, int],
-                        rb: int, wo: int, n: int, ho: int, bb: int):
-    """One grid step: BB × (slab -> windows -> MXU contraction), one
-    weight-tile DMA.
+                        rb: int, wo: int, bb: int):
+    """One grid step: BB images × RB output rows, one weight-tile DMA.
 
-    x_ref: (BB, N, rows_in, W)  input slab block, rows_in=(rb-1)*sh+kh
-    w_ref: (N*Kh*Kw, MB)        flat weight tile (feature order N, Kh, Kw)
-    b_ref: (1, MB)              bias tile
-    o_ref: (BB, MB, RB, Wo)     output tile
+    x_ref: (BB, rows_in, sw, N, W/sw)  slab, rows_in = (rb-1)*sh + kh
+    w_ref: (Kh, MB, Kw*N)              per-kernel-row weight tile
+    b_ref: (MB, 1)                     bias tile
+    o_ref: (BB, RB, MB, Wo)            output tile
 
-    The BB loop is a static unroll so each image runs the *same*
+    One loop over (image, row) pairs: each image runs the *same*
     contraction as the BB=1 kernel (bitwise-identical output per image for
     any BB) while the weight tile crosses HBM once per BB images.
     """
-    sh, sw = stride
-    out_imgs = []
-    for img in range(bb):
-        slab = x_ref[img]                   # (N, rows_in, W) in VMEM
+    bias = b_ref[...]
 
-        # WINDOW_BUFFER walk: Kh*Kw static slices, strided to (N, RB, Wo).
-        taps = []
-        for i in range(kh):
-            for j in range(kw):
-                tap = jax.lax.slice(
-                    slab,
-                    (0, i, j),
-                    (n, i + (rb - 1) * sh + 1, j + (wo - 1) * sw + 1),
-                    (1, sh, sw),
-                )                           # (N, RB, Wo)
-                taps.append(tap)
-        # windows: feature axis ordered (N, Kh, Kw) to match flat weights.
-        win = jnp.stack(taps, axis=1)       # (N, Kh*Kw, RB, Wo)
-        win = win.reshape(n * kh * kw, rb * wo)  # (η, RB*Wo)
+    def row(i, carry):
+        img, r = i // rb, i % rb
+        acc = conv_row(x_ref, w_ref, img, r, kh=kh, kw=kw, stride=stride,
+                       groups=1, group=0, width=wo)
+        o_ref[img, r] = (acc + bias).astype(o_ref.dtype)
+        return carry
 
-        # The MXU is the multiply-add tree: one contraction does all η
-        # products and their reduction per output element (paper Eq. 9).
-        acc = jax.lax.dot_general(
-            w_ref[...], win,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                   # (MB, RB*Wo)
-        acc = acc + b_ref[0, :][:, None]
-        # Rows past Ho (last row-block ragged edge) are garbage the out
-        # BlockSpec clips; keep values finite for determinism.
-        out_imgs.append(acc.reshape(-1, rb, wo))
-    o_ref[...] = jnp.stack(out_imgs, axis=0).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, bb * rb, row, 0)
 
 
-def conv2d_window_pallas(x: jax.Array, wf: jax.Array, b: jax.Array, *,
-                         kh: int, kw: int, stride: tuple[int, int],
-                         rb: int, mb: int, bb: int = 1, interpret: bool
-                         ) -> jax.Array:
-    """Launch the kernel. x: (B, N, H, W); wf: (η, M) flat weights; b: (M,).
+def conv2d_window_pallas(x: jax.Array, w: jax.Array, b: jax.Array, *,
+                         stride: tuple[int, int], rb: int, mb: int,
+                         bb: int = 1, interpret: bool) -> jax.Array:
+    """Launch the kernel. x: (B, N, H, W); w: (M, N, Kh, Kw); b: (M,).
 
     rb: output rows per block; mb: output channels per block; bb: images
     per grid step (weight reuse — a measured autotuner candidate,
-    DESIGN.md §10). Returns (B, M, Ho, Wo) in x.dtype.
+    DESIGN.md §10). Requires rb | Ho, mb | M, bb | B (the wrapper pads).
+    Returns (B, M, Ho, Wo) in x.dtype.
     """
-    bsz, n, h, w = x.shape
-    eta, m = wf.shape
-    assert eta == n * kh * kw, (eta, n, kh, kw)
+    bsz, n, h, wdt = x.shape
+    m, n2, kh, kw = w.shape
+    assert n == n2, (x.shape, w.shape)
     sh, sw = stride
     ho = (h - kh) // sh + 1
-    wo = (w - kw) // sw + 1
+    wo = (wdt - kw) // sw + 1
     assert ho % rb == 0 and m % mb == 0, (ho, rb, m, mb)
     assert bsz % bb == 0, (bsz, bb)
-    rows_in = (rb - 1) * sh + kh
-
-    grid = (bsz // bb, ho // rb, m // mb)
-
-    kernel = functools.partial(
-        _conv_window_kernel, kh=kh, kw=kw, stride=stride,
-        rb=rb, wo=wo, n=n, ho=ho, bb=bb)
-
-    # the slab: full width (line-buffer fidelity), halo rows via
-    # element-indexed offsets — consecutive row blocks overlap by
-    # kh - sh rows exactly like adjacent line-buffer windows. The batch
-    # dim is a BB-image block.
-    if hasattr(pl, "Squeezed"):          # newer pallas: per-dim block types
-        slab_spec = pl.BlockSpec((bb, n, pl.Element(rows_in), w),
-                                 lambda bi, ri, mi: (bi, 0, ri * rb * sh, 0))
-        out_spec = pl.BlockSpec((bb, mb, rb, wo),
-                                lambda bi, ri, mi: (bi, mi, ri, 0))
-    else:                                # jax 0.4.x: Unblocked (element
-        slab_spec = pl.BlockSpec(        # offsets in every dim)
-            (bb, n, rows_in, w),
-            lambda bi, ri, mi: (bi * bb, 0, ri * rb * sh, 0),
-            indexing_mode=pl.Unblocked())
-        out_spec = pl.BlockSpec((bb, mb, rb, wo),
-                                lambda bi, ri, mi: (bi, mi, ri, 0))
-
-    return pl.pallas_call(
+    xs = slab_layout(x, sw)
+    kernel = functools.partial(_conv_window_kernel, kh=kh, kw=kw,
+                               stride=stride, rb=rb, wo=wo, bb=bb)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz // bb, ho // rb, m // mb),
         in_specs=[
-            slab_spec,
-            pl.BlockSpec((eta, mb), lambda bi, ri, mi: (0, mi)),
-            pl.BlockSpec((1, mb), lambda bi, ri, mi: (0, mi)),
+            slab_spec(bb, (rb - 1) * sh + kh, sw, n, xs.shape[-1], rb * sh),
+            pl.BlockSpec((kh, mb, kw * n), lambda bi, ri, mi: (0, mi, 0)),
+            pl.BlockSpec((mb, 1), lambda bi, ri, mi: (mi, 0)),
         ],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, m, ho, wo), x.dtype),
+        out_specs=pl.BlockSpec((bb, rb, mb, wo),
+                               lambda bi, ri, mi: (bi, ri, mi, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, ho, m, wo), x.dtype),
         interpret=interpret,
-    )(x, wf, b)
+    )(xs, tap_weights(w).astype(x.dtype), b.reshape(m, 1).astype(x.dtype))
+    return out.transpose(0, 2, 1, 3)

@@ -1,10 +1,9 @@
 """jit'd public wrapper for the window-stationary conv kernel.
 
-Flattens weights to the (η, M) layout (feature order N, Kh, Kw — matching
-core.window.extract_windows and the line-buffer stream order), pads the
-output-row count to the block size when ragged, and exposes a single
-``conv2d_window`` entry point registered as the ``pallas`` backend of the
-``conv2d`` op family (repro.ops).
+Pads the output-row count to the row block and the batch to the batch
+block when ragged (the kernel launcher owns the slab/tap layouts), and
+exposes a single ``conv2d_window`` entry point registered as the
+``pallas`` backend of the ``conv2d`` op family (repro.ops).
 
 Block sizes and interpret mode come from the shared policy/tiling layer
 (DESIGN.md §7): explicit kwargs > ``ExecPolicy.tiling`` overrides > the
@@ -20,8 +19,8 @@ import jax.numpy as jnp
 
 from repro.kernels.conv_window.kernel import conv2d_window_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (choose_conv_blocks, conv_signature,
-                              largest_divisor, tile_params)
+from repro.ops.tiling import (SUBLANE, choose_conv_blocks, conv_signature,
+                              legal_block, tile_params)
 
 
 @functools.partial(jax.jit,
@@ -29,10 +28,9 @@ from repro.ops.tiling import (choose_conv_blocks, conv_signature,
 def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
                        stride: tuple[int, int], interpret: bool,
                        rb: int, mb: int, bb: int) -> jax.Array:
-    bsz, n, h, wdt = x.shape
-    m, n2, kh, kw = w.shape
-    assert n == n2, (x.shape, w.shape)
-    sh, sw = stride
+    bsz, h = x.shape[0], x.shape[2]
+    m, kh = w.shape[0], w.shape[2]
+    sh = stride[0]
     ho = (h - kh) // sh + 1
 
     # pad Ho to a multiple of rb by extending the input with dead rows —
@@ -46,13 +44,9 @@ def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
     if pad_b:
         x = jnp.pad(x, ((0, pad_b), (0, 0), (0, 0), (0, 0)))
 
-    wf = w.reshape(m, n * kh * kw).T        # (η, M), feature order (N,Kh,Kw)
-    bias = jnp.zeros((1, m), x.dtype) if b is None \
-        else b.reshape(1, m).astype(x.dtype)
-
-    out = conv2d_window_pallas(x, wf.astype(x.dtype), bias, kh=kh, kw=kw,
-                               stride=stride, rb=rb, mb=mb, bb=bb,
-                               interpret=interpret)
+    bias = jnp.zeros((m,), x.dtype) if b is None else b
+    out = conv2d_window_pallas(x, w, bias, stride=stride, rb=rb, mb=mb,
+                               bb=bb, interpret=interpret)
     return out[:bsz, :, :ho, :]
 
 
@@ -89,9 +83,9 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
         tiles["mb"] = mb
     if bb is not None:
         tiles["bb"] = bb
-    # mb must divide M (grid constraint); rb and bb are free — ragged Ho
+    # mb must divide M and obey the block rule; rb and bb are free — ragged Ho
     # and B are padded
-    tiles["mb"] = largest_divisor(m, tiles["mb"])
+    tiles["mb"] = legal_block(m, tiles["mb"], SUBLANE)
     tiles["rb"] = max(1, tiles["rb"])
     tiles["bb"] = max(1, min(tiles["bb"], x.shape[0]))
     return _conv2d_window_jit(x, w, b, stride=tuple(stride),

@@ -1,13 +1,11 @@
 """jit'd public wrapper for the fused conv+bias+relu+pool kernel.
 
-Same conventions as kernels/conv_window/ops.py: weights flatten to the
-(η, M) layout (feature order N, Kh, Kw — the line-buffer stream order),
-the pooled-row count is padded to the block size when ragged (by extending
-the input with dead rows and slicing the pooled result), the batch is
-padded to the batch block ``bb`` with dead images (sliced off the output),
-and tile sizes resolve through the shared policy/tiling layer (DESIGN.md
-§7): explicit kwargs > ``ExecPolicy.tiling`` > tuning cache > VMEM-budget
-heuristic. Under ``ExecPolicy.autotune`` a concrete (untraced) call with
+Same conventions as kernels/conv_window/ops.py: the pooled-row count is
+padded to the block size when ragged (by extending the input with dead
+rows and slicing the pooled result), the batch is padded to the batch
+block ``bb`` with dead images (sliced off the output), and tile sizes
+resolve through the shared policy/tiling layer (DESIGN.md §7): explicit
+kwargs > ``ExecPolicy.tiling`` > tuning cache > VMEM-budget heuristic. Under ``ExecPolicy.autotune`` a concrete (untraced) call with
 no cache entry first runs the measured candidate search
 (repro.ops.autotune) and the winner lands in the cache (DESIGN.md §10).
 
@@ -25,8 +23,8 @@ import jax.numpy as jnp
 
 from repro.kernels.fused_cwp.kernel import fused_cwp_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (choose_fused_blocks, conv_signature,
-                              largest_divisor, tile_params)
+from repro.ops.tiling import (SUBLANE, choose_fused_blocks, conv_signature,
+                              legal_block, tile_params)
 
 
 @functools.partial(jax.jit,
@@ -35,12 +33,10 @@ def _fused_cwp_jit(x: jax.Array, w: jax.Array, b: jax.Array | None,
                    scale: jax.Array | None, *,
                    stride: tuple[int, int], interpret: bool,
                    pb: int, mb: int, bb: int) -> jax.Array:
-    bsz, n, h, wdt = x.shape
-    m, n2, kh, kw = w.shape
-    assert n == n2, (x.shape, w.shape)
-    sh, sw = stride
-    ho = (h - kh) // sh + 1
-    po = ho // 2
+    bsz, h = x.shape[0], x.shape[2]
+    m, kh = w.shape[0], w.shape[2]
+    sh = stride[0]
+    po = ((h - kh) // sh + 1) // 2
 
     # pad Po to a multiple of pb with dead input rows; the tail block pools
     # windows over the pad and the result is sliced off
@@ -52,17 +48,12 @@ def _fused_cwp_jit(x: jax.Array, w: jax.Array, b: jax.Array | None,
     if pad_b:
         x = jnp.pad(x, ((0, pad_b), (0, 0), (0, 0), (0, 0)))
 
-    wf = w.reshape(m, n * kh * kw).T        # (η, M), feature order (N,Kh,Kw)
-    bias = jnp.zeros((1, m), x.dtype) if b is None \
-        else b.reshape(1, m).astype(x.dtype)
+    bias = jnp.zeros((m,), x.dtype) if b is None else b
     # ×1.0 on the accumulator is exact, so the unquantized path is
     # bit-identical to the pre-epilogue kernel
-    s = jnp.ones((1, m), jnp.float32) if scale is None \
-        else scale.reshape(1, m).astype(jnp.float32)
-
-    out = fused_cwp_pallas(x, wf.astype(x.dtype), s, bias, kh=kh, kw=kw,
-                           stride=stride, pb=pb, mb=mb, bb=bb,
-                           interpret=interpret)
+    s = jnp.ones((m,), jnp.float32) if scale is None else scale
+    out = fused_cwp_pallas(x, w, s, bias, stride=stride, pb=pb, mb=mb,
+                           bb=bb, interpret=interpret)
     return out[:bsz, :, :po, :]
 
 
@@ -108,9 +99,9 @@ def fused_conv_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
         tiles["mb"] = mb
     if bb is not None:
         tiles["bb"] = bb
-    # mb must divide M (grid constraint); pb and bb are free — ragged Po
+    # mb must divide M and obey the block rule; pb and bb are free — ragged Po
     # and B are padded
-    tiles["mb"] = largest_divisor(m, tiles["mb"])
+    tiles["mb"] = legal_block(m, tiles["mb"], SUBLANE)
     tiles["pb"] = max(1, tiles["pb"])
     tiles["bb"] = max(1, min(tiles["bb"], x.shape[0]))
     return _fused_cwp_jit(x, w, b, scale, stride=tuple(stride),

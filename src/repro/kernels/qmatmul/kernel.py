@@ -32,9 +32,13 @@ def _qmatmul_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # integer products are exact at any precision; an explicit DEFAULT
+    # keeps an ambient fp32 matmul-precision setting (which Mosaic rejects
+    # for int8 operands) from reaching the kernel
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32,
     )
 

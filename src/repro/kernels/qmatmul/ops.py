@@ -5,7 +5,7 @@ QTensor layout from core.quantize). ``qdense`` is the kernel-flavored
 convenience path (fp activations in, int8 weights, fp out); the
 policy-routed equivalent lives in ``repro.ops.qdense``.
 
-Block sizes come from the shared tiling layer (largest divisors of the
+Block sizes come from the shared tiling layer (legal divisors of the
 MXU-native 128 caps — the int8 GEMM does not pad); interpret mode
 auto-detects via ExecPolicy (interpret only off-TPU).
 """
@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from repro.core.quantize import QTensor, quantize_int8
 from repro.kernels.qmatmul.kernel import qmatmul_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import choose_qmatmul_blocks, largest_divisor, tile_params
+from repro.ops.tiling import (choose_qmatmul_blocks, legal_qmatmul_tiles,
+                              tile_params)
 
 
 @functools.partial(jax.jit,
@@ -48,10 +49,9 @@ def qmatmul(x_codes: jax.Array, w_codes: jax.Array,
     tiles = tile_params("qmatmul", (m, k, n), x_codes.dtype,
                         choose_qmatmul_blocks(m, n, k), pol.tile_overrides)
     # grid blocks must divide their dims exactly (the kernel never pads)
-    bm = largest_divisor(m, tiles["bm"])
-    bn = largest_divisor(n, tiles["bn"])
-    bk = largest_divisor(k, tiles["bk"])
-    return _qmatmul_jit(x_codes, w_codes, xs, ws, bm=bm, bn=bn, bk=bk,
+    # and obey the block rule
+    return _qmatmul_jit(x_codes, w_codes, xs, ws,
+                        **legal_qmatmul_tiles(m, n, k, tiles),
                         out_dtype=out_dtype, interpret=interpret)
 
 

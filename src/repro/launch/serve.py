@@ -14,9 +14,30 @@ refused with the typed ``QueueFullError`` and reported as rejected.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 
 import jax
 import numpy as np
+
+# fixed in-checkout location of JAX's persistent compilation cache when
+# JAX_COMPILATION_CACHE_DIR is not set (the path is part of the cache key,
+# so it must not move between runs)
+DEFAULT_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[3] / \
+    ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is left
+    to JAX (it reads the variable itself); otherwise the cache lives at
+    ``DEFAULT_COMPILE_CACHE``. Called by entry points only — importing
+    ``repro`` never touches the cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def _load_tuning_cache(path) -> None:
@@ -47,12 +68,40 @@ def _save_tuning_cache(path) -> None:
     print(f"tuning cache: saved {len(TUNING_CACHE)} entries to {path}")
 
 
-def _frontend(adapter, args, clock):
+def _frontend(adapter, clock, *, max_queue: int, slo_ms: float | None):
     from repro.serve import Frontend, FrontendConfig
-    max_queue = args.max_queue or max(args.requests, 64)
-    slo_s = args.slo_ms / 1e3 if args.slo_ms else None
+    slo_s = slo_ms / 1e3 if slo_ms else None
     return Frontend(adapter, FrontendConfig(max_queue=max_queue,
                                             slo_s=slo_s), clock)
+
+
+def build_vision_server(model, params, *, capacity: int, mesh=None,
+                        fixed_batch: bool = False, autotune: bool = False,
+                        artifact_dir: str | None = None,
+                        max_queue: int = 64, slo_ms: float | None = None):
+    """The vision serving stack this launcher runs: a ``VisionEngine``
+    over bucketed compiled plans (every ladder bucket compiled or
+    artifact-loaded here, at construction) behind the front-end.
+    Returns ``(engine, frontend, boot)`` with ``boot`` the time-to-ready
+    ``WarmupReport``. Dispatch binds at trace time, so the ambient
+    ``use_policy`` around this call is the policy the buckets serve
+    under (``chip_smoke.py`` pins the pallas backend that way)."""
+    from repro.artifact.warmup import collect_warmup
+    from repro.serve import (MonotonicClock, VisionAdapter, VisionEngine,
+                             VisionEngineConfig)
+    clock = MonotonicClock()
+    with collect_warmup() as boot:
+        # prewarm (on by default) compiles/loads EVERY ladder bucket here
+        engine = VisionEngine(
+            model, params,
+            VisionEngineConfig(batch=capacity, mesh=mesh,
+                               buckets=None if fixed_batch else "auto",
+                               autotune=autotune,
+                               artifact_dir=artifact_dir),
+            clock=clock)
+    frontend = _frontend(VisionAdapter(engine), clock, max_queue=max_queue,
+                         slo_ms=slo_ms)
+    return engine, frontend, boot
 
 
 def _submit_all(frontend, payloads, **options) -> int:
@@ -92,30 +141,24 @@ def _serve_vision(spec, model, args) -> None:
     ``--save-plan DIR`` writes the ladder back out for the next replica;
     ``--warmup-report`` prints the per-phase time-to-ready breakdown
     either way."""
-    from repro.artifact.warmup import collect_warmup
     from repro.launch.train import build_mesh
-    from repro.serve import (MonotonicClock, VisionAdapter, VisionEngine,
-                             VisionEngineConfig)
 
-    clock = MonotonicClock()
     mesh = None if args.mesh == "auto" else build_mesh(args.mesh)
     params = model.init(jax.random.PRNGKey(0))
-    with collect_warmup() as boot:
-        # prewarm (on by default) compiles/loads EVERY ladder bucket here
-        engine = VisionEngine(
-            model, params,
-            VisionEngineConfig(batch=args.capacity, mesh=mesh,
-                               buckets=None if args.fixed_batch else "auto",
-                               autotune=args.autotune,
-                               artifact_dir=args.plan_artifact),
-            clock=clock)
+    engine, frontend, boot = build_vision_server(
+        model, params, capacity=args.capacity, mesh=mesh,
+        fixed_batch=args.fixed_batch, autotune=args.autotune,
+        artifact_dir=args.plan_artifact,
+        max_queue=args.max_queue or max(args.requests, 64),
+        slo_ms=args.slo_ms)
+    clock = frontend.clock
     plan = engine.plan
     sharded = "" if mesh is None else (
         f", {plan.num_sharded()} sharded stages over "
         f"mesh={dict(mesh.shape)}")
     tuned = ""
     if args.autotune:
-        baked = engine._bounds[args.capacity].tuned
+        baked = engine.bound(args.capacity).tuned
         tuned = f", {len(baked)} autotuned stages"
     print(f"arch={args.arch} vision path: compiled plan with "
           f"{plan.num_fused()} fused conv blocks, quant={plan.quant}"
@@ -136,7 +179,6 @@ def _serve_vision(spec, model, args) -> None:
             print(f"saved plan artifact {args.save_plan}/{name} "
                   f"fingerprint={fp[:16]}")
 
-    frontend = _frontend(VisionAdapter(engine), args, clock)
     rng = np.random.RandomState(1)
     shape = model.input_shape()[1:]
     shed = _submit_all(frontend,
@@ -210,6 +252,7 @@ def main() -> None:
                              MonotonicClock)
     from repro.sharding.logical import DEFAULT_RULES, ShardingCtx
 
+    print(f"compile cache: {enable_compile_cache()}")
     _load_tuning_cache(args.tuning_cache)
     spec = get_arch(args.arch)
     model = spec.model()
@@ -232,7 +275,9 @@ def main() -> None:
                     EngineConfig(capacity=args.capacity, max_seq=max_seq,
                                  kv_quant=args.kv_quant),
                     ctx, clock=clock)
-    frontend = _frontend(LMAdapter(engine), args, clock)
+    frontend = _frontend(LMAdapter(engine), clock,
+                         max_queue=args.max_queue or max(args.requests, 64),
+                         slo_ms=args.slo_ms)
 
     # mixed-length synthetic workload: jittered prompts, fixed budget
     rng = np.random.RandomState(1)

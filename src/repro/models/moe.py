@@ -22,10 +22,10 @@ import jax
 import jax.numpy as jnp
 
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import ACTIVATIONS, dense_init
-from repro.sharding.compat import shard_map
 from repro.sharding.logical import A, ShardingCtx, shard
 
 __all__ = ["MoEConfig", "moe_init", "moe_axes", "moe_apply"]

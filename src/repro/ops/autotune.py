@@ -44,9 +44,11 @@ from typing import Callable, Mapping
 import jax
 
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (TUNING_CACHE, choose_conv_blocks,
-                              choose_fused_blocks, choose_qmatmul_blocks,
-                              conv_signature, largest_divisor)
+from repro.ops.tiling import (SUBLANE, TUNING_CACHE, VMEM_BUDGET_BYTES,
+                              choose_conv_blocks, choose_fused_blocks,
+                              choose_qmatmul_blocks, conv_signature,
+                              legal_block, legal_qmatmul_tiles,
+                              window_vmem_bytes)
 
 __all__ = ["ensure_tuned", "tune_conv2d", "tune_fused_conv_block",
            "tune_qmatmul", "tune_stream_conv2d",
@@ -91,7 +93,7 @@ def _measure(fn: Callable, *args, warmup: int | None = None,
 def _axis_candidates(op: str, x_shape, w_shape, stride,
                      heuristic: Mapping[str, int]) -> dict[str, list[int]]:
     """Per-axis candidate values for the conv families, heuristic point
-    included, clamped to valid ranges and deduped."""
+    included, clamped to valid ranges and legal blocks, deduped."""
     bsz, _, h, _ = x_shape
     m, _, kh, _ = w_shape
     ho = (h - kh) // stride[0] + 1
@@ -105,21 +107,35 @@ def _axis_candidates(op: str, x_shape, w_shape, stride,
     else:
         rbs = {r for r in ROW_BLOCKS if r <= ho} | {heuristic["rb"], ho}
         axes["rb"] = sorted(rbs)
-    mbs = {largest_divisor(m, cap) for cap in CHANNEL_CAPS}
+    mbs = {legal_block(m, cap, SUBLANE) for cap in CHANNEL_CAPS}
     mbs.add(heuristic["mb"])
     axes["mb"] = sorted(mbs)
     return axes
 
 
+def _window_fits(op: str, x_shape, w_shape, stride, itemsize: int
+                 ) -> Callable[[Mapping[str, int]], bool]:
+    """Whether a candidate's grid step fits the VMEM budget — the tuner
+    only ever launches tiles the compiler would accept."""
+    _, n, _, w = x_shape
+    _, _, kh, kw = w_shape
+    pooled = op == "fused_conv_block"
+    rows = "pb" if pooled else "rb"
+    return lambda t: window_vmem_bytes(
+        n, w, kh, kw, stride, t["mb"], t[rows], t["bb"], itemsize,
+        pooled=pooled) <= VMEM_BUDGET_BYTES
+
+
 def _descend(axes: dict[str, list[int]], start: dict[str, int],
              launch: Callable[..., Callable], *,
+             fits: Callable[[Mapping[str, int]], bool] | None = None,
              on_point: Callable[[dict, float], None] | None = None
              ) -> dict[str, int]:
     """Coordinate descent: sweep each axis in insertion order holding the
     others at the current best. A candidate displaces the incumbent only
     when it measures at least ``MIN_GAIN`` faster — the heuristic start
     point survives noise-level "wins". ``launch(**tiles)`` returns a
-    zero-arg timed callable."""
+    zero-arg timed callable; candidates ``fits`` rejects are skipped."""
     measured: dict[tuple, float] = {}
 
     def probe(cand: dict[str, int]) -> float:
@@ -136,6 +152,8 @@ def _descend(axes: dict[str, list[int]], start: dict[str, int],
     for axis, values in axes.items():
         for v in values:
             cand = {**best, axis: v}
+            if fits is not None and not fits(cand):
+                continue
             us = probe(cand)
             if us < best_us * (1.0 - MIN_GAIN):
                 best, best_us = cand, us
@@ -183,7 +201,9 @@ def tune_conv2d(x, w, b=None, *, stride=(1, 1),
         return lambda: conv2d_window(x, w, b, stride=tuple(stride),
                                      policy=pol, **tiles)
 
-    best = _descend(axes, heur, launch, on_point=on_point)
+    fits = _window_fits("conv2d", x.shape, w.shape, tuple(stride),
+                        x.dtype.itemsize)
+    best = _descend(axes, heur, launch, fits=fits, on_point=on_point)
     sig = conv_signature(x.shape, w.shape, tuple(stride))
     TUNING_CACHE.put("conv2d", sig, x.dtype, best)
     return best
@@ -207,7 +227,9 @@ def tune_fused_conv_block(x, w, b=None, *, stride=(1, 1), scale=None,
         return lambda: fused_conv_window(x, w, b, stride=tuple(stride),
                                          scale=scale, policy=pol, **tiles)
 
-    best = _descend(axes, heur, launch, on_point=on_point)
+    fits = _window_fits("fused_conv_block", x.shape, w.shape,
+                        tuple(stride), x.dtype.itemsize)
+    best = _descend(axes, heur, launch, fits=fits, on_point=on_point)
     sig = conv_signature(x.shape, w.shape, tuple(stride))
     TUNING_CACHE.put("fused_conv_block", sig, x.dtype, best)
     return best
@@ -218,21 +240,17 @@ def tune_qmatmul(x_codes, w_codes, x_scale, w_scale, *,
                  on_point=None) -> dict[str, int]:
     """Measure (bm, bn, bk) candidates for the blocked int8 GEMM; cache
     and return the winner. The kernel never pads, so candidate caps clamp
-    to the largest divisor of each dim (duplicates deduped by the axis
-    candidate sets)."""
+    to legal blocks of each dim (duplicates deduped by the axis candidate
+    sets)."""
     from repro.kernels.qmatmul.ops import qmatmul
     pol = _no_autotune(policy)
     m, k = x_codes.shape
     _, n = w_codes.shape
     heur = choose_qmatmul_blocks(m, n, k)
-    axes = {
-        "bm": sorted({largest_divisor(m, c) for c in QMM_CAPS}
-                     | {heur["bm"]}),
-        "bn": sorted({largest_divisor(n, c) for c in QMM_CAPS}
-                     | {heur["bn"]}),
-        "bk": sorted({largest_divisor(k, c) for c in QMM_CAPS}
-                     | {heur["bk"]}),
-    }
+    legal = [legal_qmatmul_tiles(m, n, k, {"bm": c, "bn": c, "bk": c})
+             for c in QMM_CAPS]
+    axes = {kk: sorted({t[kk] for t in legal} | {heur[kk]})
+            for kk in ("bm", "bn", "bk")}
 
     def launch(**tiles):
         pol_t = pol.with_options(
@@ -327,9 +345,7 @@ def heuristic_tiles(op: str, *args, **kwargs) -> dict[str, int] | None:
     if op == "qmatmul":
         m, k = args[0].shape
         n = args[1].shape[1]
-        heur = choose_qmatmul_blocks(m, n, k)
-        return {kk: largest_divisor({"bm": m, "bn": n, "bk": k}[kk], v)
-                for kk, v in heur.items()}
+        return choose_qmatmul_blocks(m, n, k)
     if op in _STREAM_INNER:
         tiling = kwargs.get("tiling")
         return None if tiling is None else {"th": int(tiling.tile_rows)}
@@ -341,7 +357,6 @@ def heuristic_tiles(op: str, *args, **kwargs) -> dict[str, int] | None:
                else choose_conv_blocks)
     heur = chooser(x.shape[1], x.shape[2], x.shape[3], w.shape[0],
                    w.shape[2], w.shape[3], stride, x.dtype.itemsize)
-    heur["mb"] = largest_divisor(w.shape[0], heur["mb"])
     heur["bb"] = max(1, min(heur["bb"], x.shape[0]))
     return heur
 

@@ -337,8 +337,8 @@ def dense(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
     Under ``quant="int8"`` the contraction runs on the real int8 datapath
     (per-output-channel weight scales, per-token activation scales, int32
     accumulation via the ``qmatmul`` family); ``"qformat"`` snaps operands
-    and result to the Qm.n lattice; ``"none"`` is a plain einsum. This is
-    how model layers (``models/layers.py`` MLPs) pick up quantized serving
+    and result to the Qm.n lattice; ``"none"`` is a plain einsum at HIGHEST
+    precision. This is how model layers (``models/layers.py`` MLPs) pick up quantized serving
     from one ``use_policy`` block instead of threading flags.
     """
     pol = policy if policy is not None else current_policy()
@@ -358,7 +358,11 @@ def dense(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
         # same discipline as conv2d's qformat path
         q = pol.qformat
         out = q.quantize(jnp.einsum("...d,df->...f", q.quantize(x),
-                                    q.quantize(w)))
+                                    q.quantize(w),
+                                    precision=jax.lax.Precision.HIGHEST))
         return out if b is None else q.quantize(out + q.quantize(b))
-    out = jnp.einsum("...d,df->...f", x, w)
+    # fp32-accurate on a TPU too (its default is one bf16 pass), like the
+    # conv kernels; bf16 operands are unaffected
+    out = jnp.einsum("...d,df->...f", x, w,
+                     precision=jax.lax.Precision.HIGHEST)
     return out if b is None else out + b

@@ -32,14 +32,23 @@ from typing import Mapping
 
 import numpy as np
 
-__all__ = ["largest_divisor", "padded_block", "choose_conv_blocks",
+__all__ = ["legal_block", "window_vmem_bytes", "choose_conv_blocks",
            "choose_fused_blocks", "choose_qmatmul_blocks",
+           "legal_qmatmul_tiles",
            "choose_tree_rows", "TuningCache", "TUNING_CACHE", "tile_params",
            "conv_signature", "SCHEMA_VERSION"]
 
-# VMEM working-set budget per grid step (v5e has 128 MiB VMEM per core;
-# stay well under to leave room for double buffering).
+# VMEM one grid step may occupy, double-buffered blocks included. The
+# Mosaic compiler's default scoped-VMEM limit on v5e is 16 MiB; half of it
+# leaves room for in-kernel temporaries.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
+# Mosaic's block rule: each of a block's last two dims is a multiple of
+# the hardware tile (SUBLANE rows × LANE lanes for 32-bit data, 32 rows
+# for int8) or the whole array dim.
+SUBLANE = 8
+LANE = 128
+INT8_SUBLANE = 32
 
 # version of the persisted tuning-cache JSON schema (bumped when the key or
 # row layout changes; older/newer files fall back to heuristics on load)
@@ -51,22 +60,21 @@ def _platform() -> str:
     return jax.default_backend()
 
 
-def largest_divisor(dim: int, cap: int) -> int:
-    """Largest divisor of ``dim`` that is <= cap (no power-of-two padding —
-    the paper's odd-even rule applied to blocking)."""
-    b = min(cap, dim)
-    while dim % b:
-        b -= 1
-    return b
+def legal_block(dim: int, cap: int, align: int) -> int:
+    """Block size for a dim that sits on one of a block's last two axes:
+    the whole dim when it fits ``cap``, else the largest divisor <= cap
+    that is a multiple of ``align`` (the hardware tile), else the whole
+    dim — the only sizes Mosaic's block rule accepts."""
+    if dim <= cap:
+        return dim
+    for b in range(cap // align * align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim
 
 
-def padded_block(dim: int, cap: int) -> tuple[int, int]:
-    """(block, padded_dim): block = min(cap, dim), dim rounded up to a
-    multiple of block. For kernels that pad the ragged tail and slice —
-    avoids the divisor search degenerating to block=1 on primes."""
-    block = min(cap, dim)
-    padded = -(-dim // block) * block
-    return block, padded
+def _round_up(v: int, a: int) -> int:
+    return -(-v // a) * a
 
 
 def conv_signature(x_shape, w_shape, stride) -> tuple[int, ...]:
@@ -79,31 +87,57 @@ def conv_signature(x_shape, w_shape, stride) -> tuple[int, ...]:
     return (bsz, n, h, w, m, kh, kw, *stride)
 
 
+def window_vmem_bytes(n: int, w: int, kh: int, kw: int,
+                      stride: tuple[int, int], mb: int, rows: int, bb: int,
+                      itemsize: int, *, pooled: bool) -> int:
+    """VMEM one grid step of a window kernel (kernels/conv_window,
+    kernels/fused_cwp) occupies in their (8, 128)-tiled layouts: the
+    halo'd slab (B, rows_in, P, N, W/P), the per-kernel-row weights (Kh,
+    MB, Kw·N), the per-channel scale/bias columns and the output block, all
+    double-buffered by the Pallas pipeline. ``rows`` counts the block's
+    output rows — pooled rows when ``pooled``, each covering two conv rows
+    and splitting the columns into even/odd phases."""
+    sh, sw = stride
+    groups = 2 if pooled else 1
+    phases = groups * sw
+    rows_in = (groups * rows - 1) * sh + kh
+    wo = (w - kw) // sw + 1
+    slab = (bb * rows_in * phases * _round_up(n, SUBLANE)
+            * _round_up(-(-w // phases), LANE))
+    taps = kh * _round_up(mb, SUBLANE) * _round_up(kw * n, LANE)
+    vecs = 2 * _round_up(mb, SUBLANE) * LANE
+    out = bb * rows * _round_up(mb, SUBLANE) * _round_up(wo // groups, LANE)
+    return 2 * (slab + taps + vecs + out) * itemsize
+
+
+def _largest_rows(n, w, kh, kw, stride, mb, itemsize, full, *, pooled):
+    """Largest row block in [1, full] whose grid step fits the budget
+    (1 is the floor — a single-row block is always issued)."""
+    best = 1
+    for rows in range(1, full + 1):
+        if window_vmem_bytes(n, w, kh, kw, stride, mb, rows, 1, itemsize,
+                             pooled=pooled) > VMEM_BUDGET_BYTES:
+            break
+        best = rows
+    return best
+
+
 def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
                        stride: tuple[int, int], itemsize: int
                        ) -> dict[str, int]:
     """Heuristic (rb, mb, bb) for the window-stationary conv kernel.
 
-    Budget: slab n*rows_in*w + im2col η*rb*wo + weights η*mb + out mb*rb*wo.
-    Prefer mb = min(m, 128) (MXU lane width) then grow rb. ``bb`` (images
-    per grid step) stays 1 here — batching the grid trades VMEM for weight
-    reuse, a measured decision left to the autotuner (DESIGN.md §10).
+    mb = min(m, 128) (MXU lane width) rounded to a legal block, then the
+    largest rb whose grid step fits ``VMEM_BUDGET_BYTES``
+    (``window_vmem_bytes``). Rows sit on a leading block axis, so any rb
+    is legal. ``bb`` (images per grid step) stays 1 here — batching the
+    grid trades VMEM for weight reuse, a measured decision left to the
+    autotuner (DESIGN.md §10).
     """
-    sh, sw = stride
-    ho = (h - kh) // sh + 1
-    wo = (w - kw) // sw + 1
-    eta = n * kh * kw
-    mb = largest_divisor(m, 128)
-    best = 1
-    for rb in range(1, ho + 1):
-        rows_in = (rb - 1) * sh + kh
-        bytes_needed = (n * rows_in * w + eta * rb * wo
-                        + eta * mb + mb * rb * wo) * itemsize
-        if bytes_needed <= VMEM_BUDGET_BYTES:
-            best = rb
-        else:
-            break
-    return {"rb": best, "mb": mb, "bb": 1}
+    ho = (h - kh) // stride[0] + 1
+    mb = legal_block(m, 128, SUBLANE)
+    rb = _largest_rows(n, w, kh, kw, stride, mb, itemsize, ho, pooled=False)
+    return {"rb": rb, "mb": mb, "bb": 1}
 
 
 def choose_fused_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
@@ -111,36 +145,29 @@ def choose_fused_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
                         ) -> dict[str, int]:
     """Heuristic (pb, mb, bb) for the fused conv+relu+pool kernel
     (kernels/fused_cwp). ``pb`` counts *pooled* rows: one block covers
-    2·pb conv rows, so the budget carries the pre-pool activation tile
-    (mb × 2·pb × wo) that fusion keeps out of HBM. ``bb`` defaults to 1
-    (see ``choose_conv_blocks``); the autotuner measures larger values."""
-    sh, _ = stride
-    ho = (h - kh) // sh + 1
-    wo = (w - kw) // stride[1] + 1
-    po = max(ho // 2, 1)
-    eta = n * kh * kw
-    mb = largest_divisor(m, 128)
-    best = 1
-    for pb in range(1, po + 1):
-        rb = 2 * pb
-        rows_in = (rb - 1) * sh + kh
-        bytes_needed = (n * rows_in * w + eta * rb * wo
-                        + eta * mb + mb * rb * wo
-                        + mb * pb * (wo // 2)) * itemsize
-        if bytes_needed <= VMEM_BUDGET_BYTES:
-            best = pb
-        else:
-            break
-    return {"pb": best, "mb": mb, "bb": 1}
+    2·pb conv rows. ``bb`` defaults to 1 (see ``choose_conv_blocks``);
+    the autotuner measures larger values."""
+    po = max(((h - kh) // stride[0] + 1) // 2, 1)
+    mb = legal_block(m, 128, SUBLANE)
+    pb = _largest_rows(n, w, kh, kw, stride, mb, itemsize, po, pooled=True)
+    return {"pb": pb, "mb": mb, "bb": 1}
 
 
 def choose_qmatmul_blocks(m: int, n: int, k: int) -> dict[str, int]:
     """int8 MXU-native tiling: sublane×lane = 32×128 for int8 on TPU;
-    largest divisors <= 128 per dim (blocks must divide — the int8 GEMM
-    does not pad)."""
-    return {"bm": largest_divisor(m, 128),
-            "bn": largest_divisor(n, 128),
-            "bk": largest_divisor(k, 128)}
+    blocks of at most 128 per dim that divide it (the int8 GEMM does not
+    pad) and satisfy the block rule."""
+    return legal_qmatmul_tiles(m, n, k, {"bm": 128, "bn": 128, "bk": 128})
+
+
+def legal_qmatmul_tiles(m: int, n: int, k: int,
+                        caps: Mapping[str, int]) -> dict[str, int]:
+    """Clamp requested (bm, bn, bk) caps to legal blocks: bm is the int8
+    x block's sublane dim, bk its lane dim (and the weight block's
+    sublanes), bn the weight/output lane dim."""
+    return {"bm": legal_block(m, caps["bm"], INT8_SUBLANE),
+            "bn": legal_block(n, caps["bn"], LANE),
+            "bk": legal_block(k, caps["bk"], LANE)}
 
 
 def choose_tree_rows(r: int, cap: int = 256) -> dict[str, int]:

@@ -135,6 +135,9 @@ class VisionEngine:
         self._bounds: dict[int, object] = {}    # bucket -> BoundPlan
         # bucket -> "artifact+aot" | "artifact" | "fresh" (boot telemetry)
         self.plan_source: dict[int, str] = {}
+        # bucket -> seconds from compile (or artifact load) start to the
+        # warm first dispatch (boot telemetry)
+        self.ready_s: dict[int, float] = {}
         self._store = None
         if config.artifact_dir is not None:
             from repro.artifact.store import PlanStore
@@ -187,6 +190,7 @@ class VisionEngine:
         ``VisionStats.wall_s`` measures serving only."""
         from repro.artifact.aot import aot_compile
         from repro.artifact.warmup import phase
+        t0 = self.clock.now()
         shape = (bucket, *self.model.input_shape()[1:])
         bound = exe = None
         source = "fresh"
@@ -215,7 +219,17 @@ class VisionEngine:
         warm = jnp.zeros(shape, jnp.float32)
         with phase("first_dispatch"):
             jax.block_until_ready(exe(warm))
+        self.ready_s[bucket] = self.clock.now() - t0
         return bound.plan
+
+    def executable(self, bucket: int):
+        """The compiled (AOT) program serving ``bucket`` — its
+        ``as_text()`` shows what runs on the device."""
+        return self._steps[bucket]
+
+    def bound(self, bucket: int):
+        """The ``BoundPlan`` behind ``bucket`` (folded, placed weights)."""
+        return self._bounds[bucket]
 
     def save_artifacts(self, directory=None) -> dict[str, str]:
         """Persist every compiled bucket plan (+ its AOT executable) into

@@ -1,5 +1,4 @@
 """Logical-axis sharding system (MaxText-style rules -> PartitionSpec)."""
-from repro.sharding.compat import shard_map
 from repro.sharding.logical import (A, ShardingCtx, ShardingRules,
                                     DEFAULT_RULES, SP_DECODE_RULES,
                                     INPUT_PARALLEL_RULES, spec_for, shard,

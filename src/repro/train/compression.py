@@ -21,9 +21,8 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.sharding.compat import shard_map
 
 __all__ = ["compress_decompress", "error_feedback_compress",
            "cross_pod_grad_reduce", "init_ef_state"]

@@ -133,7 +133,28 @@ class TestCacheScoping:
         assert got["rb"] == 1
 
     def test_cache_entry_steers_the_launch(self, monkeypatch):
-        """A tuned entry must actually reach the kernel launch."""
+        """A tuned entry must actually reach the kernel launch (every
+        cached value off the heuristic point: M=16 defaults to mb=16,
+        and mb=8 is a legal block — a multiple of the 8-row tile)."""
+        import repro.kernels.fused_cwp.ops as fops
+        seen = {}
+        real = fops._fused_cwp_jit
+
+        def spy(*args, **kwargs):
+            seen.update(kwargs)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(fops, "_fused_cwp_jit", spy)
+        w16 = jax.random.normal(jax.random.PRNGKey(1), (16, 3, 3, 3))
+        sig = conv_signature(X.shape, w16.shape, (1, 1))
+        TUNING_CACHE.put("fused_conv_block", sig, X.dtype,
+                         {"pb": 2, "mb": 8, "bb": 5})
+        fused_conv_window(X, w16, None)
+        assert (seen["pb"], seen["mb"], seen["bb"]) == (2, 8, 5)
+
+    def test_illegal_cached_channel_block_is_legalized(self, monkeypatch):
+        """A cached mb that breaks the block rule (not a multiple of 8,
+        not all of M) is clamped to a legal block, never launched."""
         import repro.kernels.fused_cwp.ops as fops
         seen = {}
         real = fops._fused_cwp_jit
@@ -147,7 +168,7 @@ class TestCacheScoping:
         TUNING_CACHE.put("fused_conv_block", sig, X.dtype,
                          {"pb": 2, "mb": 4, "bb": 5})
         fused_conv_window(X, W, B)
-        assert (seen["pb"], seen["mb"], seen["bb"]) == (2, 4, 5)
+        assert (seen["pb"], seen["mb"], seen["bb"]) == (2, 8, 5)
 
 
 # ------------------------------------------------------------- numerics

@@ -13,11 +13,7 @@ def _mesh(shape=(2, 2), axes=("data", "model")):
     # a fake mesh over the single CPU device repeated is not allowed;
     # use an abstract mesh for spec resolution (spec_for only needs names
     # and sizes, not devices).
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)      # jax >= 0.5
-    except TypeError:
-        # jax 0.4.x signature: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 class TestSpecFor:
