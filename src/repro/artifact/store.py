@@ -60,7 +60,8 @@ from repro.artifact.ir_codec import graph_from_doc, graph_to_doc
 from repro.core.quantize import QFormat, QTensor
 
 __all__ = ["ArtifactError", "ArtifactStaleError", "PlanArtifact",
-           "save_plan", "load_plan", "PlanStore", "MANIFEST", "PAYLOADS"]
+           "save_plan", "load_plan", "PlanStore", "compile_program",
+           "MANIFEST", "PAYLOADS"]
 
 MANIFEST = "manifest.json"
 PAYLOADS = "payloads.npz"
@@ -210,6 +211,19 @@ def _batch_sharding(plan, input_shape):
         mesh, P("data", *[None] * (len(input_shape) - 1)))
 
 
+def compile_program(bound, input_shape, dtype="float32"):
+    """AOT-compile ``bound`` for one batch shape as the function
+    ``vision_b<batch>``, lowered with the data-axis input placement. jit
+    names the module after the function (``jit_vision_b<batch>``), so a
+    profiler trace tells the bucket programs apart."""
+    def program(x):
+        return bound(x)
+
+    program.__name__ = program.__qualname__ = f"vision_b{input_shape[0]}"
+    return aot_compile(program, input_shape, dtype,
+                       sharding=_batch_sharding(bound.plan, input_shape))
+
+
 # ---------------------------------------------------------------------------
 # save
 
@@ -236,8 +250,7 @@ def save_plan(bound, path, *, input_shapes=None, aot: bool = True) -> str:
     aot_blobs: list[bytes] = []
     if aot:
         for shape in input_shapes:
-            compiled = aot_compile(lambda x: bound(x), shape,
-                                   sharding=_batch_sharding(plan, shape))
+            compiled = compile_program(bound, shape)
             blob = serialize_compiled(compiled)
             if blob is None:        # backend can't serialize: IR-only
                 aot_index.clear()
@@ -343,9 +356,7 @@ class PlanArtifact:
             return exe
         bound = self.bound
         with warmup.phase("compile"):
-            compiled = aot_compile(
-                lambda x: bound(x), input_shape, dtype,
-                sharding=_batch_sharding(bound.plan, input_shape))
+            compiled = compile_program(bound, input_shape, dtype)
         cache_executable(
             executable_key(self.fingerprint, input_shape, dtype), compiled)
         return compiled

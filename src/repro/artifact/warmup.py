@@ -7,17 +7,19 @@ module is the measuring tape: an ambient ``WarmupReport`` (contextvar,
 so threaded engines and jit trace-time code both see it) that the
 compile pipeline writes into through ``phase(name)`` blocks.
 
-Outside a ``collect_warmup()`` block every ``phase`` is a no-op with no
-ambient state touched, so the hooks in ``repro.graph.plan`` and
-``repro.serve.vision`` cost nothing on the hot path.
+Outside a ``collect_warmup()`` block every ``phase`` touches no ambient
+state, so the hooks in ``repro.graph.plan`` and ``repro.serve.vision``
+cost nothing on the hot path. Every ``phase`` also opens the program's
+span ``boot.<name>`` (``repro.spans``), so a profiler trace of a boot
+(``launch/serve.py --profile-dir``) shows its phases.
 
 ``launch/serve.py --warmup-report`` prints the breakdown; a replica
 booted with ``--plan-artifact`` must show ``trace``/``fuse``/``place``/
 ``tune`` at 0 calls — that is the asserted "zero-compilation boot".
 
-This module is intentionally stdlib-only: it sits below the graph
-compiler in the import graph (``repro.graph.plan`` imports it), while
-the rest of ``repro.artifact`` sits above.
+This module imports only the stdlib and ``repro.spans``: it sits below
+the graph compiler in the import graph (``repro.graph.plan`` imports
+it), while the rest of ``repro.artifact`` sits above.
 """
 from __future__ import annotations
 
@@ -25,6 +27,8 @@ import contextlib
 import contextvars
 import time
 from dataclasses import dataclass, field
+
+from repro.spans import span
 
 __all__ = ["PHASES", "WarmupReport", "collect_warmup", "phase",
            "current_report"]
@@ -97,13 +101,15 @@ def collect_warmup():
 @contextlib.contextmanager
 def phase(name: str):
     """Attribute the block's wall time to ``name`` in the ambient report
-    (no-op when no ``collect_warmup`` is active)."""
+    (none when no ``collect_warmup`` is active), inside the span
+    ``boot.<name>``."""
     report = _ACTIVE.get()
-    if report is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        report.add(name, time.perf_counter() - t0)
+    with span(f"boot.{name}"):
+        if report is None:
+            yield
+            return
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            report.add(name, time.perf_counter() - t0)

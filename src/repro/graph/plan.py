@@ -142,7 +142,7 @@ class ExecutionPlan:
             ref = getattr(node, attr)
             return None if ref is None else ref.fetch(params)
 
-        def _conv_stage(node, fused: bool):
+        def _conv_stage(node, fused: bool, sid: str):
             xin = env[node.inputs[0]]
             wv = _weight(node, 1, "w")
             bv = _weight(node, 2, "b")
@@ -161,13 +161,15 @@ class ExecutionPlan:
                         if fused:
                             return stream_fused_conv_block(
                                 xl, wl, bl, stride=node.stride,
-                                odd=node.odd, tiling=tiling, policy=pol)
+                                odd=node.odd, tiling=tiling, policy=pol,
+                                stage=sid)
                         return stream_conv2d(xl, wl, bl, stride=node.stride,
                                              tiling=tiling, policy=pol)
                     if fused:
                         return fused_conv_block(xl, wl, bl,
                                                 stride=node.stride,
-                                                odd=node.odd, policy=pol)
+                                                odd=node.odd, policy=pol,
+                                                stage=sid)
                     return conv2d(xl, wl, bl, stride=node.stride, policy=pol)
 
                 return self._batch_parallel(stage, xin, wv, bv)
@@ -189,27 +191,26 @@ class ExecutionPlan:
                 stride=node.stride, scale=scale, data_axis=daxis,
                 icp=ki, ocp=ko, policy=base)
 
-        for node in self.graph:
+        def _node(node, sid: str):
             if isinstance(node, InputNode):
-                env[node.id] = self._scatter(x)
-            elif isinstance(node, QuantizeNode):
+                return self._scatter(x)
+            if isinstance(node, QuantizeNode):
                 if node.id in folded:
-                    env[node.id] = folded[node.id]
-                    continue
+                    return folded[node.id]
                 val = (node.ref.fetch(params) if node.constant
                        else env[node.inputs[0]])
-                env[node.id] = _apply_quantize(node, val, self.qformat)
-            elif isinstance(node, (Conv2DNode, FusedConvBlockNode)):
-                env[node.id] = _conv_stage(
-                    node, isinstance(node, FusedConvBlockNode))
-            elif isinstance(node, ReluNode):
-                env[node.id] = jax.nn.relu(env[node.inputs[0]])
-            elif isinstance(node, MaxPool2Node):
-                env[node.id] = maxpool2(env[node.inputs[0]], odd=node.odd)
-            elif isinstance(node, FlattenNode):
+                return _apply_quantize(node, val, self.qformat)
+            if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
+                return _conv_stage(
+                    node, isinstance(node, FusedConvBlockNode), sid)
+            if isinstance(node, ReluNode):
+                return jax.nn.relu(env[node.inputs[0]])
+            if isinstance(node, MaxPool2Node):
+                return maxpool2(env[node.inputs[0]], odd=node.odd)
+            if isinstance(node, FlattenNode):
                 v = self._gather(env[node.inputs[0]])
-                env[node.id] = v.reshape(v.shape[0], -1)
-            elif isinstance(node, DenseNode):
+                return v.reshape(v.shape[0], -1)
+            if isinstance(node, DenseNode):
                 wq = folded.get(node.id)
                 b = _weight(node, 2, "b")
                 if wq is not None:
@@ -221,14 +222,18 @@ class ExecutionPlan:
                         lambda xl, wl: qdense(xl, wl, out_dtype=xl.dtype,
                                               policy=pol),
                         env[node.inputs[0]], wq)
-                    env[node.id] = out if b is None else out + b
-                else:
-                    pol = self._stage_policy(dense_pol, tuned.get(node.id))
-                    env[node.id] = self._batch_parallel(
-                        lambda xl, wl, bl: dense(xl, wl, bl, policy=pol),
-                        env[node.inputs[0]], _weight(node, 1, "w"), b)
-            else:
-                raise TypeError(f"no executor for node {node.pretty()}")
+                    return out if b is None else out + b
+                pol = self._stage_policy(dense_pol, tuned.get(node.id))
+                return self._batch_parallel(
+                    lambda xl, wl, bl: dense(xl, wl, bl, policy=pol),
+                    env[node.inputs[0]], _weight(node, 1, "w"), b)
+            raise TypeError(f"no executor for node {node.pretty()}")
+
+        # stage i (``self.stages()[i]``) runs under the scope s<i>.<op>,
+        # which the compiled program's op metadata carries
+        for i, node in enumerate(self.graph):
+            with jax.named_scope(f"s{i}.{node.op}"):
+                env[node.id] = _node(node, f"s{i}")
         return env[self.graph.output_id]
 
     def _scatter(self, x):

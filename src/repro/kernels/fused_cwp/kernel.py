@@ -94,7 +94,7 @@ def _fused_cwp_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, *,
 def fused_cwp_pallas(x: jax.Array, w: jax.Array, s: jax.Array,
                      b: jax.Array, *, stride: tuple[int, int],
                      pb: int, mb: int, bb: int = 1,
-                     interpret: bool) -> jax.Array:
+                     interpret: bool, name: str) -> jax.Array:
     """Launch. x: (B, N, H, W); w: (M, N, Kh, Kw); s: (M,) requant scales
     (ones when unquantized); b: (M,) bias.
 
@@ -102,6 +102,7 @@ def fused_cwp_pallas(x: jax.Array, w: jax.Array, s: jax.Array,
     images per grid step (weight reuse; the winner is measured, see
     repro.ops.autotune). Returns (B, M, Po, Wo/2) in x.dtype; requires
     even Ho/Wo, pb | Po, mb | M, bb | B (the wrapper pads/clamps).
+    ``name`` names the kernel's HLO instruction, and so its device events.
     """
     bsz, n, h, wdt = x.shape
     m, n2, kh, kw = w.shape
@@ -130,6 +131,7 @@ def fused_cwp_pallas(x: jax.Array, w: jax.Array, s: jax.Array,
                                lambda bi, pi, mi: (bi, pi, mi, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, po, m, wq), x.dtype),
         interpret=interpret,
+        name=name,
     )(xs, tap_weights(w).astype(x.dtype),
       s.reshape(m, 1).astype(jnp.float32), b.reshape(m, 1).astype(x.dtype))
     return out.transpose(0, 2, 1, 3)

@@ -27,12 +27,12 @@ from repro.ops.tiling import (SUBLANE, choose_fused_blocks, conv_signature,
                               legal_block, tile_params)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("stride", "interpret", "pb", "mb", "bb"))
+@functools.partial(jax.jit, static_argnames=("stride", "interpret", "pb",
+                                             "mb", "bb", "name"))
 def _fused_cwp_jit(x: jax.Array, w: jax.Array, b: jax.Array | None,
                    scale: jax.Array | None, *,
                    stride: tuple[int, int], interpret: bool,
-                   pb: int, mb: int, bb: int) -> jax.Array:
+                   pb: int, mb: int, bb: int, name: str) -> jax.Array:
     bsz, h = x.shape[0], x.shape[2]
     m, kh = w.shape[0], w.shape[2]
     sh = stride[0]
@@ -53,7 +53,7 @@ def _fused_cwp_jit(x: jax.Array, w: jax.Array, b: jax.Array | None,
     # bit-identical to the pre-epilogue kernel
     s = jnp.ones((m,), jnp.float32) if scale is None else scale
     out = fused_cwp_pallas(x, w, s, bias, stride=stride, pb=pb, mb=mb,
-                           bb=bb, interpret=interpret)
+                           bb=bb, interpret=interpret, name=name)
     return out[:bsz, :, :po, :]
 
 
@@ -64,13 +64,15 @@ def fused_conv_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
                       interpret: bool | None = None,
                       pb: int | None = None, mb: int | None = None,
                       bb: int | None = None,
+                      stage: str | None = None,
                       policy: ExecPolicy | None = None) -> jax.Array:
     """Fused conv+[requant]+bias+relu+2×2 pool. x: (B,N,H,W), w:
     (M,N,Kh,Kw) -> (B,M,Ho/2,Wo/2). ``scale`` (M,) is the int8 requant
     epilogue applied to the accumulator before bias/relu. ``bb`` batches
     images per grid step (one weight-tile DMA per BB images). Requires
     even conv output dims (``odd`` modes other than even inputs are served
-    by the ref/xla backends)."""
+    by the ref/xla backends). The kernel is named ``fused_cwp.<stage>``
+    (``fused_cwp`` without a plan stage): the name of its device events."""
     pol = policy if policy is not None else current_policy()
     if interpret is None:
         interpret = pol.resolve_interpret()
@@ -106,4 +108,5 @@ def fused_conv_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
     tiles["bb"] = max(1, min(tiles["bb"], x.shape[0]))
     return _fused_cwp_jit(x, w, b, scale, stride=tuple(stride),
                           interpret=interpret, pb=tiles["pb"],
-                          mb=tiles["mb"], bb=tiles["bb"])
+                          mb=tiles["mb"], bb=tiles["bb"],
+                          name=f"fused_cwp.{stage}" if stage else "fused_cwp")

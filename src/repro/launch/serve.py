@@ -10,6 +10,11 @@ budget; the report shows sustained occupancy, throughput, and the SLO
 view (p50/p95/p99 latency, goodput, deadline-miss rate) from the unified
 ``ServeStats``. ``--max-queue`` bounds intake — submits beyond it are
 refused with the typed ``QueueFullError`` and reported as rejected.
+``--profile-dir DIR`` records a profiler trace of the whole run into
+``DIR`` (TensorBoard's profile plugin or ``jax.profiler.ProfileData``
+read it): the boot phases (``boot.*`` spans) and every serving step
+(``frontend.step`` > ``vision.step`` > ``vision.place``/``launch``/
+``fetch``/``deliver``) on the host, beside the device's operations.
 """
 from __future__ import annotations
 
@@ -244,8 +249,22 @@ def main() -> None:
                     help="print the time-to-ready phase breakdown "
                          "(trace/fuse/place/tune/compile/artifact/"
                          "first_dispatch)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the run (boot phases "
+                         "and serving spans) into DIR")
     args = ap.parse_args()
+    if args.profile_dir is None:
+        _serve(args)
+        return
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0    # the program's spans, no call tracer
+    with jax.profiler.trace(args.profile_dir, profiler_options=opts):
+        _serve(args)
+    print(f"profile: {args.profile_dir}")
 
+
+def _serve(args) -> None:
+    """Serve ``args.arch`` as the command line asks."""
     from repro.configs.registry import get_arch
     from repro.launch.train import build_mesh, reduced_config
     from repro.serve import (Engine, EngineConfig, LMAdapter,

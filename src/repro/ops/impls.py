@@ -149,14 +149,14 @@ def conv2d(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
 
 @register("fused_conv_block", "ref", priority=1)
 def _fused_ref(x, w, b=None, *, stride=(1, 1), odd="raise", scale=None,
-               policy=None):
+               stage=None, policy=None):
     from repro.kernels.fused_cwp.ref import fused_conv_block_ref
     return fused_conv_block_ref(x, w, b, stride, odd, scale=scale)
 
 
 @register("fused_conv_block", "xla", priority=10)
 def _fused_xla(x, w, b=None, *, stride=(1, 1), odd="raise", scale=None,
-               policy=None):
+               stage=None, policy=None):
     out = conv2d_im2col(x, w, None if scale is not None else b, stride)
     if scale is not None:
         out = conv_epilogue(out, scale, b)
@@ -176,14 +176,15 @@ def _fused_pallas_ok(x, w, b=None, *, stride=(1, 1), odd="raise", **_):
 @register("fused_conv_block", "pallas", priority={"tpu": 30, "*": 5},
           supports=_fused_pallas_ok)
 def _fused_pallas(x, w, b=None, *, stride=(1, 1), odd="raise", scale=None,
-                  policy=None):
+                  stage=None, policy=None):
     from repro.kernels.fused_cwp.ops import fused_conv_window  # lazy: pallas
     return fused_conv_window(x, w, b, stride=stride, odd=odd, scale=scale,
-                             policy=policy)
+                             stage=stage, policy=policy)
 
 
 def fused_conv_block(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
                      *, stride: tuple[int, int] = (1, 1), odd: str = "raise",
+                     stage: str | None = None,
                      policy: ExecPolicy | None = None) -> jax.Array:
     """conv + bias + relu + 2×2/2 maxpool as ONE op: (B, N, H, W) ·
     (M, N, Kh, Kw) -> (B, M, Ho/2, Wo/2) (odd dims per ``odd``).
@@ -196,13 +197,15 @@ def fused_conv_block(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
     backend. Under ``int8`` (or with QTensor operands) the requant scale
     rides INTO the backend as the ``scale`` epilogue operand — it must be
     applied before the in-pipeline bias/relu/pool, so unlike ``conv2d``
-    it cannot be an outer wrapper here.
+    it cannot be an outer wrapper here. ``stage`` names the plan stage
+    (``s<i>``) the call serves; the pallas backend names its kernel
+    ``fused_cwp.<stage>`` after it, so a device trace tells stages apart.
     """
     pol = policy if policy is not None else current_policy()
     x, w, b = _conv_quant_operands(pol, x, w, b)
     x, w, scale = split_requant(x, w)
     out = dispatch("fused_conv_block", x, w, b, stride=stride, odd=odd,
-                   scale=scale, policy=pol)
+                   scale=scale, stage=stage, policy=pol)
     if pol.quant == "qformat":
         out = pol.qformat.quantize(out)
     return out

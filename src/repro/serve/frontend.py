@@ -26,7 +26,8 @@ front-end that both plug into:
   opening a new one** — a partial bucket is held while the earliest
   queued deadline still affords another service step (estimated from the
   measured step-time EWMA, or the configured virtual step cost), and is
-  force-dispatched by ``flush`` (end of arrivals) or deadline pressure.
+  force-dispatched by ``flush`` (end of arrivals) or deadline pressure;
+  ``ServeStats.hold_s``/``holds`` count how long held buckets waited.
   Requests that cannot be injected are **evicted back to the queue**, not
   dropped; requests past their deadline are still served and accounted as
   misses — the queue never lies about what it accepted.
@@ -48,6 +49,7 @@ from typing import Any
 from repro.serve.clock import Clock, MonotonicClock
 from repro.serve.queue import QueueFullError
 from repro.serve.stats import ServeStats
+from repro.spans import span
 
 __all__ = ["QueueFullError", "ServeRequestState", "ServeRequest",
            "SchedulerCore", "FrontendConfig", "Frontend",
@@ -268,6 +270,7 @@ class Frontend:
         self.results: dict[int, Any] = {}
         self.requests: dict[int, ServeRequest] = {}
         self._step_est: float | None = config.step_cost_s
+        self._held_since: float | None = None   # a partial bucket is held
 
     # ---------- intake ----------
     def submit(self, payload, *, slo_s: float | None = None,
@@ -324,42 +327,51 @@ class Frontend:
             self.stats.last_t = now
 
     def step(self, flush: bool = True) -> bool:
-        """One scheduling iteration: drain, dispatch, engine step.
-        Returns True if an engine step ran (False = held or idle).
-        ``flush=False`` tells the policy more arrivals may come (open-loop
-        drivers); the default serves everything it can immediately."""
-        self._drain_finished()
-        queued = len(self.core)
-        if queued and not self._should_hold(queued, flush):
-            picked = self.core.pick(
-                min(queued, self.adapter.free_lanes()))
-            back = []
-            for req in picked:
-                try:
-                    self.adapter.inject(req)
-                except QueueFullError:       # engine-side backpressure:
-                    back.append(req)         # evict-to-queue, never drop
-                    continue
-                req.state = ServeRequestState.DISPATCHED
-                req.dispatch_t = self.clock.now()
-            if back:
-                self.core.requeue(back)
-        if not self.adapter.has_inflight():
-            return False
-        t0 = self.clock.now()
-        self.adapter.step()
-        if self.config.step_cost_s is not None:
-            # virtual service model: the charge happens outside the
-            # engine's own timed region, so credit it into the unified
-            # stats here (real-clock runs leave step_cost_s None)
-            self.clock.sleep(self.config.step_cost_s)
-            self.stats.wall_s += self.config.step_cost_s
-        dt = self.clock.now() - t0
-        if dt > 0:                           # EWMA service-time estimate
-            self._step_est = dt if self._step_est is None \
-                else 0.5 * self._step_est + 0.5 * dt
-        self._drain_finished()
-        return True
+        """One scheduling iteration: drain, dispatch, engine step, inside
+        the span ``frontend.step``. Returns True if an engine step ran
+        (False = held or idle). ``flush=False`` tells the policy more
+        arrivals may come (open-loop drivers); the default serves
+        everything it can immediately."""
+        with span("frontend.step"):
+            self._drain_finished()
+            queued = len(self.core)
+            if queued and self._should_hold(queued, flush):
+                if self._held_since is None:
+                    self._held_since = self.clock.now()
+            elif queued:
+                if self._held_since is not None:
+                    self.stats.hold_s += self.clock.now() - self._held_since
+                    self.stats.holds += 1
+                    self._held_since = None
+                picked = self.core.pick(
+                    min(queued, self.adapter.free_lanes()))
+                back = []
+                for req in picked:
+                    try:
+                        self.adapter.inject(req)
+                    except QueueFullError:       # engine-side backpressure:
+                        back.append(req)         # evict-to-queue, never drop
+                        continue
+                    req.state = ServeRequestState.DISPATCHED
+                    req.dispatch_t = self.clock.now()
+                if back:
+                    self.core.requeue(back)
+            if not self.adapter.has_inflight():
+                return False
+            t0 = self.clock.now()
+            self.adapter.step()
+            if self.config.step_cost_s is not None:
+                # virtual service model: the charge happens outside the
+                # engine's own timed region, so credit it into the unified
+                # stats here (real-clock runs leave step_cost_s None)
+                self.clock.sleep(self.config.step_cost_s)
+                self.stats.wall_s += self.config.step_cost_s
+            dt = self.clock.now() - t0
+            if dt > 0:                           # EWMA service-time estimate
+                self._step_est = dt if self._step_est is None \
+                    else 0.5 * self._step_est + 0.5 * dt
+            self._drain_finished()
+            return True
 
     def run_until_drained(self, max_steps: int | None = None
                           ) -> dict[int, Any]:

@@ -22,6 +22,11 @@ Semantics of the core counters:
 * ``wall_s`` — clock time inside timed engine steps (via the Clock seam,
   ``repro.serve.clock``; under a ``VirtualClock`` this is virtual time).
 
+The front-end's top-up hold is counted where it is decided: ``holds``
+partial buckets were held and then dispatched, after ``hold_s`` clock
+seconds in all from the first step that held each to the step that
+dispatched it.
+
 Latency percentiles use the nearest-rank method — deterministic, no
 interpolation, so virtual-time tests can assert them exactly.
 """
@@ -58,6 +63,8 @@ class ServeStats:
     rejected: int = 0             # refused at intake (QueueFullError)
     completed: int = 0            # results delivered
     deadline_misses: int = 0      # completed after their deadline
+    hold_s: float = 0.0           # clock time partial buckets were held
+    holds: int = 0                # held partial buckets later dispatched
     latencies: list = field(default_factory=list)   # seconds, per request
     first_t: float | None = None  # first submit (clock timestamp)
     last_t: float | None = None   # last completion (clock timestamp)

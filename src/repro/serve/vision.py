@@ -52,6 +52,7 @@ import numpy as np
 from repro.ops import ExecPolicy
 from repro.serve.clock import Clock, MonotonicClock
 from repro.serve.stats import ServeStats
+from repro.spans import span
 
 __all__ = ["VisionEngineConfig", "VisionStats", "VisionEngine"]
 
@@ -188,7 +189,6 @@ class VisionEngine:
         so compile time is its own warmup phase. Either way the warm
         dispatch runs here, outside any timed serving step —
         ``VisionStats.wall_s`` measures serving only."""
-        from repro.artifact.aot import aot_compile
         from repro.artifact.warmup import phase
         t0 = self.clock.now()
         shape = (bucket, *self.model.input_shape()[1:])
@@ -208,11 +208,9 @@ class VisionEngine:
                                       autotune=self.config.autotune)
             bound = plan.bind(self._params)
         if exe is None:
-            from repro.artifact.store import _batch_sharding
+            from repro.artifact.store import compile_program
             with phase("compile"):
-                exe = aot_compile(lambda x, b=bound: b(x), shape,
-                                  sharding=_batch_sharding(bound.plan,
-                                                           shape))
+                exe = compile_program(bound, shape)
         self._bounds[bucket] = bound
         self._steps[bucket] = exe
         self.plan_source[bucket] = source
@@ -296,7 +294,10 @@ class VisionEngine:
     # ---------- driving ----------
     def step(self) -> int:
         """Serve one bucket-shaped batch from the queue; returns how many
-        real images it carried."""
+        real images it carried. The timed part is the span ``vision.step``
+        (``bucket``, ``lanes``), whose four children cover it: ``place``
+        (stack, pad, put on the device), ``launch`` (the executable call),
+        ``fetch`` (wait for and copy back the logits) and ``deliver``."""
         if not self._queue:
             return 0
         uids, imgs = [], []
@@ -308,21 +309,27 @@ class VisionEngine:
         if bucket not in self._steps:   # one-time, outside the timed step
             self._compile_bucket(bucket)
         t0 = self.clock.now()
-        batch = np.stack(imgs)
-        if len(uids) < bucket:              # pad to the bucket shape
-            pad = np.zeros((bucket - len(uids), *batch.shape[1:]),
-                           np.float32)
-            batch = np.concatenate([batch, pad])
-        logits = np.asarray(jax.device_get(
-            self._steps[bucket](self._place_batch(batch))))
-        for i, uid in enumerate(uids):
-            self.results[uid] = {"label": int(logits[i].argmax()),
-                                 "logits": logits[i]}
-        self.stats.steps += 1
-        self.stats.items += len(uids)               # real images served
-        self.stats.lane_steps += len(uids)          # real work only
-        self.stats.pad_lanes += bucket - len(uids)  # issued, not served
-        self.stats.wall_s += self.clock.now() - t0
+        with span("vision.step", bucket=bucket, lanes=len(uids)):
+            with span("vision.place"):
+                batch = np.stack(imgs)
+                if len(uids) < bucket:      # pad to the bucket shape
+                    pad = np.zeros((bucket - len(uids), *batch.shape[1:]),
+                                   np.float32)
+                    batch = np.concatenate([batch, pad])
+                placed = self._place_batch(batch)
+            with span("vision.launch"):
+                out = self._steps[bucket](placed)
+            with span("vision.fetch"):
+                logits = np.asarray(jax.device_get(out))
+            with span("vision.deliver"):
+                for i, uid in enumerate(uids):
+                    self.results[uid] = {"label": int(logits[i].argmax()),
+                                         "logits": logits[i]}
+            self.stats.steps += 1
+            self.stats.items += len(uids)               # real images served
+            self.stats.lane_steps += len(uids)          # real work only
+            self.stats.pad_lanes += bucket - len(uids)  # issued, not served
+            self.stats.wall_s += self.clock.now() - t0
         return len(uids)
 
     def run(self) -> dict[int, dict]:

@@ -91,11 +91,13 @@ def stream_conv2d(x, w, b=None, *, stride=(1, 1), scale=None,
 
 def stream_fused_conv_block(x, w, b=None, *, stride=(1, 1), odd="raise",
                             scale=None, tiling: SpatialTiling,
+                            stage: str | None = None,
                             policy: ExecPolicy | None = None) -> jax.Array:
     """Halo-banded ``repro.ops.fused_conv_block``: bands count *pooled*
     rows (even conv-row cuts — no 2×2 pool window ever straddles bands;
     only the image's own ragged last rows see the ``odd`` mode, exactly
-    as untiled). Bitwise-equal to the untiled entry point."""
+    as untiled). Bitwise-equal to the untiled entry point. Every band's
+    call carries the plan ``stage`` it serves."""
     from repro.core.window import pool_output_size
     from repro.ops.impls import _conv_quant_operands, split_requant
     pol = policy if policy is not None else current_policy()
@@ -114,7 +116,7 @@ def stream_fused_conv_block(x, w, b=None, *, stride=(1, 1), odd="raise",
     for _, _, in_lo, in_hi in pooled_bands(po, th, kh, sh, h):
         xb = x[:, :, in_lo:in_hi, :]
         out = dispatch("fused_conv_block", xb, w, b, stride=tuple(stride),
-                       odd=odd, scale=scale, policy=pol)
+                       odd=odd, scale=scale, stage=stage, policy=pol)
         if pol.quant == "qformat":
             out = pol.qformat.quantize(out)
         outs.append(out)

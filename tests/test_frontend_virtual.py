@@ -348,6 +348,42 @@ class TestTopUpPolicy:
         assert urgent.stats.deadline_misses == 0
         assert urgent.stats.latencies == [pytest.approx(0.01)]
 
+    def test_hold_ended_by_top_up_is_counted(self):
+        # step cost 1/8 s; a lone request is held at t=0, three more
+        # arrive at t=1/4 and fill the bucket, which then dispatches
+        fe, clock = _frontend(BucketSimAdapter(4), slo_s=100.0,
+                              step_cost_s=0.125)
+        fe.submit("a")
+        assert fe.step(flush=False) is False          # held from t=0
+        clock.advance(0.125)
+        assert fe.step(flush=False) is False          # still held
+        clock.advance(0.125)
+        for name in "bcd":
+            fe.submit(name)
+        assert fe.step(flush=False) is True           # full: dispatched
+        assert (fe.stats.hold_s, fe.stats.holds) == (0.25, 1)
+        assert fe.stats.pad_lanes == 0
+        # a full bucket at once is never held, so the counters stay
+        for name in "efgh":
+            fe.submit(name)
+        assert fe.step(flush=False) is True
+        assert (fe.stats.hold_s, fe.stats.holds) == (0.25, 1)
+
+    def test_hold_ended_by_deadline_is_counted(self):
+        # deadline 1/2 s, step estimate 1/8 s: held while the slack is
+        # over 2 x 1/8 s, i.e. at t=0 and t=1/8; dispatched at t=1/4
+        fe, clock = _frontend(BucketSimAdapter(4), slo_s=0.5,
+                              step_cost_s=0.125)
+        fe.submit("a")
+        assert fe.step(flush=False) is False
+        clock.advance(0.125)
+        assert fe.step(flush=False) is False
+        clock.advance(0.125)
+        assert fe.step(flush=False) is True           # partial bucket
+        assert (fe.stats.hold_s, fe.stats.holds) == (0.25, 1)
+        assert fe.stats.pad_lanes == 3
+        assert fe.requests[0].dispatch_t == 0.25
+
     def test_flush_dispatches_partial_bucket(self):
         # closed-loop (flush=True default): a partial bucket never holds
         fe, _ = _frontend(BucketSimAdapter(4), slo_s=100.0, topup=True)
@@ -356,6 +392,7 @@ class TestTopUpPolicy:
         assert fe.stats.completed == 1
         assert fe.stats.steps == 1
         assert fe.stats.pad_lanes == 3
+        assert (fe.stats.hold_s, fe.stats.holds) == (0.0, 0)
 
 
 class TestFrontendLoop:

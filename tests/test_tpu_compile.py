@@ -16,6 +16,8 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every test
 worker imports this file. Keep every such compile in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -125,6 +127,27 @@ def test_served_bucket_plan_compiles(one_chip, arch, quant, bucket):
                           (model.input_shape(bucket), jnp.float32))
     assert text.count("tpu_custom_call") >= \
         plan.num_fused() + (quant == "int8")
+
+
+@pytest.mark.parametrize("arch", ["highres_cnn", "mnist_cnn"])
+def test_served_kernels_carry_their_stage_names(one_chip, arch):
+    """Each fused stage's kernel is the instruction ``fused_cwp.s<i>``
+    (``plan.stages()[i]``; one per stream band), which names its events
+    in a device trace."""
+    from repro.configs.registry import get_arch
+    from repro.graph.ir import FusedConvBlockNode
+    from repro.ops import ExecPolicy
+    model = get_arch(arch).model()
+    plan = model.compile(policy=ExecPolicy(backend="pallas",
+                                           interpret=False), batch=8)
+    bound = plan.bind(model.init(jax.random.PRNGKey(0)))
+    text = _compiled_text(lambda x: bound(x), one_chip,
+                          (model.input_shape(8), jnp.float32))
+    kernels = re.findall(r"^\s*%(\S+) = .*tpu_custom_call", text, re.M)
+    fused = [i for i, node in enumerate(plan.graph)
+             if isinstance(node, FusedConvBlockNode)]
+    assert {re.sub(r"\.\d+$", "", k) for k in kernels} == \
+        {f"fused_cwp.s{i}" for i in fused}
 
 
 @pytest.mark.parametrize("quant", ["none", "int8"])
