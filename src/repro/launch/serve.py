@@ -200,7 +200,8 @@ def _serve_vision(spec, model, args) -> None:
           f"batches (max {args.capacity})")
     print(f"lane utilization {s.lane_utilization:.0%} "
           f"({s.lane_steps} real + {s.pad_lanes} pad lanes), "
-          f"pad_fraction={s.pad_fraction:.2f}")
+          f"pad_fraction={s.pad_fraction:.2f}, overlapped fetches "
+          f"{s.overlapped}/{s.steps}")
     _print_slo(s, args)
     if shed:
         print(f"shed {shed} submissions at intake (queue full)")

@@ -24,8 +24,9 @@ front-end that both plug into:
   into the engine's own unified ``ServeStats``. The SLO policy for
   bucket-forming engines: **prefer topping up a half-empty bucket over
   opening a new one** — a partial bucket is held while the earliest
-  queued deadline still affords another service step (estimated from the
-  measured step-time EWMA, or the configured virtual step cost), and is
+  queued deadline still affords another service step (estimated from an
+  EWMA of the measured time from a bucket's dispatch to its answer, or
+  the configured virtual step cost), and is
   force-dispatched by ``flush`` (end of arrivals) or deadline pressure;
   ``ServeStats.hold_s``/``holds`` count how long held buckets waited.
   Requests that cannot be injected are **evicted back to the queue**, not
@@ -198,9 +199,12 @@ class LMAdapter:
 
 class VisionAdapter:
     """Facade over ``repro.serve.vision.VisionEngine``. Every engine step
-    forms one bucket-shaped batch, so the whole batch width is free each
-    step — which is exactly why the top-up policy applies here: a
-    dispatched partial batch pays pad lanes forever, a held one may fill."""
+    forms a fresh bucket-shaped batch — which is exactly why the top-up
+    policy applies here: a dispatched partial batch pays pad lanes
+    forever, a held one may fill. The engine takes up to two buckets of
+    requests, counting those still unanswered: with a second bucket
+    queued behind the first it launches that one ahead, so its copy back
+    overlaps the next step's launch (DESIGN.md §11)."""
 
     kind = "vision"
     forms_buckets = True
@@ -218,7 +222,7 @@ class VisionAdapter:
         return self.engine.config.batch
 
     def free_lanes(self) -> int:
-        return self.engine.config.batch
+        return 2 * self.engine.config.batch - self.engine.unanswered()
 
     def inject(self, req: ServeRequest) -> None:
         uid = self.engine.submit(req.payload)
@@ -335,6 +339,7 @@ class Frontend:
         with span("frontend.step"):
             self._drain_finished()
             queued = len(self.core)
+            dispatched: list[ServeRequest] = []
             if queued and self._should_hold(queued, flush):
                 if self._held_since is None:
                     self._held_since = self.clock.now()
@@ -343,8 +348,12 @@ class Frontend:
                     self.stats.hold_s += self.clock.now() - self._held_since
                     self.stats.holds += 1
                     self._held_since = None
-                picked = self.core.pick(
-                    min(queued, self.adapter.free_lanes()))
+                n = min(queued, self.adapter.free_lanes())
+                batch = self.adapter.preferred_batch
+                if getattr(self.adapter, "forms_buckets", False) \
+                        and n > batch:
+                    n -= n % batch   # a partial 2nd bucket waits for top-up
+                picked = self.core.pick(n)
                 back = []
                 for req in picked:
                     try:
@@ -354,6 +363,7 @@ class Frontend:
                         continue
                     req.state = ServeRequestState.DISPATCHED
                     req.dispatch_t = self.clock.now()
+                    dispatched.append(req)
                 if back:
                     self.core.requeue(back)
             if not self.adapter.has_inflight():
@@ -367,10 +377,14 @@ class Frontend:
                 self.clock.sleep(self.config.step_cost_s)
                 self.stats.wall_s += self.config.step_cost_s
             dt = self.clock.now() - t0
-            if dt > 0:                           # EWMA service-time estimate
+            self._drain_finished()
+            # EWMA of a bucket's dispatch-to-answer time: only a step that
+            # answers all it dispatched measures it, not one that leaves a
+            # bucket in flight or only answers an earlier one
+            if dt > 0 and dispatched and all(
+                    r.state is ServeRequestState.DONE for r in dispatched):
                 self._step_est = dt if self._step_est is None \
                     else 0.5 * self._step_est + 0.5 * dt
-            self._drain_finished()
             return True
 
     def run_until_drained(self, max_steps: int | None = None

@@ -39,6 +39,15 @@ batches scatter over the mesh's ``data`` axis before dispatch. With
 candidates (or takes them from a persisted tuning cache) and bakes the
 winners into the bound plan (DESIGN.md §10) — serving traffic never
 re-tunes.
+
+``VisionEngine.step`` keeps at most **two buckets in flight** when more
+work is certain: with a full bucket queued behind the bucket a step
+answers, the step launches that one *ahead* (its copy back started for
+when the device finishes, ``copy_to_host_async``) before it fetches, and
+the next step answers it, so the transfer back of bucket *n* overlaps
+the stacking, input transfer and launch of bucket *n+1*. A lone bucket
+is fetched in the step that launched it, as before. The front-end's
+``VisionAdapter`` hands the engine a second bucket only when it is full.
 """
 from __future__ import annotations
 
@@ -94,7 +103,11 @@ class VisionStats(ServeStats):
     lanes. Issued = real + pad: a short final batch still computes its
     pad lanes, but they must never count as served work. The derived
     occupancy views (``lane_utilization``, ``pad_fraction``) live on the
-    base class; the pre-§11 names survive as aliases."""
+    base class; the pre-§11 names survive as aliases. ``overlapped``
+    counts the buckets whose answers were fetched in a later step than
+    their launch (``VisionEngine.step``)."""
+
+    overlapped: int = 0
 
     @property
     def images(self) -> int:
@@ -152,6 +165,9 @@ class VisionEngine:
         self._queue: deque[tuple[int, np.ndarray]] = deque()
         self.results: dict[int, dict] = {}
         self._uid = 0
+        # the bucket launched ahead, answered by the next step:
+        # (uids, device logits), or None
+        self._ahead: tuple[list[int], object] | None = None
 
     def _resolve_buckets(self, config: VisionEngineConfig
                          ) -> tuple[int, ...]:
@@ -293,50 +309,107 @@ class VisionEngine:
 
     # ---------- driving ----------
     def step(self) -> int:
-        """Serve one bucket-shaped batch from the queue; returns how many
-        real images it carried. The timed part is the span ``vision.step``
-        (``bucket``, ``lanes``), whose four children cover it: ``place``
-        (stack, pad, put on the device), ``launch`` (the executable call),
-        ``fetch`` (wait for and copy back the logits) and ``deliver``."""
-        if not self._queue:
+        """Serve the queue's next bucket-shaped batch; returns how many
+        real images this step launched.
+
+        Each launched bucket is a span ``vision.step`` (``bucket``,
+        ``lanes``) over ``place`` (stack, pad, put on the device) and
+        ``launch`` (the executable call); ``fetch`` (wait for and copy
+        back the logits) and ``deliver`` answer a bucket inside the last
+        ``vision.step`` of the engine step. A lone bucket is answered in
+        the step that launched it. When more work is certain (a full
+        bucket queued behind the bucket this step answers, or any bucket
+        queued behind one an earlier step launched ahead) the next
+        bucket is launched ahead first, its copy back started
+        (``copy_to_host_async``), and left in flight: the step's
+        ``fetch`` then takes the previous bucket, and the next step
+        answers the one launched ahead, its ``fetch`` marked
+        ``overlapped=1`` (``VisionStats.overlapped``). At most two
+        buckets are in flight. A step with nothing queued takes the
+        bucket in flight outside any ``vision.step``; its time still
+        counts in ``wall_s``."""
+        due, self._ahead = self._ahead, None  # launched by an earlier step
+        late = due is not None
+        if not late and not self._queue:
             return 0
+        # the step's first launch; a second one is full, compiled at boot
+        bucket = self._bucket_for(len(self._queue))
+        if self._queue and bucket not in self._steps:
+            self._compile_bucket(bucket)    # one-time, outside the timing
+        t0 = self.clock.now()
+        lanes = 0
+        if not late:
+            uids, imgs, bucket = self._pop_bucket()
+            with span("vision.step", bucket=bucket, lanes=len(uids)):
+                due = (uids, self._launch(imgs, bucket, ahead=False))
+                if len(self._queue) < self.config.batch:
+                    self._take(*due)        # a lone bucket: answer it now
+                    due = None
+            lanes += len(uids)
+        if due is not None and self._queue:
+            uids, imgs, bucket = self._pop_bucket()
+            with span("vision.step", bucket=bucket, lanes=len(uids)):
+                self._ahead = (uids, self._launch(imgs, bucket, ahead=True))
+                self._take(*due, overlapped=late)
+            lanes += len(uids)
+        elif due is not None:
+            self._take(*due, overlapped=True)
+        self.stats.wall_s += self.clock.now() - t0
+        return lanes
+
+    def _pop_bucket(self) -> tuple[list[int], list[np.ndarray], int]:
+        """Up to ``batch`` queued requests and the bucket that fits them."""
         uids, imgs = [], []
         while self._queue and len(uids) < self.config.batch:
             uid, img = self._queue.popleft()
             uids.append(uid)
             imgs.append(img)
-        bucket = self._bucket_for(len(uids))
-        if bucket not in self._steps:   # one-time, outside the timed step
-            self._compile_bucket(bucket)
-        t0 = self.clock.now()
-        with span("vision.step", bucket=bucket, lanes=len(uids)):
-            with span("vision.place"):
-                batch = np.stack(imgs)
-                if len(uids) < bucket:      # pad to the bucket shape
-                    pad = np.zeros((bucket - len(uids), *batch.shape[1:]),
-                                   np.float32)
-                    batch = np.concatenate([batch, pad])
-                placed = self._place_batch(batch)
-            with span("vision.launch"):
-                out = self._steps[bucket](placed)
-            with span("vision.fetch"):
-                logits = np.asarray(jax.device_get(out))
-            with span("vision.deliver"):
-                for i, uid in enumerate(uids):
-                    self.results[uid] = {"label": int(logits[i].argmax()),
-                                         "logits": logits[i]}
-            self.stats.steps += 1
-            self.stats.items += len(uids)               # real images served
-            self.stats.lane_steps += len(uids)          # real work only
-            self.stats.pad_lanes += bucket - len(uids)  # issued, not served
-            self.stats.wall_s += self.clock.now() - t0
-        return len(uids)
+        return uids, imgs, self._bucket_for(len(uids))
+
+    def _launch(self, imgs: list[np.ndarray], bucket: int, ahead: bool):
+        """Place and launch ``imgs`` as one ``bucket``; returns the device
+        logits. A bucket launched ``ahead`` starts its copy back as soon
+        as the device finishes."""
+        with span("vision.place"):
+            batch = np.stack(imgs)
+            if len(imgs) < bucket:      # pad to the bucket shape
+                pad = np.zeros((bucket - len(imgs), *batch.shape[1:]),
+                               np.float32)
+                batch = np.concatenate([batch, pad])
+            placed = self._place_batch(batch)
+        with span("vision.launch"):
+            out = self._steps[bucket](placed)
+            if ahead:
+                out.copy_to_host_async()
+        self.stats.steps += 1
+        self.stats.items += len(imgs)               # real images served
+        self.stats.lane_steps += len(imgs)          # real work only
+        self.stats.pad_lanes += bucket - len(imgs)  # issued, not served
+        return out
+
+    def _take(self, uids: list[int], out, overlapped: bool = False) -> None:
+        """Copy one launched bucket's logits back and answer its lanes."""
+        meta = {"overlapped": 1} if overlapped else {}
+        with span("vision.fetch", **meta):
+            logits = np.asarray(jax.device_get(out))
+        with span("vision.deliver"):
+            for i, uid in enumerate(uids):
+                self.results[uid] = {"label": int(logits[i].argmax()),
+                                     "logits": logits[i]}
+        if overlapped:
+            self.stats.overlapped += 1
 
     def run(self) -> dict[int, dict]:
         """Drain the queue; returns {uid: {"label", "logits"}}."""
-        while self._queue:
+        while self.has_work():
             self.step()
         return self.results
 
     def has_work(self) -> bool:
-        return bool(self._queue)
+        """Requests queued, or a launched bucket not yet answered."""
+        return bool(self._queue) or self._ahead is not None
+
+    def unanswered(self) -> int:
+        """Requests queued or in flight: submitted, not yet answered."""
+        return len(self._queue) + (len(self._ahead[0]) if self._ahead
+                                   else 0)

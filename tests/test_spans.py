@@ -86,6 +86,39 @@ def test_serving_steps_nest_their_spans(tmp_path):
                for name in CHILDREN for sp in by[name])
 
 
+def test_overlapped_fetches_nest_and_carry_their_mark(tmp_path):
+    """16 queued at batch 8: the first step launches both buckets and
+    fetches the first after the second's launch; the second step launches
+    nothing and fetches the second, marked ``overlapped=1``. Each
+    launched bucket is one ``vision.step``, and every fetch lies under a
+    ``vision.step`` or a ``frontend.step``."""
+    model, engine, fe = _stack(batch=8)
+    rng = np.random.RandomState(0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(16):
+            fe.submit(rng.randn(*model.input_shape()[1:])
+                      .astype(np.float32))
+        fe.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    by = {name: [sp for sp in spans if sp[0] == name]
+          for name in ("frontend.step", "vision.step", *CHILDREN)}
+    assert len(by["vision.step"]) == engine.stats.steps == 2
+    assert len(fe.results) == 16
+    for fetch in by["vision.fetch"]:
+        assert any(_inside(fetch, sp)
+                   for sp in by["vision.step"] + by["frontend.step"])
+    first, late = by["vision.fetch"]
+    assert "overlapped" not in first[3]
+    assert late[3]["overlapped"] == 1 == engine.stats.overlapped
+    second = by["vision.step"][1]
+    launch = next(sp for sp in by["vision.launch"] if _inside(sp, second))
+    assert _inside(first, second) and launch[2] <= first[1]
+    assert not any(_inside(late, sp) for sp in by["vision.step"])
+
+
 def test_bucket_executables_are_named_and_scoped():
     """Each bucket's program is the module ``jit_vision_b<bucket>``, and
     the compiled text carries the scope ``s<i>.<op>`` of each plan stage
