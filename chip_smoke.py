@@ -8,14 +8,19 @@ cannot take a call raises; nothing falls back):
 
   * ``highres_cnn`` at its published config: 224x224x3, batch 8, bucket
     ladder 1/2/4/8 — the early blocks stream as halo row bands;
-  * ``mnist_cnn``, the paper's CNN (Tab. I).
+  * ``mnist_cnn``, the paper's CNN (Tab. I);
+  * ``resnet50``, ResNet-50 v1.5 at 224x224x3: padded, strided and 1x1
+    convs, batch norm folded at bind, residual adds.
 
-Each model is served in quant ``none`` and ``int8`` on 16 images drawn
-from ``--seed``, submitted in waves of 8, 4, 2, 1, 1 so that every bucket
-of the ladder serves. Every bucket executable must contain
+Each model is served in quant ``none`` and ``int8`` (``resnet50``, whose
+folded batch norm has no int8 lowering, in ``none`` only) on 16 images
+drawn from ``--seed``, submitted in waves of 8, 4, 2, 1, 1 so that every
+bucket of the ladder serves. Every bucket executable must contain
 ``tpu_custom_call`` (kernels compiled, not interpreted), and the served
-logits must match the model's ``ref`` backend — a plain float32 forward
-at ``highest`` matmul precision — within the tolerances below.
+logits must match a plain float32 forward at ``highest`` matmul
+precision — the model's ``ref`` backend (``xla`` for ``resnet50``, whose
+paper-dataflow ``ref`` convs would hold every window's products at
+once) — within the tolerances below.
 
 ``--mesh 2x2`` runs only the sharded path (four chips): ``highres_cnn``
 compiled channel-parallel over a data x model mesh, checked for weights
@@ -25,6 +30,7 @@ the one-chip unsharded plan in the same process.
 Usage, from the repository root on a TPU host:
 
     python chip_smoke.py                 # one chip
+    python chip_smoke.py --arch resnet50 # one chip, one model
     python chip_smoke.py --mesh 2x2      # four chips
 
 The last line of stdout is ``{"ok": true, "device": {...}}``; any failed
@@ -60,6 +66,9 @@ BATCH = 8
 # difference can move one downstream code by one step, far below 1e-2.
 TOL_VS_REF = {"none": 1e-4, "int8": 0.1}
 TOL_SHARDED = {"none": 1e-4, "int8": 1e-2}
+ARCHS = ("highres_cnn", "mnist_cnn", "resnet50")
+QUANTS = {"resnet50": ("none",)}               # others: none and int8
+REFERENCE_BACKEND = {"resnet50": "xla"}        # others: ref
 
 
 def require_tpu():
@@ -78,13 +87,14 @@ def seeded_images(model, seed: int):
         (N_IMAGES, *model.input_shape()[1:])).astype(np.float32)
 
 
-def reference_logits(model, params, images):
-    """The model's ``ref`` backend: a plain float32 forward (paper-dataflow
-    conv oracle, dense einsum) at highest matmul precision."""
+def reference_logits(model, params, images, backend: str = "ref"):
+    """A plain float32 forward at highest matmul precision through
+    ``backend``: ``ref`` (paper-dataflow conv oracle, dense einsum) or
+    ``xla`` (im2col einsum)."""
     import jax
     import numpy as np
     from repro.ops import ExecPolicy, use_policy
-    with use_policy(ExecPolicy(backend="ref")), \
+    with use_policy(ExecPolicy(backend=backend)), \
             jax.default_matmul_precision("highest"):
         return np.asarray(jax.jit(model.forward)(params, images))
 
@@ -171,8 +181,9 @@ def one_chip(arch: str, seed: int) -> None:
     model = get_arch(arch).model()
     params = model.init(jax.random.PRNGKey(seed))
     images = seeded_images(model, seed)
-    want = reference_logits(model, params, images)
-    for quant in ("none", "int8"):
+    want = reference_logits(model, params, images,
+                            REFERENCE_BACKEND.get(arch, "ref"))
+    for quant in QUANTS.get(arch, ("none", "int8")):
         with use_policy(ExecPolicy(backend="pallas", quant=quant)):
             engine, frontend, _ = build_vision_server(model, params,
                                                       capacity=BATCH)
@@ -259,6 +270,9 @@ def main() -> None:
                     help="run only the sharded path on a data x model "
                          "mesh, e.g. 2x2 (four chips)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--arch", choices=ARCHS, action="append",
+                    help="serve only this model on one chip (repeatable; "
+                         "default: every model)")
     args = ap.parse_args()
 
     dev = require_tpu()
@@ -270,7 +284,7 @@ def main() -> None:
     if args.mesh:
         sharded(args.mesh, args.seed)
     else:
-        for arch in ("highres_cnn", "mnist_cnn"):
+        for arch in args.arch or ARCHS:
             one_chip(arch, args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -38,10 +38,11 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.core.window import conv_output_size, pool_output_size
-from repro.graph.ir import (Conv2DNode, DenseNode, FlattenNode,
-                            FusedConvBlockNode, Graph, InputNode,
-                            MaxPool2Node, Node, QuantizeNode, ReluNode,
-                            TensorSpec)
+from repro.graph.ir import (AddNode, BatchNormFoldNode, Conv2DNode,
+                            DenseNode, FlattenNode, FusedConvBlockNode,
+                            GlobalAvgPoolNode, Graph, InputNode,
+                            MaxPool2Node, MaxPoolNode, Node, QuantizeNode,
+                            ReluNode, TensorSpec)
 from repro.graph.passes import stage_input_spec
 from repro.stream.tiling import check_tiling
 
@@ -119,10 +120,18 @@ def _derive(graph: Graph, node: Node, out: list[Violation]) -> None:
             bad("shape-flow",
                 f"input has {n} channels but weight {node.w} expects {n2}")
             return
+        ph, pw = getattr(node, "padding", (0, 0))
+        h, w = h + 2 * ph, w + 2 * pw
         if h < kh or w < kw:
-            bad("shape-flow", f"kernel {kh}x{kw} larger than input "
-                f"{h}x{w} (VALID padding, paper Eq. 1)")
+            bad("shape-flow", f"kernel {kh}x{kw} larger than the padded "
+                f"input {h}x{w} (paper Eq. 1)")
             return
+        for i, want in zip(node.inputs[1:], (wshape, (m,))):
+            folded = graph.node(i)
+            if isinstance(folded, BatchNormFoldNode) and \
+                    tuple(folded.out.shape) != want:
+                bad("shape-flow", f"folded {folded.part} %{i} is "
+                    f"{folded.out} but the stage needs {want}")
         sh, sw = node.stride
         ho = conv_output_size(h, kh, sh)
         wo = conv_output_size(w, kw, sw)
@@ -149,6 +158,21 @@ def _derive(graph: Graph, node: Node, out: list[Violation]) -> None:
                     pool_output_size(w, node.odd)), src.dtype)
         except ValueError as e:
             bad("shape-flow", f"pool sizing invalid: {e}")
+    elif isinstance(node, MaxPoolNode):
+        bsz, c, h, w = src.shape
+        k, s, p = node.window, node.stride, node.padding
+        expect((bsz, c, conv_output_size(h + 2 * p, k, s),
+                conv_output_size(w + 2 * p, k, s)), src.dtype)
+    elif isinstance(node, AddNode):
+        other = graph.node(node.inputs[1]).out
+        if other != src:
+            bad("shape-flow", f"add of {src} and {other}: the two values "
+                f"differ")
+        expect(src.shape, src.dtype)
+    elif isinstance(node, GlobalAvgPoolNode):
+        expect(src.shape[:2], src.dtype)
+    elif isinstance(node, BatchNormFoldNode):
+        expect(node.w.shape if node.part == "w" else node.gamma.shape)
     elif isinstance(node, FlattenNode):
         expect((src.shape[0], int(np.prod(src.shape[1:]))), src.dtype)
     elif isinstance(node, DenseNode):
@@ -405,7 +429,7 @@ def _check_sharding(plan, out: list[Violation]) -> None:
                 continue
             seen.add(nid)
             src = graph.node(nid)
-            if isinstance(src, FlattenNode):
+            if isinstance(src, (FlattenNode, GlobalAvgPoolNode)):
                 continue            # gather point — stop this path
             if nid in sharded:
                 out.append(Violation(

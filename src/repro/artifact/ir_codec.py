@@ -15,10 +15,13 @@ kind this build cannot execute).
 """
 from __future__ import annotations
 
-from repro.graph.ir import (Conv2DNode, DenseNode, FlattenNode,
-                            FusedConvBlockNode, Graph, InputNode,
-                            MaxPool2Node, ParamRef, QuantizeNode, ReluNode,
-                            ShardingSpec, TensorSpec)
+import dataclasses
+
+from repro.graph.ir import (AddNode, BatchNormFoldNode, BatchNormNode,
+                            Conv2DNode, DenseNode, FlattenNode,
+                            FusedConvBlockNode, GlobalAvgPoolNode, Graph,
+                            InputNode, MaxPool2Node, MaxPoolNode, ParamRef,
+                            QuantizeNode, ReluNode, ShardingSpec, TensorSpec)
 from repro.stream.tiling import tiling_from_doc, tiling_to_doc
 
 __all__ = ["graph_to_doc", "graph_from_doc"]
@@ -32,7 +35,21 @@ _NODE_TYPES = {
     "dense": DenseNode,
     "quantize": QuantizeNode,
     "fused_conv_block": FusedConvBlockNode,
+    "add": AddNode,
+    "max_pool": MaxPoolNode,
+    "global_avg_pool": GlobalAvgPoolNode,
+    "batch_norm": BatchNormNode,
+    "bn_fold": BatchNormFoldNode,
 }
+# node kinds whose attributes are plain numbers, strings and ParamRefs:
+# each attribute is stored under its field name
+_PLAIN = (AddNode, MaxPoolNode, GlobalAvgPoolNode, BatchNormNode,
+          BatchNormFoldNode)
+
+
+def _attrs(cls) -> list[str]:
+    return [f.name for f in dataclasses.fields(cls)
+            if f.name not in ("id", "inputs", "out")]
 
 
 def _spec_doc(spec: TensorSpec) -> dict:
@@ -84,6 +101,13 @@ def _node_doc(node) -> dict:
                    tiling=tiling_to_doc(node.tiling))
         if isinstance(node, FusedConvBlockNode):
             doc["odd"] = node.odd
+        elif node.padding != (0, 0):    # VALID convs keep their old doc
+            doc["padding"] = list(node.padding)
+    elif isinstance(node, _PLAIN):
+        for name in _attrs(type(node)):
+            val = getattr(node, name)
+            doc[name] = _ref_doc(val) if isinstance(val, ParamRef) or \
+                val is None else val
     elif isinstance(node, MaxPool2Node):
         doc["odd"] = node.odd
     elif isinstance(node, DenseNode):
@@ -109,6 +133,13 @@ def _node_from(doc: dict):
                   tiling=tiling_from_doc(doc.get("tiling")))
         if cls is FusedConvBlockNode:
             kw["odd"] = doc["odd"]
+        else:
+            kw["padding"] = tuple(doc.get("padding", (0, 0)))
+    elif cls in _PLAIN:
+        for name in _attrs(cls):
+            val = doc[name]
+            kw[name] = _ref_from(val) if isinstance(val, dict) or \
+                val is None else val
     elif cls is MaxPool2Node:
         kw["odd"] = doc["odd"]
     elif cls is DenseNode:

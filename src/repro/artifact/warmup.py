@@ -33,10 +33,12 @@ from repro.spans import span
 __all__ = ["PHASES", "WarmupReport", "collect_warmup", "phase",
            "current_report"]
 
-# the canonical cold-start pipeline, in execution order. "artifact" is
-# the phase the store adds (manifest + payload load, AOT deserialize);
-# it replaces the first five when a replica boots from an artifact.
-PHASES = ("trace", "fuse", "place", "tune", "compile", "artifact",
+# the canonical cold-start pipeline, in execution order. "fold" is the
+# bind-time constant fold (weight quantization, batch norm folded into
+# its conv). "artifact" is the phase the store adds (manifest + payload
+# load, AOT deserialize); it replaces the first six when a replica boots
+# from an artifact.
+PHASES = ("trace", "fuse", "place", "fold", "tune", "compile", "artifact",
           "first_dispatch")
 
 

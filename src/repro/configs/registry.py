@@ -18,11 +18,13 @@ _MODULES = {
     "rwkv6-1.6b": "repro.configs.rwkv6_16b",
     "mnist_cnn": "repro.configs.mnist_cnn",
     "highres_cnn": "repro.configs.highres_cnn",
+    "resnet50": "repro.configs.resnet50",
 }
 
-# the vision workloads are servable via --arch but outside the assigned
-# LM shape-grid pool
-ARCH_IDS = [a for a in _MODULES if a not in ("mnist_cnn", "highres_cnn")]
+# the vision workloads (family "cnn") are servable via --arch but outside
+# the assigned LM shape-grid pool
+CNN_IDS = ("mnist_cnn", "highres_cnn", "resnet50")
+ARCH_IDS = [a for a in _MODULES if a not in CNN_IDS]
 SHAPE_IDS = list(SHAPES)
 
 

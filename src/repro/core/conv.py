@@ -44,6 +44,10 @@ class Conv2DConfig:
     kernel: tuple[int, int] = (3, 3)
     stride: tuple[int, int] = (1, 1)
     use_bias: bool = True
+    # zero rows/columns added on each side of the input (top and bottom,
+    # left and right): (0, 0) is the paper's VALID conv, (k // 2, k // 2)
+    # SAME for an odd kernel k
+    padding: tuple[int, int] = (0, 0)
     # legacy string spellings (deprecated — prefer ``policy``)
     path: Literal["ref", "im2col", "kernel"] | None = None
     quant: Literal["none", "qformat", "int8"] = "none"
@@ -72,8 +76,9 @@ class Conv2DConfig:
         return policy_from_legacy(self.path, self.quant, self.qformat)
 
     def out_size(self, h: int, w: int) -> tuple[int, int]:
-        return (conv_output_size(h, self.kernel[0], self.stride[0]),
-                conv_output_size(w, self.kernel[1], self.stride[1]))
+        ph, pw = self.padding
+        return (conv_output_size(h + 2 * ph, self.kernel[0], self.stride[0]),
+                conv_output_size(w + 2 * pw, self.kernel[1], self.stride[1]))
 
 
 def conv2d_init(key: jax.Array, cfg: Conv2DConfig, dtype=jnp.float32) -> dict:
@@ -100,7 +105,7 @@ def conv2d_apply(params: dict, x: jax.Array, cfg: Conv2DConfig) -> jax.Array:
         return hook(params, cfg)
     from repro.ops import conv2d
     return conv2d(x, params["w"], params.get("b"), stride=cfg.stride,
-                  policy=cfg.exec_policy())
+                  padding=cfg.padding, policy=cfg.exec_policy())
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,

@@ -44,12 +44,14 @@ __all__ = [
     "conv2d_ref",
     "conv2d_im2col",
     "maxpool2",
+    "pad_spatial",
 ]
 
 
 def conv_output_size(in_size: int, k: int, stride: int) -> int:
-    """Paper Eq. (1)/(2): floor((H - Hk)/Hs) + 1. VALID padding only —
-    the paper's accelerator does not pad."""
+    """Paper Eq. (1)/(2): floor((H - Hk)/Hs) + 1 over the VALID window
+    (the paper's accelerator does not pad); a padded conv passes its
+    padded size H + 2·pad."""
     if in_size < k:
         raise ValueError(f"input {in_size} smaller than kernel {k}")
     return (in_size - k) // stride + 1
@@ -94,6 +96,15 @@ def maxpool2(x: jax.Array, *, odd: str = "raise") -> jax.Array:
         x = jnp.pad(x, pad, constant_values=-jnp.inf)
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 1, 2, 2), (1, 1, 2, 2), "VALID")
+
+
+def pad_spatial(x: jax.Array, padding: tuple[int, int]) -> jax.Array:
+    """Zero-pad the last two (H, W) axes of ``x`` by ``padding`` = (ph,
+    pw) on each side: a padded conv is this, then the VALID conv."""
+    ph, pw = padding
+    if not (ph or pw):
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(ph, ph), (pw, pw)])
 
 
 def fill_latency(k: int, w: int, kw: int | None = None) -> int:

@@ -33,7 +33,9 @@ if TYPE_CHECKING:
 
 __all__ = ["TensorSpec", "ParamRef", "ShardingSpec", "Node", "InputNode",
            "Conv2DNode", "ReluNode", "MaxPool2Node", "FlattenNode",
-           "DenseNode", "QuantizeNode", "FusedConvBlockNode", "Graph"]
+           "DenseNode", "QuantizeNode", "FusedConvBlockNode", "AddNode",
+           "MaxPoolNode", "GlobalAvgPoolNode", "BatchNormNode",
+           "BatchNormFoldNode", "Graph"]
 
 
 @dataclass(frozen=True)
@@ -160,7 +162,11 @@ class InputNode(Node):
 
 @dataclass(frozen=True)
 class Conv2DNode(Node):
-    """VALID-padding conv2d + bias (paper C1/C3), weights by reference."""
+    """conv2d + bias (paper C1/C3), weights by reference. ``padding`` =
+    (ph, pw) zero rows/columns on each side of the input; (0, 0) is the
+    paper's VALID conv. A conv whose batch norm was folded
+    (``passes.fold_batch_norm``) reads its weight and bias from the
+    ``BatchNormFoldNode``s at ``inputs[1:]``."""
 
     w: ParamRef = None
     b: ParamRef | None = None
@@ -168,12 +174,15 @@ class Conv2DNode(Node):
     sharding: ShardingSpec | None = None
     # streaming row-band spec (repro.stream, DESIGN.md §13); None = untiled
     tiling: "SpatialTiling | None" = None
+    padding: tuple[int, int] = (0, 0)
 
     def describe(self) -> str:
         shard = "" if self.sharding is None else f" shard={self.sharding}"
         tile = "" if self.tiling is None else f" tile={self.tiling}"
+        pad = "" if self.padding == (0, 0) else \
+            f" p={self.padding[0]}x{self.padding[1]}"
         return (f"w={self.w} k={self.w.shape[2]}x{self.w.shape[3]} "
-                f"s={self.stride[0]}x{self.stride[1]}"
+                f"s={self.stride[0]}x{self.stride[1]}" + pad
                 + ("" if self.b is None else f" b={self.b}") + shard + tile)
 
 
@@ -266,9 +275,80 @@ class FusedConvBlockNode(Node):
 
 
 @dataclass(frozen=True)
+class AddNode(Node):
+    """Elementwise sum of two same-shaped values: the residual add where
+    a block's branch meets its shortcut (the graph's fan-in)."""
+
+
+@dataclass(frozen=True)
+class MaxPoolNode(Node):
+    """``window``×``window`` max pool, stride ``stride``, ``padding``
+    rows/columns of -inf on each side (ResNet's 3×3/2 pad-1 stem pool;
+    the paper's 2×2/2 pool is ``MaxPool2Node``)."""
+
+    _opname = "max_pool"
+
+    window: int = 3
+    stride: int = 2
+    padding: int = 1
+
+    def describe(self) -> str:
+        return f"k={self.window} s={self.stride} p={self.padding}"
+
+
+@dataclass(frozen=True)
+class GlobalAvgPoolNode(Node):
+    """(B, C, H, W) -> (B, C): the mean of each channel's map."""
+
+    _opname = "global_avg_pool"
+
+
+@dataclass(frozen=True)
+class BatchNormNode(Node):
+    """Inference batch norm over channels (axis 1): (x - mean) ·
+    gamma / sqrt(var + eps) + beta, from the running statistics. Traced
+    only: ``passes.fold_batch_norm`` folds it into the conv before it."""
+
+    _opname = "batch_norm"
+
+    gamma: ParamRef = None
+    beta: ParamRef = None
+    mean: ParamRef = None
+    var: ParamRef = None
+    eps: float = 1e-5
+
+    def describe(self) -> str:
+        return f"gamma={self.gamma} eps={self.eps:g}"
+
+
+@dataclass(frozen=True)
+class BatchNormFoldNode(Node):
+    """A constant (no inputs): the weight (``part="w"``) or the bias
+    (``part="b"``) of conv weight ``w`` and bias ``b`` (None: zero) with
+    the batch norm after it folded in. ``ExecutionPlan.bind`` computes it
+    once, like a constant quantize, so no batch norm runs per batch."""
+
+    _opname = "bn_fold"
+
+    part: str = "w"
+    w: ParamRef = None
+    b: ParamRef | None = None
+    gamma: ParamRef = None
+    beta: ParamRef = None
+    mean: ParamRef = None
+    var: ParamRef = None
+    eps: float = 1e-5
+
+    def describe(self) -> str:
+        return f"{self.part} of w={self.w} gamma={self.gamma}"
+
+
+@dataclass(frozen=True)
 class Graph:
     """An ordered (topological) operator graph with one input and one
-    output. Passes are Graph -> Graph; nodes are immutable."""
+    output; a value may feed several nodes (fan-out) and an ``AddNode``
+    reads two (fan-in). Passes are Graph -> Graph; nodes are
+    immutable."""
 
     nodes: tuple[Node, ...]
     input_id: int = 0

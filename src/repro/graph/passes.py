@@ -3,6 +3,12 @@
 The pass pipeline turns the traced layer-by-layer graph into the paper's
 deep pipeline (DESIGN.md §8):
 
+  0. ``fold_batch_norm`` — every Conv2D → BatchNorm pair becomes the conv
+     alone, its weight and bias read from two constant
+     ``BatchNormFoldNode``s that ``ExecutionPlan.bind`` computes once
+     (w·γ/√(σ²+ε) and β + (b − μ)·γ/√(σ²+ε)), so no batch norm runs per
+     batch.
+
   1. ``fuse_conv_blocks`` — every single-consumer Conv2D → Relu → MaxPool2
      chain collapses into one ``FusedConvBlockNode``, executed by the
      ``fused_conv_block`` op family (conv window pipeline + bias + relu +
@@ -42,11 +48,13 @@ from __future__ import annotations
 from dataclasses import replace
 
 from repro.core.quantize import QFormat
-from repro.graph.ir import (Conv2DNode, DenseNode, FlattenNode,
-                            FusedConvBlockNode, Graph, MaxPool2Node, Node,
-                            QuantizeNode, ReluNode, ShardingSpec, TensorSpec)
+from repro.graph.ir import (BatchNormFoldNode, BatchNormNode, Conv2DNode,
+                            DenseNode, FlattenNode, FusedConvBlockNode,
+                            Graph, MaxPool2Node, Node, QuantizeNode,
+                            ReluNode, ShardingSpec, TensorSpec)
 
-__all__ = ["fuse_conv_blocks", "lower_quant", "eliminate_dead_quantize",
+__all__ = ["fold_batch_norm", "fuse_conv_blocks", "lower_quant",
+           "eliminate_dead_quantize",
            "place_channel_parallel", "default_passes", "tunable_stages",
            "stage_input_spec", "stage_arith_intensity"]
 
@@ -56,16 +64,61 @@ def _single_consumer(graph: Graph, nid: int) -> Node | None:
     return cons[0] if len(cons) == 1 and graph.output_id != nid else None
 
 
+def fold_batch_norm(graph: Graph) -> Graph:
+    """Conv2D → BatchNorm (the conv's only consumer) ⇒ the conv, reading
+    its weight and bias from two constant ``BatchNormFoldNode``s placed
+    just before it; the conv takes the batch norm's id, so its consumers
+    stay wired. A batch norm after anything else raises: the plan runs
+    none per batch."""
+    folds = {}                           # conv id -> its BatchNormNode
+    for node in graph:
+        if not isinstance(node, BatchNormNode):
+            continue
+        conv = graph.node(node.inputs[0])
+        if not (isinstance(conv, Conv2DNode)
+                and _single_consumer(graph, conv.id) is node):
+            raise ValueError(
+                f"%{node.id} batch_norm follows %{conv.id} {conv.op}: only "
+                f"a batch norm that is a conv's one consumer folds")
+        folds[conv.id] = node
+    if not folds:
+        return graph
+    nid = graph.next_id()
+    out: list[Node] = []
+    for node in graph:
+        if isinstance(node, BatchNormNode):
+            continue
+        bn = folds.get(node.id)
+        if bn is None:
+            out.append(node)
+            continue
+        refs = dict(w=node.w, b=node.b, gamma=bn.gamma, beta=bn.beta,
+                    mean=bn.mean, var=bn.var, eps=bn.eps)
+        fw = BatchNormFoldNode(id=nid, inputs=(), part="w",
+                               out=TensorSpec(node.w.shape, node.w.dtype),
+                               **refs)
+        fb = BatchNormFoldNode(id=nid + 1, inputs=(), part="b",
+                               out=TensorSpec(bn.gamma.shape, bn.gamma.dtype),
+                               **refs)
+        nid += 2
+        out += [fw, fb, replace(node, id=bn.id,
+                                inputs=(node.inputs[0], fw.id, fb.id))]
+    # a folded conv takes its batch norm's (later) id at the conv's place,
+    # which is still before every consumer of either
+    return replace(graph, nodes=tuple(out)).validate()
+
+
 def fuse_conv_blocks(graph: Graph) -> Graph:
     """Conv2D → Relu → MaxPool2 (linear, single-consumer) ⇒ one
     FusedConvBlockNode carrying the pool's id (so downstream inputs and
-    the graph output stay valid)."""
+    the graph output stay valid). A padded conv stays unfused: the fused
+    kernel pools a VALID conv."""
     fused: list[Node] = []
     skip: set[int] = set()
     for node in graph:
         if node.id in skip:
             continue
-        if isinstance(node, Conv2DNode):
+        if isinstance(node, Conv2DNode) and node.padding == (0, 0):
             r = _single_consumer(graph, node.id)
             if isinstance(r, ReluNode):
                 p = _single_consumer(graph, r.id)
@@ -120,6 +173,11 @@ def lower_quant(graph: Graph, quant: str,
     for node in graph:
         inputs = tuple(rewired.get(i, i) for i in node.inputs)
         if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
+            if len(node.inputs) > 1:
+                raise ValueError(
+                    f"quant={quant!r}: %{node.id} {node.op} reads a folded "
+                    f"batch norm, which is not lowered to {quant} yet; "
+                    f"compile it with quant='none'")
             # activation quantize on the conv input edge
             act_kind = "qformat" if quant == "qformat" else "int8_act"
             src = inputs[0]
@@ -252,7 +310,8 @@ def _conv_hw(graph: Graph, node: Node) -> tuple[int, int]:
     h, w = stage_input_spec(graph, node).shape[2:]
     kh, kw = node.w.shape[2], node.w.shape[3]
     sh, sw = node.stride
-    return (h - kh) // sh + 1, (w - kw) // sw + 1
+    ph, pw = getattr(node, "padding", (0, 0))
+    return (h + 2 * ph - kh) // sh + 1, (w + 2 * pw - kw) // sw + 1
 
 
 def stage_arith_intensity(graph: Graph) -> list[dict]:
@@ -365,7 +424,10 @@ def stage_input_spec(graph: Graph, node: Node) -> TensorSpec:
 def default_passes(graph: Graph, quant: str = "none",
                    qformat: QFormat | None = None,
                    fuse: bool = True) -> Graph:
-    """The standard pipeline: fuse → lower quant → DQE."""
+    """The standard pipeline: fold batch norm → fuse → lower quant → DQE
+    (the fold runs with ``fuse=False`` too: no batch norm runs per
+    batch)."""
+    graph = fold_batch_norm(graph)
     if fuse:
         graph = fuse_conv_blocks(graph)
     graph = lower_quant(graph, quant, qformat)

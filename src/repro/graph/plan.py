@@ -17,9 +17,10 @@ analogue of the paper's synthesized accelerator:
     asking the plan to run under a *different* ambient quant raises
     instead of silently recompiling.
 
-``plan.bind(params)`` folds the constant (weight) quantize nodes once and
-returns a ``BoundPlan`` — per-batch calls then skip weight requantization
-entirely, the scale constant-folding of DESIGN.md §8.
+``plan.bind(params)`` folds the constant (weight) quantize nodes and the
+batch-norm folds (``BatchNormFoldNode``) once and returns a ``BoundPlan``
+— per-batch calls then skip weight requantization and batch norm
+entirely, the constant folding of DESIGN.md §8.
 
 Compiling with ``autotune=True`` makes the plan **measured** (DESIGN.md
 §10): ``bind`` runs the candidate-grid search of ``repro.ops.autotune``
@@ -51,12 +52,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.artifact.warmup import phase
 from repro.core.quantize import QFormat, QTensor, quantize_int8
-from repro.core.window import maxpool2
-from repro.graph.ir import (Conv2DNode, DenseNode, FlattenNode,
-                            FusedConvBlockNode, Graph, InputNode,
-                            MaxPool2Node, QuantizeNode, ReluNode)
+from repro.core.window import maxpool2, pad_spatial
+from repro.graph.ir import (AddNode, BatchNormFoldNode, Conv2DNode,
+                            DenseNode, FlattenNode, FusedConvBlockNode,
+                            GlobalAvgPoolNode, Graph, InputNode,
+                            MaxPool2Node, MaxPoolNode, QuantizeNode,
+                            ReluNode)
 from repro.graph.passes import default_passes, place_channel_parallel
-from repro.graph.trace import trace
+from repro.graph.trace import add, global_avg_pool, max_pool, trace
 from repro.ops.policy import ExecPolicy, current_policy
 
 __all__ = ["ExecutionPlan", "BoundPlan", "compile_model"]
@@ -77,6 +80,17 @@ def _apply_quantize(node: QuantizeNode, val, q: QFormat):
         t = quantize_int8(val.reshape(m, -1), axis=-1)
         return QTensor(t.codes.reshape(val.shape), t.scale.reshape(-1))
     raise ValueError(f"unknown quantize kind {node.kind!r}")
+
+
+def _fold_batch_norm(node: BatchNormFoldNode, params):
+    """The conv weight (``part="w"``) or bias (``"b"``) with its batch
+    norm folded in: w·s and β + (b − μ)·s, s = γ/√(σ² + ε)."""
+    scale = node.gamma.fetch(params) * jax.lax.rsqrt(
+        node.var.fetch(params) + node.eps)
+    if node.part == "w":
+        return node.w.fetch(params) * scale[:, None, None, None]
+    b = 0.0 if node.b is None else node.b.fetch(params)
+    return node.beta.fetch(params) + (b - node.mean.fetch(params)) * scale
 
 
 @dataclass(frozen=True)
@@ -170,7 +184,9 @@ class ExecutionPlan:
                                                 stride=node.stride,
                                                 odd=node.odd, policy=pol,
                                                 stage=sid)
-                    return conv2d(xl, wl, bl, stride=node.stride, policy=pol)
+                    return conv2d(xl, wl, bl, stride=node.stride,
+                                  padding=node.padding, stage=sid,
+                                  policy=pol)
 
                 return self._batch_parallel(stage, xin, wv, bv)
             from repro.core.parallelism import (
@@ -178,6 +194,7 @@ class ExecutionPlan:
                 fused_conv_block_channel_parallel)
             from repro.ops.impls import split_requant
             x_arr, w_arr, scale = split_requant(xin, wv)
+            x_arr = pad_spatial(x_arr, getattr(node, "padding", (0, 0)))
             mode = ChannelParallelism(spec.mode)
             ki, ko = spec.split(self.mesh.shape["model"])
             daxis = "data" if spec.data else None
@@ -200,6 +217,10 @@ class ExecutionPlan:
                 val = (node.ref.fetch(params) if node.constant
                        else env[node.inputs[0]])
                 return _apply_quantize(node, val, self.qformat)
+            if isinstance(node, BatchNormFoldNode):
+                if node.id in folded:
+                    return folded[node.id]
+                return _fold_batch_norm(node, params)
             if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
                 return _conv_stage(
                     node, isinstance(node, FusedConvBlockNode), sid)
@@ -207,6 +228,15 @@ class ExecutionPlan:
                 return jax.nn.relu(env[node.inputs[0]])
             if isinstance(node, MaxPool2Node):
                 return maxpool2(env[node.inputs[0]], odd=node.odd)
+            if isinstance(node, MaxPoolNode):
+                return max_pool(env[node.inputs[0]], node.window,
+                                node.stride, node.padding)
+            if isinstance(node, AddNode):
+                return add(env[node.inputs[0]], env[node.inputs[1]])
+            if isinstance(node, GlobalAvgPoolNode):
+                # the conv->fc boundary, as FlattenNode: gather the
+                # channel axis of a sharded activation
+                return self._gather(global_avg_pool(env[node.inputs[0]]))
             if isinstance(node, FlattenNode):
                 v = self._gather(env[node.inputs[0]])
                 return v.reshape(v.shape[0], -1)
@@ -353,7 +383,12 @@ class ExecutionPlan:
         rng = np.random.RandomState(0)
         for node in tunable_stages(self.graph):
             spec = stage_input_spec(self.graph, node)
-            x = jnp.asarray(rng.standard_normal(spec.shape), spec.dtype)
+            shape = spec.shape
+            ph, pw = getattr(node, "padding", (0, 0))
+            if ph or pw:    # the kernel runs VALID over the padded input
+                bsz, n, h, w = shape
+                shape = (bsz, n, h + 2 * ph, w + 2 * pw)
+            x = jnp.asarray(rng.standard_normal(shape), spec.dtype)
             if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
                 fused = isinstance(node, FusedConvBlockNode)
                 tiling = getattr(node, "tiling", None)
@@ -444,14 +479,19 @@ class ExecutionPlan:
         return pinned
 
     def _fold_constants(self, params) -> dict:
-        """The weight-quantization constant fold of ``bind``: every
-        constant QuantizeNode, plus each dense layer's QTensor under
-        int8."""
-        folded = {
-            node.id: _apply_quantize(node, node.ref.fetch(params),
-                                     self.qformat)
-            for node in self.graph
-            if isinstance(node, QuantizeNode) and node.constant}
+        """The constant fold of ``bind``: every constant QuantizeNode and
+        every batch-norm fold (phase ``fold``), plus each dense layer's
+        QTensor under int8."""
+        with phase("fold"):
+            folded = {
+                node.id: _apply_quantize(node, node.ref.fetch(params),
+                                         self.qformat)
+                for node in self.graph
+                if isinstance(node, QuantizeNode) and node.constant}
+            folded.update({
+                node.id: _fold_batch_norm(node, params)
+                for node in self.graph
+                if isinstance(node, BatchNormFoldNode)})
         if self.quant == "int8":
             for node in self.graph:
                 if isinstance(node, DenseNode):

@@ -7,10 +7,12 @@ are materialized). The repo's functional layer is duck-type hooked:
 
   * ``core.conv.conv2d_apply``   checks for ``graph_conv2d`` on its input,
   * ``core.window.maxpool2``     checks for ``graph_maxpool2``,
-  * the ``relu`` / ``flatten`` / ``dense`` wrappers below record nodes for
-    a ``TracedArray`` and defer to ``jax.nn.relu`` / ``reshape`` /
-    ``repro.ops.dense`` for real arrays — so one ``forward`` body is both
-    the eager model and the graph program (DESIGN.md §8).
+  * the ``relu`` / ``flatten`` / ``dense`` / ``batch_norm`` / ``add`` /
+    ``max_pool`` / ``global_avg_pool`` wrappers below record nodes for a
+    ``TracedArray`` and compute for real arrays — so one ``forward`` body
+    is both the eager model and the graph program (DESIGN.md §8). A
+    value used twice (a residual block's input) fans out; ``add`` fans
+    in.
 
 Shape inference happens during tracing (conv/pool output sizes via the
 paper's Eq. 1–2 helpers), so a model whose sizing is inconsistent — e.g. a
@@ -26,12 +28,14 @@ import jax
 import numpy as np
 
 from repro.core.window import conv_output_size, pool_output_size
-from repro.graph.ir import (Conv2DNode, DenseNode, FlattenNode, Graph,
-                            InputNode, MaxPool2Node, Node, ParamRef,
-                            ReluNode, TensorSpec)
+from repro.graph.ir import (AddNode, BatchNormNode, Conv2DNode, DenseNode,
+                            FlattenNode, GlobalAvgPoolNode, Graph,
+                            InputNode, MaxPool2Node, MaxPoolNode, Node,
+                            ParamRef, ReluNode, TensorSpec)
 
 __all__ = ["TracedArray", "GraphBuilder", "param_refs", "trace",
-           "relu", "flatten", "dense"]
+           "relu", "flatten", "dense", "batch_norm", "add", "max_pool",
+           "global_avg_pool"]
 
 
 @dataclass
@@ -92,10 +96,11 @@ class TracedArray:
         if n != n2:
             raise ValueError(f"conv2d: input has {n} channels, weight "
                              f"{w} expects {n2}")
-        ho = conv_output_size(h, kh, cfg.stride[0])
-        wo = conv_output_size(wd, kw, cfg.stride[1])
+        ph, pw = cfg.padding
+        ho = conv_output_size(h + 2 * ph, kh, cfg.stride[0])
+        wo = conv_output_size(wd + 2 * pw, kw, cfg.stride[1])
         return self._emit(Conv2DNode, (bsz, m, ho, wo), w=w, b=b,
-                          stride=tuple(cfg.stride))
+                          stride=tuple(cfg.stride), padding=(ph, pw))
 
     def graph_maxpool2(self, *, odd: str = "raise") -> "TracedArray":
         bsz, c, h, w = self.shape
@@ -117,6 +122,33 @@ class TracedArray:
             raise ValueError(f"dense: input dim {self.shape[-1]} vs "
                              f"weight {w} dim {k}")
         return self._emit(DenseNode, (*self.shape[:-1], n), w=w, b=b)
+
+    def graph_batch_norm(self, params: dict, eps: float) -> "TracedArray":
+        if params["gamma"].shape != (self.shape[1],):
+            raise ValueError(f"batch_norm: {self.shape[1]} channels, "
+                             f"gamma {params['gamma']} is "
+                             f"{params['gamma'].shape}")
+        return self._emit(BatchNormNode, self.shape, gamma=params["gamma"],
+                          beta=params["beta"], mean=params["mean"],
+                          var=params["var"], eps=float(eps))
+
+    def graph_add(self, other: "TracedArray") -> "TracedArray":
+        if other.builder is not self.builder or other.spec != self.spec:
+            raise ValueError(f"add: {self.spec} and {other.spec} are not "
+                             f"two values of one graph with one shape")
+        return self.builder.add(AddNode, (self.node_id, other.node_id),
+                                self.spec)
+
+    def graph_max_pool(self, window: int, stride: int,
+                       padding: int) -> "TracedArray":
+        bsz, c, h, w = self.shape
+        ho = conv_output_size(h + 2 * padding, window, stride)
+        wo = conv_output_size(w + 2 * padding, window, stride)
+        return self._emit(MaxPoolNode, (bsz, c, ho, wo), window=window,
+                          stride=stride, padding=padding)
+
+    def graph_global_avg_pool(self) -> "TracedArray":
+        return self._emit(GlobalAvgPoolNode, self.shape[:2])
 
 
 # ------------------------------------------------------ functional layer
@@ -144,6 +176,43 @@ def dense(x, w, b=None, *, policy=None):
         return hook(w, b)
     from repro.ops import dense as op
     return op(x, w, b, policy=policy)
+
+
+def batch_norm(x, params: dict, *, eps: float):
+    """Inference batch norm over channels (axis 1) from the running
+    statistics in ``params`` (gamma, beta, mean, var), or a BatchNorm node
+    when tracing (the compiled plan folds it into the conv before it)."""
+    hook = getattr(x, "graph_batch_norm", None)
+    if hook is not None:
+        return hook(params, eps)
+    scale = params["gamma"] * jax.lax.rsqrt(params["var"] + eps)
+    shift = params["beta"] - params["mean"] * scale
+    return x * scale[:, None, None] + shift[:, None, None]
+
+
+def add(x, y):
+    """x + y, or an Add node (fan-in) when tracing."""
+    hook = getattr(x, "graph_add", None)
+    return hook(y) if hook is not None else x + y
+
+
+def max_pool(x, window: int, stride: int, padding: int):
+    """NCHW max pool over ``window``×``window`` windows at ``stride``,
+    the borders padded with -inf, or a MaxPool node when tracing."""
+    hook = getattr(x, "graph_max_pool", None)
+    if hook is not None:
+        return hook(window, stride, padding)
+    pad = ((0, 0), (0, 0), (padding, padding), (padding, padding))
+    return jax.lax.reduce_window(x, -np.inf, jax.lax.max,
+                                 (1, 1, window, window),
+                                 (1, 1, stride, stride), pad)
+
+
+def global_avg_pool(x):
+    """(B, C, H, W) -> (B, C) channel means, or a GlobalAvgPool node when
+    tracing."""
+    hook = getattr(x, "graph_global_avg_pool", None)
+    return hook() if hook is not None else x.mean(axis=(2, 3))
 
 
 # ---------------------------------------------------------------- trace
@@ -182,5 +251,5 @@ def trace(model, input_shape: tuple[int, ...],
             f"{type(model).__name__}.forward returned {type(out).__name__} "
             f"under tracing — its ops must route through the hooked "
             f"functional layer (conv2d_apply, maxpool2, relu, flatten, "
-            f"dense)")
+            f"dense, batch_norm, add, max_pool, global_avg_pool)")
     return builder.finish(out)

@@ -140,13 +140,15 @@ def _conv_window_kernel(x_ref, w_ref, b_ref, o_ref, *,
 
 def conv2d_window_pallas(x: jax.Array, w: jax.Array, b: jax.Array, *,
                          stride: tuple[int, int], rb: int, mb: int,
-                         bb: int = 1, interpret: bool) -> jax.Array:
+                         bb: int = 1, interpret: bool,
+                         name: str = "conv_window") -> jax.Array:
     """Launch the kernel. x: (B, N, H, W); w: (M, N, Kh, Kw); b: (M,).
 
     rb: output rows per block; mb: output channels per block; bb: images
     per grid step (weight reuse — a measured autotuner candidate,
     DESIGN.md §10). Requires rb | Ho, mb | M, bb | B (the wrapper pads).
-    Returns (B, M, Ho, Wo) in x.dtype.
+    Returns (B, M, Ho, Wo) in x.dtype. ``name`` names the kernel's HLO
+    instruction, and so its device events.
     """
     bsz, n, h, wdt = x.shape
     m, n2, kh, kw = w.shape
@@ -171,5 +173,6 @@ def conv2d_window_pallas(x: jax.Array, w: jax.Array, b: jax.Array, *,
                                lambda bi, ri, mi: (bi, ri, mi, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, ho, m, wo), x.dtype),
         interpret=interpret,
+        name=name,
     )(xs, tap_weights(w).astype(x.dtype), b.reshape(m, 1).astype(x.dtype))
     return out.transpose(0, 2, 1, 3)

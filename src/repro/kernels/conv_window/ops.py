@@ -23,11 +23,11 @@ from repro.ops.tiling import (SUBLANE, choose_conv_blocks, conv_signature,
                               legal_block, tile_params)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("stride", "interpret", "rb", "mb", "bb"))
+@functools.partial(jax.jit, static_argnames=("stride", "interpret", "rb",
+                                             "mb", "bb", "name"))
 def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
                        stride: tuple[int, int], interpret: bool,
-                       rb: int, mb: int, bb: int) -> jax.Array:
+                       rb: int, mb: int, bb: int, name: str) -> jax.Array:
     bsz, h = x.shape[0], x.shape[2]
     m, kh = w.shape[0], w.shape[2]
     sh = stride[0]
@@ -46,7 +46,7 @@ def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
 
     bias = jnp.zeros((m,), x.dtype) if b is None else b
     out = conv2d_window_pallas(x, w, bias, stride=stride, rb=rb, mb=mb,
-                               bb=bb, interpret=interpret)
+                               bb=bb, interpret=interpret, name=name)
     return out[:bsz, :, :ho, :]
 
 
@@ -55,13 +55,17 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
                   interpret: bool | None = None,
                   rb: int | None = None, mb: int | None = None,
                   bb: int | None = None,
+                  stage: str | None = None,
                   policy: ExecPolicy | None = None) -> jax.Array:
     """Window-stationary conv2d. x: (B,N,H,W), w: (M,N,Kh,Kw) -> (B,M,Ho,Wo).
 
-    VALID padding, like the paper's accelerator. ``interpret=None``
+    VALID padding, like the paper's accelerator (``repro.ops.conv2d``
+    pads the input first for a padded conv). ``interpret=None``
     auto-detects (kernel body interpreted everywhere but TPU);
     ``rb``/``mb``/``bb`` override the resolved tile sizes (``bb`` = images
-    per grid step, one weight-tile DMA per BB images).
+    per grid step, one weight-tile DMA per BB images). The kernel is
+    named ``conv_window.<stage>`` (``conv_window`` without a plan stage):
+    the name of its device events.
     """
     pol = policy if policy is not None else current_policy()
     if interpret is None:
@@ -91,4 +95,6 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
     return _conv2d_window_jit(x, w, b, stride=tuple(stride),
                               interpret=interpret,
                               rb=tiles["rb"], mb=tiles["mb"],
-                              bb=tiles["bb"])
+                              bb=tiles["bb"],
+                              name=f"conv_window.{stage}" if stage
+                              else "conv_window")

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantize import QTensor, conv_epilogue, quantize_int8
-from repro.core.window import conv2d_im2col, conv2d_ref, maxpool2
+from repro.core.window import (conv2d_im2col, conv2d_ref, maxpool2,
+                               pad_spatial)
 from repro.core.addtree import pairwise_sum
 from repro.ops.policy import ExecPolicy, current_policy
 from repro.ops.registry import dispatch, register
@@ -47,12 +48,12 @@ __all__ = ["conv2d", "fused_conv_block", "tree_reduce_sum", "qmatmul",
 # ---------------------------------------------------------------- conv2d
 
 @register("conv2d", "ref", priority=1)
-def _conv2d_ref(x, w, b=None, *, stride=(1, 1), policy=None):
+def _conv2d_ref(x, w, b=None, *, stride=(1, 1), stage=None, policy=None):
     return conv2d_ref(x, w, b, stride)
 
 
 @register("conv2d", "xla", priority=10)
-def _conv2d_xla(x, w, b=None, *, stride=(1, 1), policy=None):
+def _conv2d_xla(x, w, b=None, *, stride=(1, 1), stage=None, policy=None):
     return conv2d_im2col(x, w, b, stride)
 
 
@@ -63,9 +64,9 @@ def _conv2d_pallas_ok(x, w, b=None, *, stride=(1, 1), **_) -> bool:
 
 @register("conv2d", "pallas", priority={"tpu": 30, "*": 5},
           supports=_conv2d_pallas_ok)
-def _conv2d_pallas(x, w, b=None, *, stride=(1, 1), policy=None):
+def _conv2d_pallas(x, w, b=None, *, stride=(1, 1), stage=None, policy=None):
     from repro.kernels.conv_window.ops import conv2d_window  # lazy: pallas
-    return conv2d_window(x, w, b, stride=stride, policy=policy)
+    return conv2d_window(x, w, b, stride=stride, stage=stage, policy=policy)
 
 
 def _conv_quant_operands(pol: ExecPolicy, x, w, b):
@@ -120,8 +121,13 @@ def split_requant(x, w):
 
 def conv2d(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
            stride: tuple[int, int] = (1, 1),
+           padding: tuple[int, int] = (0, 0), stage: str | None = None,
            policy: ExecPolicy | None = None) -> jax.Array:
-    """x: (B, N, H, W) · w: (M, N, Kh, Kw) -> (B, M, Ho, Wo), VALID padding.
+    """x: (B, N, H, W) · w: (M, N, Kh, Kw) -> (B, M, Ho, Wo). ``padding``
+    = (ph, pw) zero rows/columns on each side (default VALID); the input
+    is padded here and every backend runs the VALID conv of the padded
+    input. ``stage`` names the plan stage (``s<i>``) the call serves; the
+    pallas backend names its kernel ``conv_window.<stage>`` after it.
 
     Backend and quantization come from ``policy`` (or the active
     ``use_policy`` context). This is the single conv entry point — the
@@ -136,8 +142,9 @@ def conv2d(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
     pol = policy if policy is not None else current_policy()
     x, w, b = _conv_quant_operands(pol, x, w, b)
     x, w, scale = split_requant(x, w)
-    out = dispatch("conv2d", x, w, None if scale is not None else b,
-                   stride=stride, policy=pol)
+    out = dispatch("conv2d", pad_spatial(x, padding), w,
+                   None if scale is not None else b, stride=stride,
+                   stage=stage, policy=pol)
     if scale is not None:
         out = conv_epilogue(out, scale, b)
     if pol.quant == "qformat":

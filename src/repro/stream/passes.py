@@ -11,7 +11,12 @@ byte-identical with streaming compiled in.
 
 Channel-sharded stages are skipped for the same reason they skip
 bind-time autotuning: their per-device shapes live inside shard_map,
-and spatial banding composes with collectives in a later PR.
+and spatial banding composes with collectives in a later PR. Padded
+stages and 1x1 stages are left untiled too (DESIGN.md §13): a band of a
+padded frame would need zero rows at the frame's edges only, which the
+band executor does not make; a 1x1 conv has no halo, so a band would be
+the kernel's own row block at the price of a launch. The kernels'
+row-block grid bounds their VMEM either way.
 """
 from __future__ import annotations
 
@@ -42,7 +47,9 @@ def place_spatial_tiling(graph: Graph, *,
             placed.append(node)
             continue
         spec = node.sharding
-        if spec is not None and spec.mode != "none":
+        if (spec is not None and spec.mode != "none"
+                or getattr(node, "padding", (0, 0)) != (0, 0)
+                or node.w.shape[2:] == (1, 1)):
             placed.append(node)
             continue
         in_spec = stage_input_spec(graph, node)

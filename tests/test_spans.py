@@ -150,7 +150,7 @@ def test_profile_dir_records_boot_and_serving(tmp_path, monkeypatch,
             *CHILDREN} <= names
 
 
-@pytest.mark.parametrize("name", ["compile", "first_dispatch"])
+@pytest.mark.parametrize("name", ["compile", "first_dispatch", "fold"])
 def test_a_warmup_phase_is_a_boot_span(tmp_path, name):
     from repro.artifact.warmup import collect_warmup, phase
     jax.profiler.start_trace(str(tmp_path))

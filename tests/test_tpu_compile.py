@@ -4,11 +4,13 @@ No chip is needed: the TPU compiler is installed here and compiles for a
 topology that is described, not attached. Each case compiles one kernel
 call at a shape the served plans issue — the fused conv block and the
 plain window conv at every conv stage of ``highres_cnn`` (224², per
-stream band) and ``mnist_cnn``, the int8 GEMM at both fc shapes, and the
+stream band) and ``mnist_cnn``, the window conv at ``resnet50``'s stage
+shapes (padded, strided, 1x1), the int8 GEMM at both fc shapes, and the
 addition tree — with interpret mode off, and asserts that the executable
 holds a Mosaic kernel (``tpu_custom_call``); every served bucket plan
-(batch 1, 2, 4, 8; quant none and int8) compiles whole for one chip, and
-the 2×2-mesh plans for four described chips. What the compiler refuses
+(batch 1, 2, 4, 8; quant none and int8) compiles whole for one chip, as
+does ``resnet50``'s bucket-8 plan, and the 2×2-mesh plans for four
+described chips. What the compiler refuses
 here (block shapes off the 8×128 tiling, vector ops Mosaic cannot lower,
 too much VMEM) would otherwise first show up at serve time on the chip.
 
@@ -39,6 +41,27 @@ STAGES = {
     "highres_b3": (8, 32, 26, 26, 32, 3),
     "mnist_conv1": (8, 1, 28, 28, 15, 3),
     "mnist_conv2": (8, 15, 13, 13, 20, 6),
+}
+# (B, N, H, W, M, K, stride) of ResNet-50's conv kernel calls at batch 8,
+# the input already padded (the kernel runs VALID over it): the 7x7/2
+# stem, per stage a 3x3 and a 1x1, and from stage 2 the strided 3x3 and
+# the strided 1x1 projection
+RESNET_STAGES = {
+    "stem": (8, 3, 230, 230, 64, 7, 2),
+    "s1_3x3": (8, 64, 58, 58, 64, 3, 1),
+    "s1_1x1": (8, 256, 56, 56, 64, 1, 1),
+    "s2_3x3": (8, 128, 30, 30, 128, 3, 1),
+    "s2_1x1": (8, 128, 28, 28, 512, 1, 1),
+    "s2_3x3_stride2": (8, 128, 58, 58, 128, 3, 2),
+    "s2_proj": (8, 256, 56, 56, 512, 1, 2),
+    "s3_3x3": (8, 256, 16, 16, 256, 3, 1),
+    "s3_1x1": (8, 1024, 14, 14, 256, 1, 1),
+    "s3_3x3_stride2": (8, 256, 30, 30, 256, 3, 2),
+    "s3_proj": (8, 512, 28, 28, 1024, 1, 2),
+    "s4_3x3": (8, 512, 9, 9, 512, 3, 1),
+    "s4_1x1": (8, 2048, 7, 7, 512, 1, 1),
+    "s4_3x3_stride2": (8, 512, 16, 16, 512, 3, 2),
+    "s4_proj": (8, 1024, 14, 14, 2048, 1, 2),
 }
 # (M, K, N) of the int8 fc GEMM: highres_cnn, mnist_cnn
 GEMMS = {"highres_fc": (8, 4608, 10), "mnist_fc": (8, 320, 10)}
@@ -91,6 +114,17 @@ def test_conv_window_compiles(one_chip, stage):
     text = _compiled_text(
         lambda x, w, b: conv2d_window(x, w, b, interpret=False),
         one_chip, *_conv_specs(stage))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("stage", sorted(RESNET_STAGES))
+def test_conv_window_compiles_at_resnet50_shapes(one_chip, stage):
+    b, n, h, w, m, k, s = RESNET_STAGES[stage]
+    text = _compiled_text(
+        lambda x, wt, bias: conv2d_window(x, wt, bias, stride=(s, s),
+                                          interpret=False),
+        one_chip, ((b, n, h, w), jnp.float32), ((m, n, k, k), jnp.float32),
+        ((m,), jnp.float32))
     assert "tpu_custom_call" in text
 
 
@@ -148,6 +182,28 @@ def test_served_kernels_carry_their_stage_names(one_chip, arch):
              if isinstance(node, FusedConvBlockNode)]
     assert {re.sub(r"\.\d+$", "", k) for k in kernels} == \
         {f"fused_cwp.s{i}" for i in fused}
+
+
+def test_resnet50_bucket_plan_compiles_with_every_conv_a_kernel(one_chip):
+    """The bucket-8 plan ``resnet50.offline`` serves, weights bound: each
+    of the 53 convs is the instruction ``conv_window.s<i>``
+    (``plan.stages()[i]``); batch norm is folded, so nothing else is a
+    kernel."""
+    from repro.configs.registry import get_arch
+    from repro.graph.ir import Conv2DNode
+    from repro.ops import ExecPolicy
+    model = get_arch("resnet50").model()
+    plan = model.compile(policy=ExecPolicy(backend="pallas",
+                                           interpret=False), batch=8)
+    bound = plan.bind(model.init(jax.random.PRNGKey(0)))
+    text = _compiled_text(lambda x: bound(x), one_chip,
+                          (model.input_shape(8), jnp.float32))
+    kernels = re.findall(r"^\s*%(\S+) = .*tpu_custom_call", text, re.M)
+    convs = [i for i, node in enumerate(plan.graph)
+             if isinstance(node, Conv2DNode)]
+    assert len(convs) == 53
+    assert sorted(re.sub(r"\.\d+$", "", k) for k in kernels) == \
+        sorted(f"conv_window.s{i}" for i in convs)
 
 
 @pytest.mark.parametrize("quant", ["none", "int8"])
