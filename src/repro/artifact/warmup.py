@@ -13,6 +13,11 @@ cost nothing on the hot path. Every ``phase`` also opens the program's
 span ``boot.<name>`` (``repro.spans``), so a profiler trace of a boot
 (``launch/serve.py --profile-dir``) shows its phases.
 
+The report also keeps which operand layout each window-conv kernel
+took (``note_layout``, called by ``kernels/conv_window`` as the plan is
+traced, keyed by the kernel's name ``conv_window.s<i>``): a boot that
+loads AOT executables traces nothing and records none.
+
 ``launch/serve.py --warmup-report`` prints the breakdown; a replica
 booted with ``--plan-artifact`` must show ``trace``/``fuse``/``place``/
 ``tune`` at 0 calls — that is the asserted "zero-compilation boot".
@@ -31,7 +36,7 @@ from dataclasses import dataclass, field
 from repro.spans import span
 
 __all__ = ["PHASES", "WarmupReport", "collect_warmup", "phase",
-           "current_report"]
+           "current_report", "note_layout"]
 
 # the canonical cold-start pipeline, in execution order. "fold" is the
 # bind-time constant fold (weight quantization, batch norm folded into
@@ -44,11 +49,14 @@ PHASES = ("trace", "fuse", "place", "fold", "tune", "compile", "artifact",
 
 @dataclass
 class WarmupReport:
-    """Per-phase wall seconds + call counts for one boot."""
+    """Per-phase wall seconds + call counts for one boot, and the layout
+    each traced window-conv kernel took."""
 
     seconds: dict[str, float] = field(default_factory=dict)
     counts: dict[str, int] = field(default_factory=dict)
     total_s: float = 0.0
+    # kernel name (``conv_window.s<i>``) -> operand layout
+    layouts: dict[str, str] = field(default_factory=dict)
 
     def add(self, name: str, dt: float) -> None:
         self.seconds[name] = self.seconds.get(name, 0.0) + dt
@@ -75,6 +83,13 @@ class WarmupReport:
         lines.append(f"  {'other':<14} "
                      f"{max(self.total_s - accounted, 0.0) * 1e3:9.1f} ms")
         lines.append(f"  {'total':<14} {self.total_s * 1e3:9.1f} ms")
+        counts: dict[str, dict[str, int]] = {}
+        for kernel, layout in self.layouts.items():
+            per = counts.setdefault(kernel.split(".")[0], {})
+            per[layout] = per.get(layout, 0) + 1
+        for family, per in sorted(counts.items()):
+            lines.append(f"{family} layouts: " + ", ".join(
+                f"{layout} {n}" for layout, n in sorted(per.items())))
         return "\n".join(lines)
 
 
@@ -115,3 +130,11 @@ def phase(name: str):
             yield
         finally:
             report.add(name, time.perf_counter() - t0)
+
+
+def note_layout(kernel: str, layout: str) -> None:
+    """Record that the kernel named ``kernel`` took ``layout`` (in the
+    ambient report; nothing outside a ``collect_warmup`` block)."""
+    report = _ACTIVE.get()
+    if report is not None:
+        report.layouts[kernel] = layout

@@ -1,10 +1,14 @@
 """Window-stationary Pallas TPU conv2d — the paper's window buffer on VMEM.
 
-Mapping of the paper's §III.B.2 structure onto the TPU memory hierarchy
-(DESIGN.md §2, row C3):
+Two operand layouts (DESIGN.md §2 row C3, §3); ``ops.tiling.conv_layout``
+picks one from the shape. Channels on the MXU's lanes (``lanes.py``)
+where min(N, 128) > min(Wo, 128) and N >= 64: 52 of ResNet-50's 53
+convs. The output width on the lanes (this module) elsewhere: the 7x7
+stem, the paper CNN's convs, and ``kernels/fused_cwp``, which shares
+this module's slab helpers. Width on lanes maps the paper's structure so:
 
-  FPGA                         TPU (this kernel)
-  ----                         -----------------
+  FPGA                         TPU (this module's kernel)
+  ----                         --------------------------
   SHIFT_BUFFER (K-1)×(W-K)     the input *slab*: a (rows_in × W) full-width
     holds W-K trailing pixels    stripe of the image, DMA'd HBM->VMEM once
     of the previous K-1 rows     per (row-block, batch) grid step
@@ -13,11 +17,8 @@ Mapping of the paper's §III.B.2 structure onto the TPU memory hierarchy
                                  row; a kernel row's Kw taps stack on
                                  sublanes into a (Kw·N, Wo) operand
   K² DSP multipliers +         one (MB, Kw·N)·(Kw·N, Wo) MXU contraction
-    odd-even addition tree       per kernel row, accumulated in fp32 — the
-                                 systolic array does the multiplies and
-                                 the sums over Kw and the channels
+    odd-even addition tree       per kernel row, accumulated in fp32
   M parallel kernel banks      the Cout grid axis (output-channel parallel)
-  N-channel parallel units     Cin folded into each tap's contraction
 
 Layout (DESIGN.md §8): the slab is ``(B, H, P, N, W/P)`` — rows on a
 leading axis, channels on sublanes, width on lanes, and the columns split
@@ -27,17 +28,16 @@ tap, strided or not, is a contiguous lane slice, so the kernel body uses
 no strided or gathered vector access. The output is ``(B, Ho, M, Wo)``:
 its last two block dims are always (MB, Wo), so the row block RB is free
 of the 8×128 tiling rule and only MB must be a multiple of 8 or all of M.
-The wrappers transpose NCHW in and out around the call.
+With Wo of 7-56 on the lanes most of each MXU pass is empty, which is
+why wide convs take the channels-on-lanes kernel. The wrappers transpose
+NCHW in and out around the call.
 
 Reuse invariant preserved: each input element crosses HBM->VMEM once per
-row block (halo rows of adjacent blocks excepted: Kh−stride_h rows, the same
-(K−1)/K-style overlap the paper's SHIFT_BUFFER absorbs — here amortized to
-(Kh−s)/(RB·s) per block). The halo'd slab is addressed with element
-offsets on every axis (``pl.Element``); the Pallas TPU pipeline
-double-buffers it against the MXU work of the previous step.
-
-Grid: (B/BB, Ho/RB, M/MB). Block sizes come from ops.py (VMEM budget,
-tiling rule, measured tuning cache).
+row block (halo rows of adjacent blocks excepted: Kh−stride_h rows,
+amortized to (Kh−s)/(RB·s) per block, the overlap the paper's
+SHIFT_BUFFER absorbs), through element offsets on every axis
+(``pl.Element``), double-buffered against the previous step's MXU work.
+Grid: (B/BB, Ho/RB, M/MB); block sizes come from ops.py.
 """
 from __future__ import annotations
 

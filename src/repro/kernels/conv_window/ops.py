@@ -1,8 +1,11 @@
 """jit'd public wrapper for the window-stationary conv kernel.
 
 Pads the output-row count to the row block and the batch to the batch
-block when ragged (the kernel launcher owns the slab/tap layouts), and
-exposes a single ``conv2d_window`` entry point registered as the
+block when ragged (the kernel launchers own the slab/tap layouts), picks
+the operand layout from the shape (``ops.tiling.conv_layout``: channels
+on lanes where they fill more of a pass than the output width does and
+at least half of it),
+and exposes a single ``conv2d_window`` entry point registered as the
 ``pallas`` backend of the ``conv2d`` op family (repro.ops).
 
 Block sizes and interpret mode come from the shared policy/tiling layer
@@ -17,17 +20,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.artifact.warmup import note_layout
 from repro.kernels.conv_window.kernel import conv2d_window_pallas
+from repro.kernels.conv_window.lanes import conv2d_lanes_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (SUBLANE, choose_conv_blocks, conv_signature,
-                              legal_block, tile_params)
+from repro.ops.tiling import (LANE, SUBLANE, choose_conv_blocks,
+                              conv_layout, conv_signature, legal_block,
+                              tile_params)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret", "rb",
-                                             "mb", "bb", "name"))
+                                             "mb", "bb", "name", "layout"))
 def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
                        stride: tuple[int, int], interpret: bool,
-                       rb: int, mb: int, bb: int, name: str) -> jax.Array:
+                       rb: int, mb: int, bb: int, name: str,
+                       layout: str) -> jax.Array:
     bsz, h = x.shape[0], x.shape[2]
     m, kh = w.shape[0], w.shape[2]
     sh = stride[0]
@@ -45,8 +52,10 @@ def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
         x = jnp.pad(x, ((0, pad_b), (0, 0), (0, 0), (0, 0)))
 
     bias = jnp.zeros((m,), x.dtype) if b is None else b
-    out = conv2d_window_pallas(x, w, bias, stride=stride, rb=rb, mb=mb,
-                               bb=bb, interpret=interpret, name=name)
+    launch = (conv2d_lanes_pallas if layout == "lanes"
+              else conv2d_window_pallas)
+    out = launch(x, w, bias, stride=stride, rb=rb, mb=mb, bb=bb,
+                 interpret=interpret, name=name)
     return out[:bsz, :, :ho, :]
 
 
@@ -65,7 +74,8 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
     ``rb``/``mb``/``bb`` override the resolved tile sizes (``bb`` = images
     per grid step, one weight-tile DMA per BB images). The kernel is
     named ``conv_window.<stage>`` (``conv_window`` without a plan stage):
-    the name of its device events.
+    the name of its device events. The layout it takes is noted in the
+    ambient boot report (``repro.artifact.warmup.note_layout``).
     """
     pol = policy if policy is not None else current_policy()
     if interpret is None:
@@ -73,8 +83,9 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
 
     n, h, wdt = x.shape[1], x.shape[2], x.shape[3]
     m, kh, kw = w.shape[0], w.shape[2], w.shape[3]
+    layout = conv_layout(n, (wdt - kw) // stride[1] + 1)
     defaults = choose_conv_blocks(n, h, wdt, m, kh, kw, tuple(stride),
-                                  x.dtype.itemsize)
+                                  x.dtype.itemsize, batch=x.shape[0])
     sig = conv_signature(x.shape, w.shape, stride)
     if (pol.autotune and rb is None and mb is None and bb is None
             and not isinstance(x, jax.core.Tracer)):
@@ -87,14 +98,16 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
         tiles["mb"] = mb
     if bb is not None:
         tiles["bb"] = bb
-    # mb must divide M and obey the block rule; rb and bb are free — ragged Ho
+    # mb must divide M and obey the block rule (on sublanes in the slab
+    # layout, on lanes in the lane layout); rb and bb are free — ragged Ho
     # and B are padded
-    tiles["mb"] = legal_block(m, tiles["mb"], SUBLANE)
+    tiles["mb"] = legal_block(m, tiles["mb"],
+                              LANE if layout == "lanes" else SUBLANE)
     tiles["rb"] = max(1, tiles["rb"])
     tiles["bb"] = max(1, min(tiles["bb"], x.shape[0]))
+    name = f"conv_window.{stage}" if stage else "conv_window"
+    note_layout(name, layout)
     return _conv2d_window_jit(x, w, b, stride=tuple(stride),
                               interpret=interpret,
                               rb=tiles["rb"], mb=tiles["mb"],
-                              bb=tiles["bb"],
-                              name=f"conv_window.{stage}" if stage
-                              else "conv_window")
+                              bb=tiles["bb"], name=name, layout=layout)

@@ -44,9 +44,10 @@ from typing import Callable, Mapping
 import jax
 
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (SUBLANE, TUNING_CACHE, VMEM_BUDGET_BYTES,
-                              choose_conv_blocks, choose_fused_blocks,
-                              choose_qmatmul_blocks, conv_signature,
+from repro.ops.tiling import (LANE, SUBLANE, TUNING_CACHE,
+                              VMEM_BUDGET_BYTES, choose_conv_blocks,
+                              choose_fused_blocks, choose_qmatmul_blocks,
+                              conv_layout, conv_signature, lane_vmem_bytes,
                               legal_block, legal_qmatmul_tiles,
                               window_vmem_bytes)
 
@@ -67,6 +68,8 @@ MIN_GAIN = 0.05
 BATCH_BLOCKS = (1, 2, 4, 8, 16)
 ROW_BLOCKS = (1, 2, 4, 8)
 CHANNEL_CAPS = (32, 64, 128)
+# output-channel blocks of the channels-on-lanes conv: whole lane tiles
+LANE_CHANNEL_CAPS = (128, 256)
 QMM_CAPS = (32, 64, 128, 256)
 # streamed-stage tile heights (output rows per band, DESIGN.md §13);
 # the budget-derived heuristic and the full height join the set
@@ -90,10 +93,17 @@ def _measure(fn: Callable, *args, warmup: int | None = None,
     return best * 1e6
 
 
+def _lanes(op: str, x_shape, w_shape, stride) -> bool:
+    """Whether the call runs the channels-on-lanes window conv."""
+    return op == "conv2d" and conv_layout(
+        x_shape[1], (x_shape[3] - w_shape[3]) // stride[1] + 1) == "lanes"
+
+
 def _axis_candidates(op: str, x_shape, w_shape, stride,
                      heuristic: Mapping[str, int]) -> dict[str, list[int]]:
     """Per-axis candidate values for the conv families, heuristic point
-    included, clamped to valid ranges and legal blocks, deduped."""
+    included, clamped to valid ranges and legal blocks, deduped. The
+    channels-on-lanes conv takes whole lane tiles for ``mb``."""
     bsz, _, h, _ = x_shape
     m, _, kh, _ = w_shape
     ho = (h - kh) // stride[0] + 1
@@ -107,7 +117,10 @@ def _axis_candidates(op: str, x_shape, w_shape, stride,
     else:
         rbs = {r for r in ROW_BLOCKS if r <= ho} | {heuristic["rb"], ho}
         axes["rb"] = sorted(rbs)
-    mbs = {legal_block(m, cap, SUBLANE) for cap in CHANNEL_CAPS}
+    if _lanes(op, x_shape, w_shape, stride):
+        mbs = {legal_block(m, cap, LANE) for cap in LANE_CHANNEL_CAPS}
+    else:
+        mbs = {legal_block(m, cap, SUBLANE) for cap in CHANNEL_CAPS}
     mbs.add(heuristic["mb"])
     axes["mb"] = sorted(mbs)
     return axes
@@ -119,6 +132,10 @@ def _window_fits(op: str, x_shape, w_shape, stride, itemsize: int
     only ever launches tiles the compiler would accept."""
     _, n, _, w = x_shape
     _, _, kh, kw = w_shape
+    if _lanes(op, x_shape, w_shape, stride):
+        return lambda t: lane_vmem_bytes(
+            n, w, kh, kw, stride, t["mb"], t["rb"], t["bb"],
+            itemsize) <= VMEM_BUDGET_BYTES
     pooled = op == "fused_conv_block"
     rows = "pb" if pooled else "rb"
     return lambda t: window_vmem_bytes(
@@ -194,7 +211,7 @@ def tune_conv2d(x, w, b=None, *, stride=(1, 1),
     pol = _no_autotune(policy)
     heur = choose_conv_blocks(x.shape[1], x.shape[2], x.shape[3], w.shape[0],
                               w.shape[2], w.shape[3], tuple(stride),
-                              x.dtype.itemsize)
+                              x.dtype.itemsize, batch=x.shape[0])
     axes = _axis_candidates("conv2d", x.shape, w.shape, tuple(stride), heur)
 
     def launch(**tiles):
@@ -353,10 +370,10 @@ def heuristic_tiles(op: str, *args, **kwargs) -> dict[str, int] | None:
         return None
     x, w = args[0], args[1]
     stride = tuple(kwargs.get("stride", (1, 1)))
-    chooser = (choose_fused_blocks if op == "fused_conv_block"
-               else choose_conv_blocks)
-    heur = chooser(x.shape[1], x.shape[2], x.shape[3], w.shape[0],
-                   w.shape[2], w.shape[3], stride, x.dtype.itemsize)
+    dims = (x.shape[1], x.shape[2], x.shape[3], w.shape[0], w.shape[2],
+            w.shape[3], stride, x.dtype.itemsize)
+    heur = (choose_fused_blocks(*dims) if op == "fused_conv_block"
+            else choose_conv_blocks(*dims, batch=x.shape[0]))
     heur["bb"] = max(1, min(heur["bb"], x.shape[0]))
     return heur
 

@@ -32,7 +32,8 @@ from typing import Mapping
 
 import numpy as np
 
-__all__ = ["legal_block", "window_vmem_bytes", "choose_conv_blocks",
+__all__ = ["legal_block", "window_vmem_bytes", "conv_layout",
+           "lane_vmem_bytes", "choose_conv_blocks", "choose_lane_blocks",
            "choose_fused_blocks", "choose_qmatmul_blocks",
            "legal_qmatmul_tiles",
            "choose_tree_rows", "TuningCache", "TUNING_CACHE", "tile_params",
@@ -51,8 +52,10 @@ LANE = 128
 INT8_SUBLANE = 32
 
 # version of the persisted tuning-cache JSON schema (bumped when the key or
-# row layout changes; older/newer files fall back to heuristics on load)
-SCHEMA_VERSION = 1
+# row layout changes, or what a row's tiles mean; older/newer files fall
+# back to heuristics on load). 2: a ``conv2d`` entry's ``mb`` is a lane
+# block wherever the conv takes the channels-on-lanes layout.
+SCHEMA_VERSION = 2
 
 
 def _platform() -> str:
@@ -122,10 +125,91 @@ def _largest_rows(n, w, kh, kw, stride, mb, itemsize, full, *, pooled):
     return best
 
 
-def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
-                       stride: tuple[int, int], itemsize: int
+def conv_layout(n: int, wo: int) -> str:
+    """The operand layout of the window conv kernel at this shape:
+    ``"lanes"`` (channels on the MXU's lanes) where the input channels
+    fill more of a 128-lane pass than the output width does, and at
+    least half of it; else ``"slab"`` (output width on the lanes).
+
+    Under 64 channels a tap of the lane kernel contracts less than half
+    the MXU's depth, where the slab contracts a kernel row's Kw·N at
+    once; and the slab is the contraction ``kernels/fused_cwp`` runs, so
+    such a conv computes the same sums fused or not (the paper CNN's
+    15-channel conv2, DESIGN.md §8)."""
+    return ("lanes" if min(n, LANE) > min(wo, LANE) and n >= LANE // 2
+            else "slab")
+
+
+def lane_vmem_bytes(n: int, w: int, kh: int, kw: int,
+                    stride: tuple[int, int], mb: int, rows: int, bb: int,
+                    itemsize: int) -> int:
+    """VMEM one grid step of the channels-on-lanes window conv occupies,
+    in the same tiled reckoning as ``window_vmem_bytes``: Wo_pad on
+    sublanes, N and MB on lanes. Double-buffered blocks: the halo'd slab
+    (BB, rows_in, sh·sw, Wq, N), the tap weights (Kh·Kw, N, MB), the bias
+    row and the output block (BB, RB, Wo_pad, MB); plus, once, the
+    float32 temporaries of the contraction: one kernel row's operand
+    (BB·RB·Wo_pad, Kw·N, or N per tap) and the accumulator. Wo_pad is Wo
+    rounded up to the sublane tile; a 1x1 conv reads a subsampled input
+    (kernels/conv_window), so its slab has one phase."""
+    sub = SUBLANE * 4 // itemsize
+    sh, sw = (1, 1) if kh == kw == 1 else stride
+    wo_pad = _round_up((w - kw) // stride[1] + 1, sub)
+    rows_in = rows + (kh - 1) // sh
+    wq = wo_pad + (kw - 1) // sw
+    k = kw * n if n % LANE == 0 else n
+    slab = bb * rows_in * sh * sw * _round_up(wq, sub) * _round_up(n, LANE)
+    taps = kh * kw * _round_up(n, sub) * _round_up(mb, LANE)
+    vecs = sub * _round_up(mb, LANE)
+    out = bb * rows * wo_pad * _round_up(mb, LANE)
+    temps = bb * rows * wo_pad * (_round_up(k, LANE) + _round_up(mb, LANE))
+    return 2 * (slab + taps + vecs + out) * itemsize + 4 * temps
+
+
+def _least_padding(full: int, cap: int) -> int:
+    """The block in [1, cap] that pads ``full`` least (the larger on a
+    tie): 8 under a cap of 5 gives 4, 56 under 20 gives 14."""
+    return min(range(1, max(cap, 1) + 1),
+               key=lambda v: (-(-full // v) * v, -v))
+
+
+def choose_lane_blocks(b: int, n: int, h: int, w: int, m: int, kh: int,
+                       kw: int, stride: tuple[int, int], itemsize: int
                        ) -> dict[str, int]:
-    """Heuristic (rb, mb, bb) for the window-stationary conv kernel.
+    """Heuristic (rb, mb, bb) for the channels-on-lanes window conv.
+
+    mb = a 256-lane block (128 where the weights of 256 lanes leave no
+    room). Then the most output rows a step fits under
+    ``VMEM_BUDGET_BYTES`` (``lane_vmem_bytes``), and once a step holds a
+    whole image, the most images: rows x width x images form the
+    contraction's streamed dimension, so the fuller the step, the fuller
+    each MXU pass. Each is the block that pads its axis least.
+    """
+    ho = (h - kh) // stride[0] + 1
+
+    def fits(mb, rows, bb):
+        return lane_vmem_bytes(n, w, kh, kw, stride, mb, rows, bb,
+                               itemsize) <= VMEM_BUDGET_BYTES
+
+    mb = legal_block(m, 2 * LANE, LANE)
+    if not fits(mb, 1, 1):
+        mb = legal_block(m, LANE, LANE)
+    rows = max([r for r in range(1, ho + 1) if fits(mb, r, 1)], default=1)
+    rb = _least_padding(ho, rows)
+    bb = 1
+    if rb == ho:
+        imgs = max([i for i in range(1, b + 1) if fits(mb, ho, i)],
+                   default=1)
+        bb = _least_padding(b, imgs)
+    return {"rb": rb, "mb": mb, "bb": bb}
+
+
+def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
+                       stride: tuple[int, int], itemsize: int,
+                       batch: int = 1) -> dict[str, int]:
+    """Heuristic (rb, mb, bb) for the window-stationary conv kernel, in
+    the layout ``conv_layout`` gives the shape: ``choose_lane_blocks``
+    for channels on lanes; for the slab, as below.
 
     mb = min(m, 128) (MXU lane width) rounded to a legal block, then the
     largest rb whose grid step fits ``VMEM_BUDGET_BYTES``
@@ -135,6 +219,9 @@ def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
     autotuner (DESIGN.md §10).
     """
     ho = (h - kh) // stride[0] + 1
+    if conv_layout(n, (w - kw) // stride[1] + 1) == "lanes":
+        return choose_lane_blocks(batch, n, h, w, m, kh, kw, stride,
+                                  itemsize)
     mb = legal_block(m, 128, SUBLANE)
     rb = _largest_rows(n, w, kh, kw, stride, mb, itemsize, ho, pooled=False)
     return {"rb": rb, "mb": mb, "bb": 1}
