@@ -1,9 +1,8 @@
 """Tab. I reproduction: the paper CNN's structure, parameters, FLOPs.
 
-Parameterized over ``img_size`` (the streaming PRs run the same table at
-high resolution to show where the per-layer activation footprint crosses
-``STREAM_VMEM_BUDGET_BYTES``); the paper's Tab. I numbers are asserted
-only at the default 28×28.
+Parameterized over ``img_size`` (the same table at high resolution shows
+each layer's activation footprint); the paper's Tab. I numbers are
+asserted only at the default 28×28.
 """
 from __future__ import annotations
 

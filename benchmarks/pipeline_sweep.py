@@ -69,9 +69,8 @@ def _best_us(fn, *args, warmup: int = 3, iters: int = 25) -> float:
 def sweep(batches=BATCHES, quants=QUANTS, *, img_size=28, warmup=3,
           iters=25):
     """-> rows [{quant, batch, eager_us, plan_us, gops_eager, gops_plan,
-    speedup}]. ``img_size`` scales the workload past MNIST — above the
-    streaming budget the compiled plan's over-budget stages execute as
-    halo row bands (DESIGN.md §13) while eager stays full-frame."""
+    speedup}]. ``img_size`` scales the workload past MNIST; the kernels'
+    row-block grid tiles each stage's rows (DESIGN.md §13)."""
     key = jax.random.PRNGKey(0)
     cfg = PaperCNNConfig(img_size=img_size)
     flops1 = cfg.flops_per_image()
@@ -270,9 +269,8 @@ if __name__ == "__main__":
     ap.add_argument("--no-json", action="store_true",
                     help="skip the BENCH_pipeline.json trajectory write")
     ap.add_argument("--img-size", type=int, default=28,
-                    help="input resolution; above the streaming budget "
-                         "the plan's stages run as halo row bands "
-                         "(DESIGN.md §13)")
+                    help="input resolution (the kernels' row blocks tile "
+                         "each stage's rows, DESIGN.md §13)")
     args = ap.parse_args()
     print("name,us_per_call,derived")
     if args.smoke:

@@ -29,10 +29,9 @@ def main() -> None:
     from benchmarks import (addtree_resources, batch_sweep, cnn_table,
                             gops_table, op_sweep, pipeline_sweep,
                             plan_boot, roofline_table, serve_slo,
-                            serve_throughput, shard_sweep, stream_sweep,
-                            window_pipeline)
+                            serve_throughput, shard_sweep, window_pipeline)
     for mod in (cnn_table, addtree_resources, window_pipeline, op_sweep,
-                pipeline_sweep, stream_sweep, shard_sweep, batch_sweep,
+                pipeline_sweep, shard_sweep, batch_sweep,
                 gops_table, roofline_table, serve_throughput, serve_slo,
                 plan_boot):
         try:

@@ -6,8 +6,9 @@ behind the ``Frontend``), with the op registry pinned to the ``pallas``
 backend so every conv stage runs a compiled Pallas kernel (a kernel that
 cannot take a call raises; nothing falls back):
 
-  * ``highres_cnn`` at its published config: 224x224x3, batch 8, bucket
-    ladder 1/2/4/8 — the early blocks stream as halo row bands;
+  * ``highres_cnn``, a VGG-style stack that is not a published config:
+    224x224x3, batch 8, bucket ladder 1/2/4/8 — each block is one
+    ``fused_cwp`` call, its rows tiled by the kernel's row-block grid;
   * ``mnist_cnn``, the paper's CNN (Tab. I);
   * ``resnet50``, ResNet-50 v1.5 at 224x224x3: padded, strided and 1x1
     convs, batch norm folded at bind, residual adds.
@@ -151,9 +152,7 @@ def stage_backends(plan) -> list[str]:
                     else "xla einsum")
         else:
             continue
-        tiling = getattr(node, "tiling", None)
-        band = f", streamed {tiling}" if tiling is not None else ""
-        out.append(f"%{node.id} {node.op} -> {kind}{band}")
+        out.append(f"%{node.id} {node.op} -> {kind}")
     return out
 
 

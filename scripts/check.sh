@@ -73,22 +73,6 @@ check_schema(history[-1])
 print(f"BENCH_slo schema OK ({len(history)} point(s))")
 PY
 
-echo "== stream_sweep smoke (halo-banded streaming, bitwise + schema gates) =="
-PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.stream_sweep \
-  --smoke --out "$TUNE_TMP/stream.json"
-PYTHONPATH=src:. python - "$TUNE_TMP/stream.json" <<'PY'
-import json, sys
-from benchmarks.stream_sweep import check_schema
-history = json.loads(open(sys.argv[1]).read())
-assert isinstance(history, list) and history, "smoke output not a history list"
-check_schema(history[-1], smoke=True)
-committed = json.loads(open("BENCH_stream.json").read())
-assert isinstance(committed, list) and committed, \
-    "BENCH_stream.json not a history list"
-check_schema(committed[-1])          # full schema: a >=224 sweep point
-print(f"BENCH_stream schema OK (smoke + {len(committed)} committed point(s))")
-PY
-
 echo "== plan-artifact smoke (cross-process save -> zero-derivation boot, bitwise parity) =="
 PYTHONPATH=src python - "$TUNE_TMP/plans" <<'PY'
 import sys

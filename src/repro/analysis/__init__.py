@@ -2,11 +2,11 @@
 (DESIGN.md §14).
 
 The paper's architecture only works because hard invariants hold —
-channel counts divide the ICP/OCP mesh (Eq. 6/7), the window pipeline's
-per-band working set fits the buffer budget (§III.B), int8 requant stays
-exact per-channel. This package checks those invariants *statically*,
-once, with a named rule and a fix hint, instead of letting a mistyped
-stage compile and die at dispatch:
+channel counts divide the ICP/OCP mesh (Eq. 6/7), every stage's shapes
+follow from its inputs (Eq. 1–2), int8 requant stays exact per-channel.
+This package checks those invariants *statically*, once, with a named
+rule and a fix hint, instead of letting a mistyped stage compile and die
+at dispatch:
 
   * ``repro.analysis.rules`` / ``engine`` — an AST lint engine over the
     source tree. Every regex grep-gate that used to live in
@@ -20,8 +20,8 @@ stage compile and die at dispatch:
   * ``repro.analysis.verifier`` — ``verify_plan(plan_or_bound)``
     statically re-derives and checks every stage of a compiled
     ``ExecutionPlan`` / ``BoundPlan`` before any dispatch: shape/dtype
-    flow, quantization invariants, sharding legality, streaming
-    legality, artifact-schema coherence. Wired into
+    flow, quantization invariants, sharding legality, artifact-schema
+    coherence. Wired into
     ``compile_model``/``bind`` under ``verify=True`` and into the
     artifact loader, so a corrupt plan is rejected with a named
     violation instead of a downstream crash.

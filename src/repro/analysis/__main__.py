@@ -8,7 +8,7 @@ error-severity finding or plan violation:
      stable sorted one-line-per-finding summary (diffable across CI
      runs), or JSON with ``--json``.
   2. **Verify**: compiles the reference models (PaperCNN across every
-     quant mode, the 224x224 VGG-style model with streamed stages) with
+     quant mode, the 224x224 VGG-style model) with
      ``verify=False`` and then runs ``verify_plan`` explicitly — so the
      gate exercises the verifier itself, not just the compile wiring.
 
@@ -48,7 +48,7 @@ def _run_verify() -> int:
               lambda q=q: PaperCNN(PaperCNNConfig()).compile(
                   ExecPolicy(quant=q), verify=False))
              for q in ("none", "qformat", "int8")]
-    cases.append(("highres_vgg[streamed]",
+    cases.append(("highres_vgg",
                   lambda: VGGStyleCNN(VGGStyleCNNConfig()).compile(
                       verify=False)))
     for name, build in cases:

@@ -327,48 +327,6 @@ class RawClockRule(BaseRule):
         return out
 
 
-@register
-class StreamScaleRule(BaseRule):
-    """Direct conv dispatch with a ≥220 spatial literal in its
-    neighborhood (DESIGN.md §13): large images go through compiled plans
-    whose placement pass bands them, never ad-hoc full-frame dispatch."""
-
-    id = "stream-scale"
-    doc = "full-image conv dispatch at streaming scale (>=220 literal)"
-    anchor = "DESIGN.md §13"
-    fix = ("compile the model (stream placement bands over-budget "
-           "stages) or use repro.stream executors")
-    exempt_prefixes = ("src/repro/stream/", "src/repro/graph/",
-                       "src/repro/kernels/", "src/repro/ops/")
-    WINDOW = 8                      # lines around the conv call to scan
-    _CONV_NAMES = ("conv2d", "fused_conv_block", "conv2d_window",
-                   "fused_conv_window")
-
-    def check(self, tree, path, lines):
-        conv, dims = [], set()
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Call):
-                name = _call_name(node).rsplit(".", 1)[-1]
-                if name in self._CONV_NAMES:
-                    conv.append(node.lineno)
-                elif name == "dispatch" and node.args \
-                        and isinstance(node.args[0], ast.Constant) \
-                        and node.args[0].value in ("conv2d",
-                                                   "fused_conv_block"):
-                    conv.append(node.lineno)
-            elif isinstance(node, ast.Constant) \
-                    and type(node.value) is int and node.value >= 220:
-                dims.add(node.lineno)
-        out = []
-        for ln in conv:
-            lo, hi = ln - self.WINDOW, ln + self.WINDOW
-            if any(lo <= d <= hi for d in dims):
-                out.append(self.finding(
-                    path, ln,
-                    "full-image conv dispatch at streaming scale", lines))
-        return out
-
-
 # ---------------------------------------------------------------------------
 # rules the regexes could not express
 

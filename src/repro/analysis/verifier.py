@@ -17,9 +17,6 @@ Invariant families (each a stable ``Violation.code`` prefix):
   * ``shard-*`` — ICP/OCP/2-D divisibility against the mesh (Eq. 6/7,
     icp × ocp factorization of the model axis, gather-axis purity), data
     axis presence, flatten-gather placement at the conv→fc boundary;
-  * ``stream-*`` — band cuts never straddle a 2×2 pool window, per-band
-    working set fits the budget, halo accounting matches K/stride
-    (§III.B), banding not stamped on a sharded stage;
   * ``artifact-coherence`` — every fingerprint input serializes (graph
     doc roundtrip, policies, params pytree keys), so the plan can
     become an artifact (DESIGN.md §12).
@@ -44,7 +41,6 @@ from repro.graph.ir import (AddNode, BatchNormFoldNode, Conv2DNode,
                             MaxPool2Node, MaxPoolNode, Node, QuantizeNode,
                             ReluNode, TensorSpec)
 from repro.graph.passes import stage_input_spec
-from repro.stream.tiling import check_tiling
 
 __all__ = ["Violation", "PlanVerificationError", "verify_plan"]
 
@@ -407,12 +403,6 @@ def _check_sharding(plan, out: list[Violation]) -> None:
                 code="shard-mesh", node=node.id,
                 message=f"stage opts into data-axis sharding but mesh "
                         f"{dict(mesh.shape)} has no 'data' axis"))
-        if getattr(node, "tiling", None) is not None:
-            out.append(Violation(
-                code="stream-sharded-stage", node=node.id,
-                message="spatial banding stamped on a channel-sharded "
-                        "stage — the executor cannot compose them yet",
-                hint="the placement pass skips sharded stages; re-place"))
 
     if not sharded:
         return
@@ -472,26 +462,6 @@ def _check_sharding(plan, out: list[Violation]) -> None:
                          "mesh's data axis"))
                 break
             frontier.extend(src.inputs)
-
-
-# ---------------------------------------------------------------------------
-# streaming legality (§III.B; DESIGN.md §13)
-
-def _check_streaming(plan, out: list[Violation]) -> None:
-    graph = plan.graph
-    for node in graph:
-        tiling = getattr(node, "tiling", None)
-        if tiling is None:
-            continue
-        fused = isinstance(node, FusedConvBlockNode)
-        act, wshape = _conv_like_specs(graph, node)
-        if len(act.shape) != 4 or len(wshape) != 4:
-            continue                # shape-flow already flagged this stage
-        for code, msg in check_tiling(
-                tiling, fused=fused, in_shape=tuple(act.shape),
-                w_shape=wshape, stride=tuple(node.stride),
-                itemsize=np.dtype(act.dtype).itemsize):
-            out.append(Violation(code=code, message=msg, node=node.id))
 
 
 # ---------------------------------------------------------------------------
@@ -581,7 +551,6 @@ def verify_plan(plan_or_bound, *, raise_on_violation: bool = True
                         f"{type(e).__name__}: {e}"))
     _check_quant(plan, out)
     _check_sharding(plan, out)
-    _check_streaming(plan, out)
     _check_artifact_coherence(plan, bound, out)
     if bound is not None:
         _check_folded(bound, out)

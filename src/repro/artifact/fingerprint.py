@@ -42,7 +42,8 @@ __all__ = ["SCHEMA_VERSION", "REPRO_PLAN_VERSION", "params_digest",
 # version of the on-disk artifact schema (manifest layout + payload
 # naming). Bumped when the format changes; loaders refuse other versions
 # and the caller falls back to the fresh pipeline.
-SCHEMA_VERSION = 1
+# v2: conv and fused-conv nodes no longer carry a ``tiling`` key.
+SCHEMA_VERSION = 2
 
 # version of the *semantics* a plan encodes (executor calling
 # conventions, pass meanings). Part of the fingerprint so a plan written

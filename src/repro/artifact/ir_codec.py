@@ -22,7 +22,6 @@ from repro.graph.ir import (AddNode, BatchNormFoldNode, BatchNormNode,
                             FusedConvBlockNode, GlobalAvgPoolNode, Graph,
                             InputNode, MaxPool2Node, MaxPoolNode, ParamRef,
                             QuantizeNode, ReluNode, ShardingSpec, TensorSpec)
-from repro.stream.tiling import tiling_from_doc, tiling_to_doc
 
 __all__ = ["graph_to_doc", "graph_from_doc"]
 
@@ -97,8 +96,7 @@ def _node_doc(node) -> dict:
     if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
         doc.update(w=_ref_doc(node.w), b=_ref_doc(node.b),
                    stride=list(node.stride),
-                   sharding=_shard_doc(node.sharding),
-                   tiling=tiling_to_doc(node.tiling))
+                   sharding=_shard_doc(node.sharding))
         if isinstance(node, FusedConvBlockNode):
             doc["odd"] = node.odd
         elif node.padding != (0, 0):    # VALID convs keep their old doc
@@ -129,8 +127,7 @@ def _node_from(doc: dict):
     if cls in (Conv2DNode, FusedConvBlockNode):
         kw.update(w=_ref_from(doc["w"]), b=_ref_from(doc["b"]),
                   stride=tuple(doc["stride"]),
-                  sharding=_shard_from(doc.get("sharding")),
-                  tiling=tiling_from_doc(doc.get("tiling")))
+                  sharding=_shard_from(doc.get("sharding")))
         if cls is FusedConvBlockNode:
             kw["odd"] = doc["odd"]
         else:

@@ -39,6 +39,8 @@ __all__ = [
     "pool_output_size",
     "fill_latency",
     "reuse_ratio",
+    "halo_rows",
+    "band_input_rows",
     "LineBufferSim",
     "extract_windows",
     "conv2d_ref",
@@ -113,8 +115,8 @@ def fill_latency(k: int, w: int, kw: int | None = None) -> int:
     Generalized to a non-square Kh×Kw window (``k`` rows, ``kw`` cols,
     default square): T_u = (Kh-1)·W + Kw - 1 — Kh-1 full rows must be
     resident plus Kw-1 pixels of the current row. The Kh-1 resident rows
-    are exactly the streaming tiler's stride-1 halo
-    (``repro.stream.halo_rows(kh, 1)``)."""
+    are exactly the stride-1 halo of the kernels' row blocks
+    (``halo_rows(kh, 1)``)."""
     kw = k if kw is None else kw
     return (k - 1) * w + kw - 1
 
@@ -123,6 +125,26 @@ def reuse_ratio(k: int) -> float:
     """Paper Fig. 6: fraction of data shared between horizontally adjacent
     windows = (K-1)/K."""
     return (k - 1) / k
+
+
+def halo_rows(kh: int, sh: int = 1) -> int:
+    """Input rows shared by vertically adjacent row blocks: kh - sh
+    (clamped at 0 — stride ≥ kernel means no reuse). At stride 1 this is
+    the paper's K-1 resident shift-buffer rows, and
+    ``halo_rows(k, 1) / k == reuse_ratio(k)``."""
+    return max(kh - sh, 0)
+
+
+def band_input_rows(rb: int, kh: int, sh: int = 1) -> int:
+    """Input rows a block of ``rb`` conv-output rows reads: (rb-1)·sh + kh,
+    the height of the halo'd slab each grid step of the window kernels
+    DMA's (DESIGN.md §13) — the vertical form of the line buffer's
+    fill+stream span (``band_input_rows(1, k, 1) == k``; one more output
+    row adds ``sh`` rows, the same marginal cost as one more line-buffer
+    step down)."""
+    if rb < 1:
+        raise ValueError(f"band needs >= 1 output rows, got {rb}")
+    return (rb - 1) * sh + kh
 
 
 class LineBufferSim:
@@ -146,8 +168,8 @@ class LineBufferSim:
 
     ``k`` may be a (Kh, Kw) pair for non-square windows: WB becomes
     Kh×Kw, SB becomes (Kh-1)×(W-Kw), and T_u = (Kh-1)·W + Kw - 1 — the
-    reference model for the streaming tiler's halo accounting
-    (repro.stream, DESIGN.md §13).
+    reference model for the halo accounting of the kernels' row blocks
+    (``halo_rows``, ``band_input_rows``, DESIGN.md §13).
     """
 
     def __init__(self, k: int | tuple[int, int], w: int):

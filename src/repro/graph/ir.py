@@ -26,10 +26,7 @@ exactly like a bitstream serves any weight ROM contents.
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import TYPE_CHECKING, Iterator
-
-if TYPE_CHECKING:
-    from repro.stream.tiling import SpatialTiling
+from typing import Iterator
 
 __all__ = ["TensorSpec", "ParamRef", "ShardingSpec", "Node", "InputNode",
            "Conv2DNode", "ReluNode", "MaxPool2Node", "FlattenNode",
@@ -172,18 +169,15 @@ class Conv2DNode(Node):
     b: ParamRef | None = None
     stride: tuple[int, int] = (1, 1)
     sharding: ShardingSpec | None = None
-    # streaming row-band spec (repro.stream, DESIGN.md §13); None = untiled
-    tiling: "SpatialTiling | None" = None
     padding: tuple[int, int] = (0, 0)
 
     def describe(self) -> str:
         shard = "" if self.sharding is None else f" shard={self.sharding}"
-        tile = "" if self.tiling is None else f" tile={self.tiling}"
         pad = "" if self.padding == (0, 0) else \
             f" p={self.padding[0]}x{self.padding[1]}"
         return (f"w={self.w} k={self.w.shape[2]}x{self.w.shape[3]} "
                 f"s={self.stride[0]}x{self.stride[1]}" + pad
-                + ("" if self.b is None else f" b={self.b}") + shard + tile)
+                + ("" if self.b is None else f" b={self.b}") + shard)
 
 
 @dataclass(frozen=True)
@@ -263,15 +257,12 @@ class FusedConvBlockNode(Node):
     stride: tuple[int, int] = (1, 1)
     odd: str = "raise"
     sharding: ShardingSpec | None = None
-    # streaming row-band spec in POOLED rows (DESIGN.md §13); None = untiled
-    tiling: "SpatialTiling | None" = None
 
     def describe(self) -> str:
         shard = "" if self.sharding is None else f" shard={self.sharding}"
-        tile = "" if self.tiling is None else f" tile={self.tiling}"
         return (f"w={self.w} k={self.w.shape[2]}x{self.w.shape[3]} "
                 f"s={self.stride[0]}x{self.stride[1]} odd={self.odd}"
-                + shard + tile)
+                + shard)
 
 
 @dataclass(frozen=True)

@@ -164,21 +164,8 @@ class ExecutionPlan:
             if self.mesh is None or spec is None or spec.mode == "none":
                 # single-device, or pure data parallel over the mesh
                 pol = self._stage_policy(base, tuned.get(node.id))
-                tiling = getattr(node, "tiling", None)
 
                 def stage(xl, wl, bl):
-                    if tiling is not None:
-                        # over-budget stage: stream halo-overlapped row
-                        # bands through the same op registry (§13)
-                        from repro.stream.executor import (
-                            stream_conv2d, stream_fused_conv_block)
-                        if fused:
-                            return stream_fused_conv_block(
-                                xl, wl, bl, stride=node.stride,
-                                odd=node.odd, tiling=tiling, policy=pol,
-                                stage=sid)
-                        return stream_conv2d(xl, wl, bl, stride=node.stride,
-                                             tiling=tiling, policy=pol)
                     if fused:
                         return fused_conv_block(xl, wl, bl,
                                                 stride=node.stride,
@@ -391,10 +378,7 @@ class ExecutionPlan:
             x = jnp.asarray(rng.standard_normal(shape), spec.dtype)
             if isinstance(node, (Conv2DNode, FusedConvBlockNode)):
                 fused = isinstance(node, FusedConvBlockNode)
-                tiling = getattr(node, "tiling", None)
                 op = "fused_conv_block" if fused else "conv2d"
-                if tiling is not None:      # streamed stage: tune th instead
-                    op = "stream_" + op
                 wv = (folded[node.inputs[1]] if len(node.inputs) > 1
                       else node.w.fetch(params))
                 bv = (folded.get(node.inputs[2])
@@ -407,14 +391,8 @@ class ExecutionPlan:
                 else:
                     w_arr = wv
                 kw = dict(stride=node.stride)
-                if tiling is not None:
-                    kw["tiling"] = tiling
                 if fused:
                     kw["scale"] = scale     # the in-kernel requant epilogue
-                    if tiling is not None:
-                        kw["odd"] = node.odd
-                elif tiling is not None:
-                    kw["scale"] = scale
                 yield node, op, (x, w_arr, bv), kw
             else:                           # DenseNode
                 wq = folded.get(node.id)
@@ -623,7 +601,6 @@ class BoundPlan:
 def compile_model(model, input_shape: tuple[int, ...] | None = None, *,
                   policy: ExecPolicy | None = None, fuse: bool = True,
                   mesh: Mesh | None = None, autotune: bool = False,
-                  stream_budget: int | None = None,
                   dtype: str = "float32",
                   verify: bool = True) -> ExecutionPlan:
     """trace → passes → plan for any model whose forward routes through
@@ -644,15 +621,10 @@ def compile_model(model, input_shape: tuple[int, ...] | None = None, *,
     §10: ``plan.bind`` measures tile candidates per stage (tuning-cache
     hits skip the measurement) and bakes the winners into the BoundPlan.
 
-    ``stream_budget`` (bytes, default
-    ``repro.stream.STREAM_VMEM_BUDGET_BYTES``) is the per-image stage
-    footprint above which conv/fused stages get a ``SpatialTiling`` and
-    execute as halo-overlapped row bands (DESIGN.md §13).
-
     ``verify=True`` (the default) runs the static plan verifier
     (``repro.analysis.verify_plan``, DESIGN.md §14) over the finished
-    plan — shape/dtype flow, quant invariants, sharding and streaming
-    legality, artifact coherence — raising ``PlanVerificationError``
+    plan — shape/dtype flow, quant invariants, sharding legality,
+    artifact coherence — raising ``PlanVerificationError``
     with named violations. Verification is read-only: verified and
     unverified compiles produce byte-identical plans.
     """
@@ -680,12 +652,6 @@ def compile_model(model, input_shape: tuple[int, ...] | None = None, *,
                 graph, mesh.shape["model"],
                 override=quant_pol.channel_parallel,
                 data="data" in mesh.axis_names)
-    # streaming spatial tiling (DESIGN.md §13): stamp over-budget stages.
-    # Runs on every compile — under-budget graphs (all MNIST-sized plans)
-    # come back node-for-node identical, so fingerprints are unchanged.
-    from repro.stream.passes import place_spatial_tiling
-    with phase("place"):
-        graph = place_spatial_tiling(graph, budget_bytes=stream_budget)
     plan = ExecutionPlan(graph=graph, quant=quant_pol.quant,
                          qformat=quant_pol.qformat, compile_policy=pol,
                          mesh=mesh,

@@ -33,11 +33,13 @@ why wide convs take the channels-on-lanes kernel. The wrappers transpose
 NCHW in and out around the call.
 
 Reuse invariant preserved: each input element crosses HBM->VMEM once per
-row block (halo rows of adjacent blocks excepted: Kh−stride_h rows,
-amortized to (Kh−s)/(RB·s) per block, the overlap the paper's
-SHIFT_BUFFER absorbs), through element offsets on every axis
-(``pl.Element``), double-buffered against the previous step's MXU work.
-Grid: (B/BB, Ho/RB, M/MB); block sizes come from ops.py.
+row block, in a slab of ``band_input_rows(RB, Kh, sh)`` rows (halo rows
+of adjacent blocks excepted: ``halo_rows(Kh, sh)``, amortized to
+(Kh−s)/(RB·s) per block, the overlap the paper's SHIFT_BUFFER absorbs),
+through element offsets on every axis (``pl.Element``), double-buffered
+against the previous step's MXU work. This grid is the repo's one row
+tiling (DESIGN.md §13). Grid: (B/BB, Ho/RB, M/MB); block sizes come from
+ops.py.
 """
 from __future__ import annotations
 
@@ -46,6 +48,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core.window import band_input_rows
 
 # fp32 operands contract at full fp32 precision on the MXU; int8 codes
 # (integer-valued fp32, |code| <= 127) are exact at any precision
@@ -165,7 +169,8 @@ def conv2d_window_pallas(x: jax.Array, w: jax.Array, b: jax.Array, *,
         kernel,
         grid=(bsz // bb, ho // rb, m // mb),
         in_specs=[
-            slab_spec(bb, (rb - 1) * sh + kh, sw, n, xs.shape[-1], rb * sh),
+            slab_spec(bb, band_input_rows(rb, kh, sh), sw, n, xs.shape[-1],
+                      rb * sh),
             pl.BlockSpec((kh, mb, kw * n), lambda bi, ri, mi: (0, mi, 0)),
             pl.BlockSpec((mb, 1), lambda bi, ri, mi: (mi, 0)),
         ],

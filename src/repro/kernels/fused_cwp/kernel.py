@@ -9,8 +9,9 @@ step. Mapping of the paper's §III.B structure:
   FPGA                          TPU (this kernel)
   ----                          -----------------
   window buffer streams rows    the input slab covers 2·PB conv rows
-    into conv                     ((2·PB−1)·sh + Kh input rows, halo
-                                  overlap with the next block)
+    into conv                     (``band_input_rows(2·PB, Kh, sh)``
+                                  input rows, halo overlap with the
+                                  next block)
   conv → relu wired directly    the MXU accumulators are requantized,
                                   biased and relu'd in VREGs, never
                                   written back
@@ -50,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quantize import requant_epilogue
+from repro.core.window import band_input_rows
 from repro.kernels.conv_window.kernel import (conv_row, slab_layout,
                                               slab_spec, tap_weights)
 
@@ -121,8 +123,8 @@ def fused_cwp_pallas(x: jax.Array, w: jax.Array, s: jax.Array,
         kernel,
         grid=(bsz // bb, po // pb, m // mb),
         in_specs=[
-            slab_spec(bb, (2 * pb - 1) * sh + kh, 2 * sw, n, xs.shape[-1],
-                      2 * pb * sh),
+            slab_spec(bb, band_input_rows(2 * pb, kh, sh), 2 * sw, n,
+                      xs.shape[-1], 2 * pb * sh),
             pl.BlockSpec((kh, mb, kw * n), lambda bi, pi, mi: (0, mi, 0)),
             pl.BlockSpec((mb, 1), lambda bi, pi, mi: (mi, 0)),
             pl.BlockSpec((mb, 1), lambda bi, pi, mi: (mi, 0)),

@@ -152,7 +152,6 @@ class PaperCNN:
     def compile(self, policy: ExecPolicy | None = None, *,
                 fuse: bool = True, batch: int = 1,
                 mesh=None, autotune: bool = False,
-                stream_budget: int | None = None,
                 verify: bool = True) -> "ExecutionPlan":
         """Lift this model into a fused, static ``ExecutionPlan``
         (repro.graph, DESIGN.md §8): trace → conv+relu+pool fusion →
@@ -173,7 +172,7 @@ class PaperCNN:
         from repro.graph.plan import compile_model
         return compile_model(self, self.input_shape(batch), policy=policy,
                              fuse=fuse, mesh=mesh, autotune=autotune,
-                             stream_budget=stream_budget, verify=verify)
+                             verify=verify)
 
     def loss(self, params: dict, batch: dict, ctx=None
              ) -> tuple[jax.Array, dict]:

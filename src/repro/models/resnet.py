@@ -177,16 +177,16 @@ class ResNet:
     def compile(self, policy: ExecPolicy | None = None, *,
                 fuse: bool = True, batch: int = 1, mesh=None,
                 autotune: bool = False,
-                stream_budget: int | None = None,
                 verify: bool = True) -> "ExecutionPlan":
-        """Same contract as ``PaperCNN.compile`` (DESIGN.md §8-§10,
-        §13): trace -> batch-norm fold -> quant lowering -> placement.
-        No stage is fused (no conv is followed by a 2x2 pool) or
-        streamed (every conv is padded or 1x1)."""
+        """Same contract as ``PaperCNN.compile`` (DESIGN.md §8-§10):
+        trace -> batch-norm fold -> quant lowering -> placement.
+        No stage is fused (no conv is followed by a 2x2 pool); each conv
+        is one ``conv_window`` call, its rows tiled by the kernel's
+        row-block grid (DESIGN.md §13)."""
         from repro.graph.plan import compile_model
         return compile_model(self, self.input_shape(batch), policy=policy,
                              fuse=fuse, mesh=mesh, autotune=autotune,
-                             stream_budget=stream_budget, verify=verify)
+                             verify=verify)
 
     def loss(self, params: dict, batch: dict, ctx=None
              ) -> tuple[jax.Array, dict]:

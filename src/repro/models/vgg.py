@@ -1,10 +1,11 @@
-"""Multi-block VGG-style CNN — the high-resolution streaming workload.
+"""Multi-block VGG-style CNN — a 224×224 smoke for the window kernels.
 
 The paper's PaperCNN tops out at 28×28; this model stacks conv blocks
 (conv → relu → 2×2 pool, each fusable by the graph compiler into one
-``fused_conv_block`` stage) deep enough that a ≥224×224 input's early
-stages blow past the streaming budget and exercise ``repro.stream``
-(DESIGN.md §13). VALID padding throughout, like the paper's accelerator
+``fused_conv_block`` stage) at a ≥224×224 input; each stage is one
+kernel call over its whole frame, whose halo'd row-block grid sizes
+every step to the VMEM budget (DESIGN.md §13). It is not a published
+configuration. VALID padding throughout, like the paper's accelerator
 — no SAME-pad convenience, so block kernel sizes are chosen to keep
 every pre-pool feature map even (the ``maxpool2`` odd='raise' sizing
 discipline).
@@ -129,7 +130,7 @@ class VGGStyleCNN:
     def forward(self, params: dict, images: jax.Array) -> jax.Array:
         """(B, C, H, W) -> logits (B, n_classes); every op trace-aware,
         so ``compile`` fuses each block into one ``fused_conv_block``
-        stage and the streaming pass tiles the over-budget ones."""
+        stage."""
         cfg = self.cfg
         x = images
         for i in range(len(cfg.blocks)):
@@ -142,16 +143,15 @@ class VGGStyleCNN:
     def compile(self, policy: ExecPolicy | None = None, *,
                 fuse: bool = True, batch: int = 1, mesh=None,
                 autotune: bool = False,
-                stream_budget: int | None = None,
                 verify: bool = True) -> "ExecutionPlan":
-        """Same contract as ``PaperCNN.compile`` (DESIGN.md §8–§10, §13):
-        trace → block fusion → quant lowering → spatial-tiling placement.
-        At the default 224×224 the early blocks exceed the streaming
-        budget and execute as halo-overlapped row bands."""
+        """Same contract as ``PaperCNN.compile`` (DESIGN.md §8–§10):
+        trace → block fusion → quant lowering. Every block is one
+        ``fused_cwp`` call, its row-block grid sized to the VMEM budget
+        (DESIGN.md §13)."""
         from repro.graph.plan import compile_model
         return compile_model(self, self.input_shape(batch), policy=policy,
                              fuse=fuse, mesh=mesh, autotune=autotune,
-                             stream_budget=stream_budget, verify=verify)
+                             verify=verify)
 
     def loss(self, params: dict, batch: dict, ctx=None
              ) -> tuple[jax.Array, dict]:

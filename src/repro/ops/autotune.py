@@ -52,8 +52,7 @@ from repro.ops.tiling import (LANE, SUBLANE, TUNING_CACHE,
                               window_vmem_bytes)
 
 __all__ = ["ensure_tuned", "tune_conv2d", "tune_fused_conv_block",
-           "tune_qmatmul", "tune_stream_conv2d",
-           "tune_stream_fused_conv_block", "resolved_backend",
+           "tune_qmatmul", "resolved_backend",
            "heuristic_tiles", "TUNE_WARMUP", "TUNE_ITERS", "MIN_GAIN"]
 
 # best-of timing per candidate: min over ITERS after WARMUP compile calls.
@@ -71,9 +70,6 @@ CHANNEL_CAPS = (32, 64, 128)
 # output-channel blocks of the channels-on-lanes conv: whole lane tiles
 LANE_CHANNEL_CAPS = (128, 256)
 QMM_CAPS = (32, 64, 128, 256)
-# streamed-stage tile heights (output rows per band, DESIGN.md §13);
-# the budget-derived heuristic and the full height join the set
-STREAM_TILE_ROWS = (4, 8, 16, 32, 64)
 
 
 def _measure(fn: Callable, *args, warmup: int | None = None,
@@ -280,77 +276,8 @@ def tune_qmatmul(x_codes, w_codes, x_scale, w_scale, *,
     return best
 
 
-def _stream_axis(full: int, heur_th: int) -> list[int]:
-    vals = {v for v in STREAM_TILE_ROWS if v <= full}
-    vals |= {heur_th, max(full // 2, 1), full}
-    return sorted(v for v in vals if 1 <= v <= full)
-
-
-def tune_stream_conv2d(x, w, b=None, *, stride=(1, 1), scale=None,
-                       tiling=None,
-                       policy: ExecPolicy | None = None,
-                       on_point=None) -> dict[str, int]:
-    """Measure tile-height (``th``) candidates for a streamed conv stage
-    (DESIGN.md §13): each candidate re-bands the SAME stage, trading halo
-    re-reads against per-launch overhead. Caches and returns the winner."""
-    from repro.stream.executor import stream_conv2d
-    pol = _no_autotune(policy)
-    kh, sh = w.shape[2], stride[0]
-    ho = (x.shape[2] - kh) // sh + 1
-    heur = {"th": min(tiling.tile_rows, ho)}
-    axes = {"th": _stream_axis(ho, heur["th"])}
-
-    def launch(**tiles):
-        pol_t = pol.with_options(tiling={"stream_conv2d.th": tiles["th"]})
-        return lambda: stream_conv2d(x, w, b, stride=tuple(stride),
-                                     scale=scale, tiling=tiling,
-                                     policy=pol_t)
-
-    best = _descend(axes, heur, launch, on_point=on_point)
-    sig = conv_signature(x.shape, w.shape, tuple(stride))
-    TUNING_CACHE.put("stream_conv2d", sig, x.dtype, best)
-    return best
-
-
-def tune_stream_fused_conv_block(x, w, b=None, *, stride=(1, 1),
-                                 odd="raise", scale=None, tiling=None,
-                                 policy: ExecPolicy | None = None,
-                                 on_point=None) -> dict[str, int]:
-    """Measure tile-height (``th``, in POOLED rows) candidates for a
-    streamed fused stage; caches and returns the winner."""
-    from repro.core.window import pool_output_size
-    from repro.stream.executor import stream_fused_conv_block
-    pol = _no_autotune(policy)
-    kh, sh = w.shape[2], stride[0]
-    ho = (x.shape[2] - kh) // sh + 1
-    po = pool_output_size(ho, odd)
-    heur = {"th": min(tiling.tile_rows, po)}
-    axes = {"th": _stream_axis(po, heur["th"])}
-
-    def launch(**tiles):
-        pol_t = pol.with_options(
-            tiling={"stream_fused_conv_block.th": tiles["th"]})
-        return lambda: stream_fused_conv_block(
-            x, w, b, stride=tuple(stride), odd=odd, scale=scale,
-            tiling=tiling, policy=pol_t)
-
-    best = _descend(axes, heur, launch, on_point=on_point)
-    sig = conv_signature(x.shape, w.shape, tuple(stride))
-    TUNING_CACHE.put("stream_fused_conv_block", sig, x.dtype, best)
-    return best
-
-
 _TUNERS = {"conv2d": tune_conv2d, "fused_conv_block": tune_fused_conv_block,
-           "qmatmul": tune_qmatmul,
-           "stream_conv2d": tune_stream_conv2d,
-           "stream_fused_conv_block": tune_stream_fused_conv_block}
-
-# streamed stages dispatch band-by-band through the inner op family; the
-# pallas-only tuning gate checks capability on the INNER op with the
-# stream-only kwargs stripped
-_STREAM_INNER = {"stream_conv2d": "conv2d",
-                 "stream_fused_conv_block": "fused_conv_block"}
-_STREAM_KWARGS = ("tiling",)
+           "qmatmul": tune_qmatmul}
 
 
 def heuristic_tiles(op: str, *args, **kwargs) -> dict[str, int] | None:
@@ -363,9 +290,6 @@ def heuristic_tiles(op: str, *args, **kwargs) -> dict[str, int] | None:
         m, k = args[0].shape
         n = args[1].shape[1]
         return choose_qmatmul_blocks(m, n, k)
-    if op in _STREAM_INNER:
-        tiling = kwargs.get("tiling")
-        return None if tiling is None else {"th": int(tiling.tile_rows)}
     if op not in ("conv2d", "fused_conv_block"):
         return None
     x, w = args[0], args[1]
@@ -398,9 +322,6 @@ def ensure_tuned(op: str, *args, policy: ExecPolicy | None = None,
     hit = TUNING_CACHE.get(op, _sig_of(op, args, kwargs), args[0].dtype)
     if hit is not None:
         return hit
-    inner = _STREAM_INNER.get(op, op)
-    ikw = {k: v for k, v in kwargs.items() if k not in _STREAM_KWARGS} \
-        if inner != op else kwargs
-    if resolved_backend(inner, *args, policy=policy, **ikw) != "pallas":
+    if resolved_backend(op, *args, policy=policy, **kwargs) != "pallas":
         return None
     return tuner(*args, policy=policy, **kwargs)

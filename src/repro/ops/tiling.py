@@ -32,6 +32,8 @@ from typing import Mapping
 
 import numpy as np
 
+from repro.core.window import band_input_rows
+
 __all__ = ["legal_block", "window_vmem_bytes", "conv_layout",
            "lane_vmem_bytes", "choose_conv_blocks", "choose_lane_blocks",
            "choose_fused_blocks", "choose_qmatmul_blocks",
@@ -103,7 +105,7 @@ def window_vmem_bytes(n: int, w: int, kh: int, kw: int,
     sh, sw = stride
     groups = 2 if pooled else 1
     phases = groups * sw
-    rows_in = (groups * rows - 1) * sh + kh
+    rows_in = band_input_rows(groups * rows, kh, sh)
     wo = (w - kw) // sw + 1
     slab = (bb * rows_in * phases * _round_up(n, SUBLANE)
             * _round_up(-(-w // phases), LANE))

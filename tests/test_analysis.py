@@ -52,7 +52,6 @@ class TestRuleFixtures:
         assert ("benchmarks/bad_dispatch.py", 6, "interpret-literal") in hits
         assert ("benchmarks/bad_chain.py", 5, "conv-chain") in hits
         assert ("benchmarks/bad_shard.py", 5, "shard-map-conv") in hits
-        assert ("benchmarks/bad_stream.py", 6, "stream-scale") in hits
         assert ("src/repro/util/bad_random.py", 7, "global-random") in hits
         assert ("src/repro/util/bad_random.py", 8, "global-random") in hits
         assert ("src/repro/util/bad_except.py", 7, "bare-except") in hits
@@ -95,8 +94,8 @@ class TestRuleFixtures:
         rules = all_rules()
         assert {r.id for r in rules} >= {
             "string-dispatch", "interpret-literal", "conv-chain",
-            "shard-map-conv", "raw-clock", "stream-scale",
-            "global-random", "bare-except", "mutable-default"}
+            "shard-map-conv", "raw-clock", "global-random", "bare-except",
+            "mutable-default"}
         for r in rules:
             assert r.doc and r.anchor.startswith("DESIGN.md")
         assert rule_by_id("raw-clock").anchor == "DESIGN.md §11"
@@ -165,7 +164,7 @@ class TestVerifyPlan:
 
     def test_verification_is_read_only(self):
         from repro.artifact.fingerprint import plan_fingerprint
-        for kw in ({}, {"stream_budget": 10_000}):
+        for kw in ({}, {"batch": 2}):
             a = _model().compile(verify=False, **kw)
             b = _model().compile(verify=True, **kw)
             assert plan_fingerprint(a) == plan_fingerprint(b)
@@ -257,31 +256,6 @@ class TestVerifyPlan:
         with pytest.raises(PlanVerificationError) as e:
             verify_plan(bad)
         assert any(v.code == "shard-mesh" for v in e.value.violations)
-
-    def test_band_cut_straddling_pool_named(self):
-        plan = _model().compile(batch=2, stream_budget=10_000,
-                                verify=False)
-        tiled = [n for n in plan.graph if getattr(n, "tiling", None)]
-        assert tiled, "fixture expects a streamed plan"
-        node = tiled[0]
-        bad = _replace_node(
-            plan, node,
-            tiling=dataclasses.replace(node.tiling, pooled=False))
-        codes = [v.code for v in
-                 verify_plan(bad, raise_on_violation=False)]
-        assert "stream-pool-straddle" in codes
-
-    def test_wrong_halo_named(self):
-        plan = _model().compile(batch=2, stream_budget=10_000,
-                                verify=False)
-        node = next(n for n in plan.graph if getattr(n, "tiling", None))
-        bad = _replace_node(
-            plan, node,
-            tiling=dataclasses.replace(node.tiling,
-                                       halo=node.tiling.halo + 1))
-        codes = [v.code for v in
-                 verify_plan(bad, raise_on_violation=False)]
-        assert "stream-halo" in codes
 
     def test_qtensor_scale_mismatch_named(self):
         model = _model()

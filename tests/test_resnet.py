@@ -231,14 +231,6 @@ def test_residual_plan_roundtrips_through_the_artifact_store(tiny, tmp_path):
     assert _rel_err(loaded(images), want) < TOL
 
 
-def test_no_stage_of_a_resnet_streams(tiny):
-    """Padded and 1x1 stages stay untiled even over a tiny budget: the
-    kernels' row-block grid bounds their VMEM (DESIGN.md §13)."""
-    model = tiny[0]
-    plan = compile_model(model, model.input_shape(2), stream_budget=1024)
-    assert all(getattr(n, "tiling", None) is None for n in plan.graph)
-
-
 def test_quantized_batch_norm_fold_is_refused(tiny):
     graph = fold_batch_norm(trace(tiny[0], (1, 3, 32, 32)))
     with pytest.raises(ValueError, match="folded batch norm"):

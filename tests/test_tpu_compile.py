@@ -3,8 +3,8 @@
 No chip is needed: the TPU compiler is installed here and compiles for a
 topology that is described, not attached. Each case compiles one kernel
 call at a shape the served plans issue — the fused conv block and the
-plain window conv at every conv stage of ``highres_cnn`` (224², per
-stream band) and ``mnist_cnn``, the window conv at ``resnet50``'s stage
+plain window conv at every conv stage of ``highres_cnn`` (224²) and
+``mnist_cnn``, the window conv at ``resnet50``'s stage
 shapes (padded, strided, 1x1), the int8 GEMM at both fc shapes, and the
 addition tree — with interpret mode off, and asserts that the executable
 holds a Mosaic kernel (``tpu_custom_call``); every served bucket plan
@@ -31,12 +31,10 @@ from repro.kernels.fused_cwp.ops import fused_conv_window
 from repro.kernels.qmatmul.ops import qmatmul
 
 # (B, N, H, W, M, K) of every conv-stage kernel call the served plans
-# make at batch 8; streamed stages appear once per distinct band height.
+# make at batch 8: each stage is one call over its whole frame.
 STAGES = {
-    "highres_b0_band94": (8, 3, 94, 224, 8, 5),
-    "highres_b0_band44": (8, 3, 44, 224, 8, 5),
-    "highres_b1_band86": (8, 8, 86, 110, 16, 3),
-    "highres_b1_band26": (8, 8, 26, 110, 16, 3),
+    "highres_b0": (8, 3, 224, 224, 8, 5),
+    "highres_b1": (8, 8, 110, 110, 16, 3),
     "highres_b2": (8, 16, 54, 54, 32, 3),
     "highres_b3": (8, 32, 26, 26, 32, 3),
     "mnist_conv1": (8, 1, 28, 28, 15, 3),
@@ -149,7 +147,7 @@ def test_addtree_compiles(one_chip):
 @pytest.mark.parametrize("arch", ["highres_cnn", "mnist_cnn"])
 def test_served_bucket_plan_compiles(one_chip, arch, quant, bucket):
     """Each bucket of the batch-8 ladder ``VisionEngine`` serves, as the
-    bound plan it compiles: every conv stage (every stream band) and,
+    bound plan it compiles: every conv stage and,
     under int8, the fc GEMM is a Mosaic kernel."""
     from repro.configs.registry import get_arch
     from repro.ops import ExecPolicy
@@ -166,7 +164,7 @@ def test_served_bucket_plan_compiles(one_chip, arch, quant, bucket):
 @pytest.mark.parametrize("arch", ["highres_cnn", "mnist_cnn"])
 def test_served_kernels_carry_their_stage_names(one_chip, arch):
     """Each fused stage's kernel is the instruction ``fused_cwp.s<i>``
-    (``plan.stages()[i]``; one per stream band), which names its events
+    (``plan.stages()[i]``), which names its events
     in a device trace."""
     from repro.configs.registry import get_arch
     from repro.graph.ir import FusedConvBlockNode
@@ -238,11 +236,10 @@ def test_sharded_plan_compiles_on_four_chips(topo, one_chip, arch, quant):
 @pytest.mark.parametrize("arch", ["highres_cnn", "mnist_cnn"])
 def test_stage_table_covers_served_plans(arch):
     """STAGES lists every conv kernel call of the served batch-8 plans,
-    stream bands included — so the compiles above track the models."""
+    so the compiles above track the models."""
     from repro.configs.registry import get_arch
     from repro.graph.ir import FusedConvBlockNode
     from repro.graph.passes import stage_input_spec
-    from repro.stream.tiling import pooled_bands
     plan = get_arch(arch).model().compile(batch=8)
     calls = set()
     for node in plan.graph:
@@ -250,10 +247,5 @@ def test_stage_table_covers_served_plans(arch):
             continue
         b, n, h, w = stage_input_spec(plan.graph, node).shape
         m, _, k, _ = node.w.shape
-        heights = {h}
-        if node.tiling is not None:
-            po = (h - k + 1) // 2
-            heights = {hi - lo for _, _, lo, hi in pooled_bands(
-                po, node.tiling.tile_rows, k, 1, h)}
-        calls |= {(b, n, hb, w, m, k) for hb in heights}
+        calls.add((b, n, h, w, m, k))
     assert calls and calls <= set(STAGES.values())

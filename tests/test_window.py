@@ -6,11 +6,10 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core.window import (LineBufferSim, conv2d_im2col, conv2d_ref,
-                               conv_output_size, extract_windows,
-                               fill_latency, maxpool2, pool_output_size,
-                               reuse_ratio)
-from repro.stream import band_input_rows, halo_rows, streamed_input_rows
+from repro.core.window import (LineBufferSim, band_input_rows,
+                               conv2d_im2col, conv2d_ref, conv_output_size,
+                               extract_windows, fill_latency, halo_rows,
+                               maxpool2, pool_output_size, reuse_ratio)
 
 
 class TestLaws:
@@ -125,7 +124,7 @@ def _check_strided_laws(kh: int, kw: int, w: int, h: int,
 class TestLineBufferStrideNonSquare:
     """§III.B.2 generalized: stride > 1 (readout gating, same fill
     latency) and non-square Kh×Kw windows — the reference model for the
-    streaming tiler's halo accounting (repro.stream, DESIGN.md §13)."""
+    halo accounting of the kernels' row blocks (DESIGN.md §13)."""
 
     @pytest.mark.parametrize("kh,kw,w,h,sh,sw",
                              [(3, 3, 9, 7, 2, 2),     # square, strided
@@ -153,8 +152,8 @@ class TestLineBufferStrideNonSquare:
         h = data.draw(st.integers(kh, kh + 6))
         _check_strided_laws(kh, kw, w, h, sh, sw)
 
-    def test_halo_accounting_matches_stream(self):
-        """The tiler's halo IS the line buffer's resident-row count: at
+    def test_halo_accounting_matches_line_buffer(self):
+        """The row blocks' halo IS the line buffer's resident-row count: at
         stride 1, halo_rows(k) == K-1 shift-buffer rows, and
         halo_rows(k)/k equals the paper's (K-1)/K reuse ratio; the fill
         latency is exactly those resident rows plus the Kw-1 lead-in."""
@@ -165,23 +164,13 @@ class TestLineBufferStrideNonSquare:
             assert fill_latency(kh, w, kw) == halo_rows(kh, 1) * w + kw - 1
 
     def test_band_rows_are_line_buffer_spans(self):
-        """A 1-row band reads exactly Kh rows (the window) and each extra
+        """A 1-row block reads exactly Kh rows (the window) and each extra
         output row costs sh more — the vertical form of the line buffer's
         fill+stream law."""
         for kh, sh in [(3, 1), (3, 2), (5, 2), (6, 1)]:
             assert band_input_rows(1, kh, sh) == kh
             assert band_input_rows(4, kh, sh) - \
                 band_input_rows(3, kh, sh) == sh
-
-    def test_streamed_rows_identity(self):
-        """Total rows DMA'd = untiled rows + (n_bands - 1)·halo — the
-        halo re-read is the entire streaming overhead."""
-        for out_rows, tile, kh, sh in [(26, 7, 3, 1), (8, 3, 6, 1),
-                                       (13, 4, 3, 2), (10, 10, 5, 1)]:
-            untiled = (out_rows - 1) * sh + kh
-            nbands = -(-out_rows // tile)
-            assert streamed_input_rows(out_rows, tile, kh, sh) == \
-                untiled + (nbands - 1) * halo_rows(kh, sh)
 
 
 class TestMaxPool2:
