@@ -1,13 +1,13 @@
 """Window-stationary Pallas TPU conv2d — the paper's window buffer on VMEM.
 
-Two operand layouts (DESIGN.md §2 row C3, §3); ``ops.tiling.conv_layout``
-picks one from the shape. Channels on the MXU's lanes (``lanes.py``)
-where min(N, 128) > min(Wo, 128) and N >= 64: 52 of ResNet-50's 53
-convs. The output width on the lanes (this module) elsewhere: the 7x7
-stem, the paper CNN's convs, and ``kernels/fused_cwp``, which shares
-this module's slab helpers. Width on lanes maps the paper's structure so:
+Three operand layouts (DESIGN.md §2 row C3, §3); ``ops.tiling.conv_layout``
+picks one from the shape: channels on the MXU's lanes (``lanes.py``, 52 of
+ResNet-50's 53 convs); whole windows (``patches.py``) where a kernel row's
+Kw·N taps are under 32 deep and all Kh·Kw·N at least 128 (the 7x7x3 stem);
+else the output width on the lanes (this module: the paper CNN's convs and
+``kernels/fused_cwp``, which shares its slab helpers). The paper maps so:
 
-  FPGA                         TPU (this module's kernel)
+  FPGA                         TPU (this module; ``patches.py`` in [ ])
   ----                         --------------------------
   SHIFT_BUFFER (K-1)×(W-K)     the input *slab*: a (rows_in × W) full-width
     holds W-K trailing pixels    stripe of the image, DMA'd HBM->VMEM once
@@ -15,9 +15,12 @@ this module's slab helpers. Width on lanes maps the paper's structure so:
   WINDOW_BUFFER K×K regs       the Kh·Kw taps: per output row, each tap is
     one window per clock         a lane-shifted (N, Wo) view of one slab
                                  row; a kernel row's Kw taps stack on
-                                 sublanes into a (Kw·N, Wo) operand
+                                 sublanes into a (Kw·N, Wo) operand [the
+                                 taps of two rows into one (K, Wo) patch]
   K² DSP multipliers +         one (MB, Kw·N)·(Kw·N, Wo) MXU contraction
-    odd-even addition tree       per kernel row, accumulated in fp32
+    odd-even addition tree       per kernel row, accumulated in fp32 [one
+                                 (2·MB, K)·(K, Wo) per two output rows:
+                                 each K×K window into one tree, §III.B]
   M parallel kernel banks      the Cout grid axis (output-channel parallel)
 
 Layout (DESIGN.md §8): the slab is ``(B, H, P, N, W/P)`` — rows on a
@@ -28,18 +31,15 @@ tap, strided or not, is a contiguous lane slice, so the kernel body uses
 no strided or gathered vector access. The output is ``(B, Ho, M, Wo)``:
 its last two block dims are always (MB, Wo), so the row block RB is free
 of the 8×128 tiling rule and only MB must be a multiple of 8 or all of M.
-With Wo of 7-56 on the lanes most of each MXU pass is empty, which is
-why wide convs take the channels-on-lanes kernel. The wrappers transpose
-NCHW in and out around the call.
+The wrappers transpose NCHW in and out around the call.
 
 Reuse invariant preserved: each input element crosses HBM->VMEM once per
 row block, in a slab of ``band_input_rows(RB, Kh, sh)`` rows (halo rows
 of adjacent blocks excepted: ``halo_rows(Kh, sh)``, amortized to
 (Kh−s)/(RB·s) per block, the overlap the paper's SHIFT_BUFFER absorbs),
 through element offsets on every axis (``pl.Element``), double-buffered
-against the previous step's MXU work. This grid is the repo's one row
-tiling (DESIGN.md §13). Grid: (B/BB, Ho/RB, M/MB); block sizes come from
-ops.py.
+against the previous step's MXU work: the repo's one row tiling (DESIGN.md
+§13). Grid: (B/BB, Ho/RB, M/MB); block sizes from ops.py.
 """
 from __future__ import annotations
 

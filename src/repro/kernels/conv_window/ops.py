@@ -4,9 +4,11 @@ Pads the output-row count to the row block and the batch to the batch
 block when ragged (the kernel launchers own the slab/tap layouts), picks
 the operand layout from the shape (``ops.tiling.conv_layout``: channels
 on lanes where they fill more of a pass than the output width does and
-at least half of it),
-and exposes a single ``conv2d_window`` entry point registered as the
-``pallas`` backend of the ``conv2d`` op family (repro.ops).
+at least half of it; whole-window patches where a kernel row's taps fill
+under a quarter of the MXU's depth and the window at least one pass;
+else the width-on-lanes slab), and exposes a single ``conv2d_window``
+entry point registered as the ``pallas`` backend of the ``conv2d`` op
+family (repro.ops).
 
 Block sizes and interpret mode come from the shared policy/tiling layer
 (DESIGN.md §7): explicit kwargs > ``ExecPolicy.tiling`` overrides > the
@@ -23,10 +25,11 @@ import jax.numpy as jnp
 from repro.artifact.warmup import note_layout
 from repro.kernels.conv_window.kernel import conv2d_window_pallas
 from repro.kernels.conv_window.lanes import conv2d_lanes_pallas
+from repro.kernels.conv_window.patches import conv2d_patches_pallas
 from repro.ops.policy import ExecPolicy, current_policy
-from repro.ops.tiling import (LANE, SUBLANE, choose_conv_blocks,
-                              conv_layout, conv_signature, legal_block,
-                              tile_params)
+from repro.ops.tiling import (LANE, PATCH_ROWS, SUBLANE,
+                              choose_conv_blocks, conv_layout,
+                              conv_signature, legal_block, tile_params)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret", "rb",
@@ -52,8 +55,9 @@ def _conv2d_window_jit(x: jax.Array, w: jax.Array, b: jax.Array | None, *,
         x = jnp.pad(x, ((0, pad_b), (0, 0), (0, 0), (0, 0)))
 
     bias = jnp.zeros((m,), x.dtype) if b is None else b
-    launch = (conv2d_lanes_pallas if layout == "lanes"
-              else conv2d_window_pallas)
+    launch = {"lanes": conv2d_lanes_pallas,
+              "patches": conv2d_patches_pallas,
+              "slab": conv2d_window_pallas}[layout]
     out = launch(x, w, bias, stride=stride, rb=rb, mb=mb, bb=bb,
                  interpret=interpret, name=name)
     return out[:bsz, :, :ho, :]
@@ -83,7 +87,7 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
 
     n, h, wdt = x.shape[1], x.shape[2], x.shape[3]
     m, kh, kw = w.shape[0], w.shape[2], w.shape[3]
-    layout = conv_layout(n, (wdt - kw) // stride[1] + 1)
+    layout = conv_layout(n, (wdt - kw) // stride[1] + 1, kh, kw)
     defaults = choose_conv_blocks(n, h, wdt, m, kh, kw, tuple(stride),
                                   x.dtype.itemsize, batch=x.shape[0])
     sig = conv_signature(x.shape, w.shape, stride)
@@ -98,12 +102,14 @@ def conv2d_window(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
         tiles["mb"] = mb
     if bb is not None:
         tiles["bb"] = bb
-    # mb must divide M and obey the block rule (on sublanes in the slab
-    # layout, on lanes in the lane layout); rb and bb are free — ragged Ho
-    # and B are padded
+    # mb must divide M and obey the block rule (on lanes in the lane
+    # layout, on sublanes in the others); rb and bb are free — ragged Ho
+    # and B are padded — but for whole-window patches rb is a multiple of
+    # the rows one contraction covers
     tiles["mb"] = legal_block(m, tiles["mb"],
                               LANE if layout == "lanes" else SUBLANE)
-    tiles["rb"] = max(1, tiles["rb"])
+    step = PATCH_ROWS if layout == "patches" else 1
+    tiles["rb"] = max(1, -(-tiles["rb"] // step)) * step
     tiles["bb"] = max(1, min(tiles["bb"], x.shape[0]))
     name = f"conv_window.{stage}" if stage else "conv_window"
     note_layout(name, layout)

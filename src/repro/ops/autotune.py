@@ -49,7 +49,7 @@ from repro.ops.tiling import (LANE, SUBLANE, TUNING_CACHE,
                               choose_fused_blocks, choose_qmatmul_blocks,
                               conv_layout, conv_signature, lane_vmem_bytes,
                               legal_block, legal_qmatmul_tiles,
-                              window_vmem_bytes)
+                              patch_vmem_bytes, window_vmem_bytes)
 
 __all__ = ["ensure_tuned", "tune_conv2d", "tune_fused_conv_block",
            "tune_qmatmul", "resolved_backend",
@@ -89,10 +89,13 @@ def _measure(fn: Callable, *args, warmup: int | None = None,
     return best * 1e6
 
 
-def _lanes(op: str, x_shape, w_shape, stride) -> bool:
-    """Whether the call runs the channels-on-lanes window conv."""
-    return op == "conv2d" and conv_layout(
-        x_shape[1], (x_shape[3] - w_shape[3]) // stride[1] + 1) == "lanes"
+def _layout(op: str, x_shape, w_shape, stride) -> str:
+    """The window conv layout the call runs (``"slab"`` for the fused
+    block, which always runs the slab)."""
+    if op != "conv2d":
+        return "slab"
+    return conv_layout(x_shape[1], (x_shape[3] - w_shape[3]) // stride[1]
+                       + 1, w_shape[2], w_shape[3])
 
 
 def _axis_candidates(op: str, x_shape, w_shape, stride,
@@ -113,7 +116,7 @@ def _axis_candidates(op: str, x_shape, w_shape, stride,
     else:
         rbs = {r for r in ROW_BLOCKS if r <= ho} | {heuristic["rb"], ho}
         axes["rb"] = sorted(rbs)
-    if _lanes(op, x_shape, w_shape, stride):
+    if _layout(op, x_shape, w_shape, stride) == "lanes":
         mbs = {legal_block(m, cap, LANE) for cap in LANE_CHANNEL_CAPS}
     else:
         mbs = {legal_block(m, cap, SUBLANE) for cap in CHANNEL_CAPS}
@@ -128,8 +131,10 @@ def _window_fits(op: str, x_shape, w_shape, stride, itemsize: int
     only ever launches tiles the compiler would accept."""
     _, n, _, w = x_shape
     _, _, kh, kw = w_shape
-    if _lanes(op, x_shape, w_shape, stride):
-        return lambda t: lane_vmem_bytes(
+    layout = _layout(op, x_shape, w_shape, stride)
+    if layout != "slab":
+        vmem = lane_vmem_bytes if layout == "lanes" else patch_vmem_bytes
+        return lambda t: vmem(
             n, w, kh, kw, stride, t["mb"], t["rb"], t["bb"],
             itemsize) <= VMEM_BUDGET_BYTES
     pooled = op == "fused_conv_block"

@@ -25,6 +25,7 @@ returns 0, leaving the analytic heuristics in charge.
 from __future__ import annotations
 
 import json
+import math
 import os
 import pathlib
 import warnings
@@ -35,7 +36,9 @@ import numpy as np
 from repro.core.window import band_input_rows
 
 __all__ = ["legal_block", "window_vmem_bytes", "conv_layout",
-           "lane_vmem_bytes", "choose_conv_blocks", "choose_lane_blocks",
+           "lane_vmem_bytes", "PATCH_ROWS", "patch_channels",
+           "patch_vmem_bytes",
+           "choose_conv_blocks", "choose_lane_blocks", "choose_patch_blocks",
            "choose_fused_blocks", "choose_qmatmul_blocks",
            "legal_qmatmul_tiles",
            "choose_tree_rows", "TuningCache", "TUNING_CACHE", "tile_params",
@@ -52,6 +55,11 @@ VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 SUBLANE = 8
 LANE = 128
 INT8_SUBLANE = 32
+
+# output rows one contraction of the whole-window conv covers: two rows
+# share one patch, so the stem's 64 channels fill the MXU's 128 rows
+# (kernels/conv_window/patches.py); its row blocks are multiples of it
+PATCH_ROWS = 2
 
 # version of the persisted tuning-cache JSON schema (bumped when the key or
 # row layout changes, or what a row's tiles mean; older/newer files fall
@@ -127,19 +135,29 @@ def _largest_rows(n, w, kh, kw, stride, mb, itemsize, full, *, pooled):
     return best
 
 
-def conv_layout(n: int, wo: int) -> str:
+def conv_layout(n: int, wo: int, kh: int, kw: int) -> str:
     """The operand layout of the window conv kernel at this shape:
-    ``"lanes"`` (channels on the MXU's lanes) where the input channels
-    fill more of a 128-lane pass than the output width does, and at
-    least half of it; else ``"slab"`` (output width on the lanes).
+
+    - ``"lanes"`` (channels on the MXU's lanes) where the input channels
+      fill more of a 128-lane pass than the output width does, and at
+      least half of it;
+    - ``"patches"`` (the whole Kh·Kw·N window contracted at once) where a
+      kernel row of taps, Kw·N, fills under a quarter of the MXU's depth
+      and the whole window fills at least one pass;
+    - else ``"slab"`` (output width on the lanes, one Kw·N contraction
+      per kernel row).
 
     Under 64 channels a tap of the lane kernel contracts less than half
     the MXU's depth, where the slab contracts a kernel row's Kw·N at
     once; and the slab is the contraction ``kernels/fused_cwp`` runs, so
     such a conv computes the same sums fused or not (the paper CNN's
-    15-channel conv2, DESIGN.md §8)."""
-    return ("lanes" if min(n, LANE) > min(wo, LANE) and n >= LANE // 2
-            else "slab")
+    15-channel conv2, DESIGN.md §8). A window too small to fill one pass
+    gains nothing from the patch, and keeps the slab too."""
+    if min(n, LANE) > min(wo, LANE) and n >= LANE // 2:
+        return "lanes"
+    if kw * n < LANE // 4 and kh * kw * n >= LANE:
+        return "patches"
+    return "slab"
 
 
 def lane_vmem_bytes(n: int, w: int, kh: int, kw: int,
@@ -166,6 +184,42 @@ def lane_vmem_bytes(n: int, w: int, kh: int, kw: int,
     out = bb * rows * wo_pad * _round_up(mb, LANE)
     temps = bb * rows * wo_pad * (_round_up(k, LANE) + _round_up(mb, LANE))
     return 2 * (slab + taps + vecs + out) * itemsize + 4 * temps
+
+
+def patch_channels(n: int, phases: int, itemsize: int) -> int:
+    """Np, the input channels of the whole-window conv padded so that
+    its P = stride_w column phases of Np channels fill whole sublane
+    tiles (kernels/conv_window/patches.py)."""
+    sub = SUBLANE * 4 // itemsize
+    step = sub // math.gcd(phases, sub)
+    return _round_up(n, step)
+
+
+def patch_vmem_bytes(n: int, w: int, kh: int, kw: int,
+                     stride: tuple[int, int], mb: int, rows: int, bb: int,
+                     itemsize: int) -> int:
+    """VMEM one grid step of the whole-window conv occupies, in the same
+    tiled reckoning as ``window_vmem_bytes``, ``rows`` rounded up to a
+    multiple of ``PATCH_ROWS`` (G). Double-buffered blocks: the halo'd
+    slab (BB, rows_in, P·Np, Wo + ⌈Kw/P⌉ - 1), the stacked weights (G·MB,
+    K) with K = ((G-1)·sh + Kh)·⌈Kw/P⌉·P·Np, the bias column and the
+    output block (BB, RB, MB, Wo); plus, once, the float32 patch (K, Wo)
+    and accumulator (G·MB, Wo)."""
+    sub = SUBLANE * 4 // itemsize
+    sh, sw = stride
+    rows = _round_up(rows, PATCH_ROWS)
+    wo = (w - kw) // sw + 1
+    offs = -(-kw // sw)
+    tile = sw * patch_channels(n, sw, itemsize)        # P·Np sublanes
+    k = ((PATCH_ROWS - 1) * sh + kh) * offs * tile
+    lanes = _round_up(wo + offs - 1, LANE)
+    slab = bb * band_input_rows(rows, kh, sh) * tile * lanes
+    wts = _round_up(PATCH_ROWS * mb, sub) * _round_up(k, LANE)
+    vecs = _round_up(mb, sub) * LANE
+    out = bb * rows * _round_up(mb, sub) * _round_up(wo, LANE)
+    temps = (_round_up(k, sub) + _round_up(PATCH_ROWS * mb, sub)) \
+        * _round_up(wo, LANE)
+    return 2 * (slab + wts + vecs + out) * itemsize + 4 * temps
 
 
 def _least_padding(full: int, cap: int) -> int:
@@ -206,12 +260,39 @@ def choose_lane_blocks(b: int, n: int, h: int, w: int, m: int, kh: int,
     return {"rb": rb, "mb": mb, "bb": bb}
 
 
+def choose_patch_blocks(b: int, n: int, h: int, w: int, m: int, kh: int,
+                        kw: int, stride: tuple[int, int], itemsize: int
+                        ) -> dict[str, int]:
+    """Heuristic (rb, mb, bb) for the whole-window conv: mb = min(m, 128)
+    rounded to a legal sublane block, then the most output rows a step
+    fits under ``VMEM_BUDGET_BYTES`` (``patch_vmem_bytes``) and, once a
+    step holds a whole image, the most images; each the block that pads
+    its axis least, rows in multiples of ``PATCH_ROWS``."""
+    ho = (h - kh) // stride[0] + 1
+    mb = legal_block(m, LANE, SUBLANE)
+
+    def fits(rows, bb):
+        return patch_vmem_bytes(n, w, kh, kw, stride, mb, rows, bb,
+                                itemsize) <= VMEM_BUDGET_BYTES
+
+    groups = -(-ho // PATCH_ROWS)
+    most = max([g for g in range(1, groups + 1)
+                if fits(g * PATCH_ROWS, 1)], default=1)
+    rb = PATCH_ROWS * _least_padding(groups, most)
+    bb = 1
+    if rb >= ho:
+        imgs = max([i for i in range(1, b + 1) if fits(rb, i)], default=1)
+        bb = _least_padding(b, imgs)
+    return {"rb": rb, "mb": mb, "bb": bb}
+
+
 def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
                        stride: tuple[int, int], itemsize: int,
                        batch: int = 1) -> dict[str, int]:
     """Heuristic (rb, mb, bb) for the window-stationary conv kernel, in
     the layout ``conv_layout`` gives the shape: ``choose_lane_blocks``
-    for channels on lanes; for the slab, as below.
+    for channels on lanes, ``choose_patch_blocks`` for whole-window
+    patches; for the slab, as below.
 
     mb = min(m, 128) (MXU lane width) rounded to a legal block, then the
     largest rb whose grid step fits ``VMEM_BUDGET_BYTES``
@@ -221,9 +302,13 @@ def choose_conv_blocks(n: int, h: int, w: int, m: int, kh: int, kw: int,
     autotuner (DESIGN.md §10).
     """
     ho = (h - kh) // stride[0] + 1
-    if conv_layout(n, (w - kw) // stride[1] + 1) == "lanes":
+    layout = conv_layout(n, (w - kw) // stride[1] + 1, kh, kw)
+    if layout == "lanes":
         return choose_lane_blocks(batch, n, h, w, m, kh, kw, stride,
                                   itemsize)
+    if layout == "patches":
+        return choose_patch_blocks(batch, n, h, w, m, kh, kw, stride,
+                                   itemsize)
     mb = legal_block(m, 128, SUBLANE)
     rb = _largest_rows(n, w, kh, kw, stride, mb, itemsize, ho, pooled=False)
     return {"rb": rb, "mb": mb, "bb": 1}

@@ -14,7 +14,9 @@ compute each output row with the same contraction whatever the block, so
 they are held bitwise on random data. The lane kernel contracts a whole
 block's rows in one dot, whose size XLA:CPU may sum in another order, so
 its float32 cases use data on which every product and partial sum is
-exact (``_lattice``); then any order gives the same bits.
+exact (``_lattice``); then any order gives the same bits. The
+whole-window patch kernel's cases use the lattice too, in every quant
+mode.
 """
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,12 @@ LANE_SHAPES = {
     "3x3_same": (2, 64, 8, 8, 64, 3, 1, 1),
     "3x3_stride2": (2, 128, 9, 9, 128, 3, 2, 1),
     "3x3_ragged": (2, 64, 9, 9, 64, 3, 1, 0),
+}
+# stem-like whole-window convs, reduced: (B, N, H, W, M, k, stride, pad)
+PATCH_SHAPES = {
+    "7x7_stride2_same": (2, 3, 18, 16, 8, 7, 2, 3),
+    "7x7_ragged": (2, 3, 17, 15, 16, 7, 1, 0),
+    "5x5x6_stride2": (2, 6, 19, 19, 8, 5, 2, 0),
 }
 
 
@@ -69,7 +77,8 @@ def _pallas(quant="none", **tiling):
 def test_conv_slab_row_blocks_bitwise(quant, k, s, h, rb):
     x, w, b = _operands((2, 3, h, h + 2), 4, k)
     ho = _out_rows(h, k, s)
-    assert conv_layout(3, _out_rows(h + 2, k, s)) == "slab" and rb < ho
+    assert conv_layout(3, _out_rows(h + 2, k, s), k, k) == "slab"
+    assert rb < ho
     got = conv2d(x, w, b, stride=(s, s),
                  policy=_pallas(quant, **{"conv2d.rb": rb}))
     want = conv2d(x, w, b, stride=(s, s),
@@ -87,10 +96,31 @@ def test_conv_lanes_row_blocks_bitwise(shape, rb):
     bsz, n, h, wd, m, k, s, pad = LANE_SHAPES[shape]
     x, w, b = _operands((bsz, n, h, wd), m, k, n=n, data=_lattice)
     ho = _out_rows(h, k, s, pad)
-    assert conv_layout(n, _out_rows(wd, k, s, pad)) == "lanes" and rb < ho
+    assert conv_layout(n, _out_rows(wd, k, s, pad), k, k) == "lanes"
+    assert rb < ho
     kw = dict(stride=(s, s), padding=(pad, pad))
     got = conv2d(x, w, b, policy=_pallas(**{"conv2d.rb": rb}), **kw)
     want = conv2d(x, w, b, policy=_pallas(**{"conv2d.rb": ho}), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# -------------------------------------------- conv_window, whole windows
+
+@pytest.mark.parametrize("rb", [2, 3])
+@pytest.mark.parametrize("shape", sorted(PATCH_SHAPES))
+@pytest.mark.parametrize("quant", QUANTS)
+def test_conv_patches_row_blocks_bitwise(quant, shape, rb):
+    """The layout ``resnet50``'s stem takes: strided, padded and ragged
+    row blocks against one block, in every quant mode."""
+    bsz, n, h, wd, m, k, s, pad = PATCH_SHAPES[shape]
+    x, w, b = _operands((bsz, n, h, wd), m, k, n=n, data=_lattice)
+    ho = _out_rows(h, k, s, pad)
+    assert conv_layout(n, _out_rows(wd, k, s, pad), k, k) == "patches"
+    assert rb < ho
+    kw = dict(stride=(s, s), padding=(pad, pad))
+    got = conv2d(x, w, b, policy=_pallas(quant, **{"conv2d.rb": rb}), **kw)
+    want = conv2d(x, w, b, policy=_pallas(quant, **{"conv2d.rb": ho}),
+                  **kw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
